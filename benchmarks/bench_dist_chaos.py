@@ -231,7 +231,9 @@ def child_main(argv):
 
 
 def run_subprocess() -> dict:
-    env = dict(os.environ)
+    # a host-device drill: the child stays on the CPU even where the parent
+    # holds an accelerator (a second process cannot share the chip)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     with tempfile.TemporaryDirectory() as root:

@@ -236,7 +236,9 @@ def dist_drill(root: str) -> dict:
 def dist_drill_subprocess() -> dict:
     """Run :func:`dist_drill` in a child process with 8 forced host devices
     (the parent's jax is already initialized with 1)."""
-    env = dict(os.environ)
+    # a host-device drill: the child stays on the CPU even where the parent
+    # holds an accelerator (a second process cannot share the chip)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     with tempfile.TemporaryDirectory() as root:

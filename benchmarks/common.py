@@ -14,9 +14,11 @@ from __future__ import annotations
 import os
 import time
 
-os.environ.setdefault("REPRO_CPU_EXEC", "1")
-
 import jax
+
+if jax.default_backend() == "cpu":
+    # XLA:CPU cannot execute bf16 x bf16 -> f32 dots (models/common.py)
+    os.environ.setdefault("REPRO_CPU_EXEC", "1")
 import jax.numpy as jnp
 import numpy as np
 

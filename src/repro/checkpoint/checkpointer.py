@@ -30,6 +30,7 @@ import shutil
 import jax
 import numpy as np
 
+from repro.core.quantize import PACK_LAYOUT
 from repro.distributed.fault_tolerance import retry_on_transient
 from repro.robustness import NO_FAULTS, InjectedFault
 
@@ -164,6 +165,7 @@ class Checkpointer:
             "leaves": entries,
             "step": step,
             "num_leaves": len(entries),
+            "pack_layout": PACK_LAYOUT,
         }
         def write_spec():
             with open(os.path.join(tmp, "spec.json"), "w") as f:
@@ -263,6 +265,14 @@ class Checkpointer:
             loaded = [self._load_leaf(path, e) for e in spec["leaves"]]
         else:  # v1 layout: one whole-array file per leaf
             loaded = [np.load(os.path.join(path, n)) for n in spec["names"]]
+        # uint8 leaves are packed codes: their bytes only mean something in
+        # the layout they were packed in
+        if (spec.get("pack_layout") != PACK_LAYOUT
+                and any(np.dtype(l.dtype) == np.uint8 for l in loaded)):
+            raise ValueError(
+                f"checkpoint step {step} holds packed codes in pack layout "
+                f"{spec.get('pack_layout')!r}; this build reads "
+                f"{PACK_LAYOUT!r} — re-quantize instead of restoring")
         if shardings is not None:
             shard_leaves = jax.tree_util.tree_flatten(shardings)[0]
             loaded = [jax.device_put(l, s)

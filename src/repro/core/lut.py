@@ -23,6 +23,8 @@ __all__ = [
     "codebook_bits",
     "CODEBOOKS",
     "midpoints",
+    "static_levels",
+    "static_midpoints",
     "mixed_precision_schedule",
     "realized_bits",
 ]
@@ -115,6 +117,18 @@ def midpoints(name: str) -> jnp.ndarray:
     """Decision boundaries between adjacent levels (len = n_levels - 1)."""
     levels = _build(name)
     return jnp.asarray((levels[1:] + levels[:-1]) / 2)
+
+
+def static_levels(name: str) -> tuple[float, ...]:
+    """:func:`codebook` as Python floats (the exact f32 values): constants a
+    kernel body can bake in while it is being traced."""
+    return tuple(float(v) for v in _build(name))
+
+
+def static_midpoints(name: str) -> tuple[float, ...]:
+    """:func:`midpoints` as Python floats (exact f32 values)."""
+    levels = _build(name)
+    return tuple(float(v) for v in (levels[1:] + levels[:-1]) / 2)
 
 
 def mixed_precision_schedule(

@@ -3,20 +3,29 @@
 Storage format
 --------------
 Codes are indices into a codebook (``repro.core.lut``).  On disk / in HBM we
-pack them along the last axis in groups of ``PackSpec.group_codes`` codes per
-``PackSpec.group_bytes`` bytes, little-endian within the group (code 0 in the
-lowest bits of byte 0):
+pack them along the last axis in groups of ``g = PackSpec.group_codes``
+codes per ``PackSpec.group_bytes`` bytes:
 
   * 8-bit codebooks (int8):          1 code  per byte   (1c/1B)
-  * 4-bit codebooks (nf4/int4/fp4):  2 codes per byte   (2c/1B, low nibble
-    first — unchanged from the historical nibble layout)
+  * 4-bit codebooks (nf4/int4/fp4):  2 codes per byte   (2c/1B)
   * 3-bit codebooks (nf3):           8 codes per 3 bytes (8c/3B, cross-byte:
     the 8 codes form one 24-bit little-endian integer)
   * 2-bit codebooks (nf2/int2):      4 codes per byte   (4c/1B)
 
-For ``group_bytes == 1`` widths this is byte-identical to the historical
-layout; 3-bit is the only cross-byte group.  All functions are jit-friendly
-and differentiable where meaningful.
+Groups are *slot-major planes*: a row of ``K`` codes is cut into ``g``
+contiguous planes of ``W = K / g`` codes, and group ``j`` gathers column
+``j`` of every plane — code slot ``i`` (bits ``[bits*i, bits*(i+1))`` of the
+group's little-endian integer) holds logical code ``i*W + j``.  Byte ``c`` of
+group ``j`` is stored at column ``c*W + j``, so every byte plane is
+contiguous as well.  A kernel tile of packed columns therefore unpacks by
+shift/mask alone into ``g`` lane-dense code planes, each a contiguous run of
+logical K — no lane interleave.  Padding K changes ``W``, so a wider row is
+re-packed, not byte-padded (:func:`repack_width`).  All functions are
+jit-friendly and differentiable where meaningful.
+
+:data:`PACK_LAYOUT` names this layout.  Everything that persists packed
+codes records it (the streaming-PTQ plan fingerprint, checkpoint specs),
+so codes written in another layout are refused rather than misread.
 """
 from __future__ import annotations
 
@@ -29,6 +38,7 @@ from repro.core import lut
 from repro.core.scaling import SCALE_EPS
 
 __all__ = [
+    "PACK_LAYOUT",
     "PackSpec",
     "pack_spec",
     "nearest_code",
@@ -36,12 +46,18 @@ __all__ = [
     "dequantize_codes",
     "pack_codes",
     "unpack_codes",
+    "repack_width",
     "packed_dim",
     "codes_per_byte",
     "fake_quant",
     "quantize_blockwise",
     "dequantize_blockwise",
 ]
+
+
+# the stored byte layout of packed codes (module docstring); an artifact
+# that records no layout was packed in an earlier one
+PACK_LAYOUT = "planes"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,23 +165,18 @@ def packed_dim(m: int, codebook_name: str) -> int:
 
 
 def pack_codes(codes: jnp.ndarray, codebook_name: str) -> jnp.ndarray:
-    """Pack uint8 code indices along the last axis into uint8 bytes.
-
-    Each group of ``group_codes`` codes is assembled into one little-endian
-    integer (code i at bits ``[bits*i, bits*(i+1))``) and emitted as
-    ``group_bytes`` little-endian bytes.  For single-byte groups this reduces
-    to the historical low-nibble-first layout.
-    """
+    """Pack uint8 code indices along the last axis into uint8 bytes, in the
+    slot-major plane layout of the module docstring."""
     ps = pack_spec(codebook_name)
     if ps.group_codes == 1:
         return codes.astype(jnp.uint8)
     *lead, m = codes.shape
-    grp = codes.reshape(*lead, ps.packed_width(m) // ps.group_bytes,
-                        ps.group_codes).astype(jnp.uint32)
-    shifts = jnp.arange(ps.group_codes, dtype=jnp.uint32) * ps.bits
-    word = jnp.sum(grp << shifts, axis=-1)  # <= 24 bits, fits uint32
-    byte_shifts = jnp.arange(ps.group_bytes, dtype=jnp.uint32) * 8
-    packed = (word[..., None] >> byte_shifts) & jnp.uint32(0xFF)
+    planes = codes.reshape(*lead, ps.group_codes,
+                           ps.packed_width(m) // ps.group_bytes)
+    shifts = jnp.arange(ps.group_codes, dtype=jnp.uint32)[:, None] * ps.bits
+    word = jnp.sum(planes.astype(jnp.uint32) << shifts, axis=-2)  # <= 24 bits
+    byte_shifts = jnp.arange(ps.group_bytes, dtype=jnp.uint32)[:, None] * 8
+    packed = (word[..., None, :] >> byte_shifts) & jnp.uint32(0xFF)
     return packed.reshape(*lead, -1).astype(jnp.uint8)
 
 
@@ -175,14 +186,32 @@ def unpack_codes(packed: jnp.ndarray, codebook_name: str) -> jnp.ndarray:
     if ps.group_codes == 1:
         return packed.astype(jnp.uint8)
     *lead, mp = packed.shape
-    grp = packed.reshape(*lead, ps.logical_width(mp) // ps.group_codes,
-                         ps.group_bytes).astype(jnp.uint32)
-    byte_shifts = jnp.arange(ps.group_bytes, dtype=jnp.uint32) * 8
-    word = jnp.sum(grp << byte_shifts, axis=-1)
-    shifts = jnp.arange(ps.group_codes, dtype=jnp.uint32) * ps.bits
-    mask = jnp.uint32(2**ps.bits - 1)
-    codes = (word[..., None] >> shifts) & mask
+    planes = packed.reshape(*lead, ps.group_bytes,
+                            ps.logical_width(mp) // ps.group_codes)
+    byte_shifts = jnp.arange(ps.group_bytes, dtype=jnp.uint32)[:, None] * 8
+    word = jnp.sum(planes.astype(jnp.uint32) << byte_shifts, axis=-2)
+    shifts = jnp.arange(ps.group_codes, dtype=jnp.uint32)[:, None] * ps.bits
+    codes = (word[..., None, :] >> shifts) & jnp.uint32(2**ps.bits - 1)
     return codes.reshape(*lead, -1).astype(jnp.uint8)
+
+
+def repack_width(packed: jnp.ndarray, k: int, codebook_name: str
+                 ) -> jnp.ndarray:
+    """Re-lay packed rows out for a logical width of ``k`` codes: trailing
+    zero codes are appended, or codes past ``k`` dropped.  A plane's width
+    is ``K / group_codes``, so this is a re-pack rather than a byte pad
+    whenever the group is more than one code."""
+    ps = pack_spec(codebook_name)
+    cur = ps.logical_width(packed.shape[-1])
+    if cur == k:
+        return packed
+    codes = unpack_codes(packed, codebook_name)
+    if k < cur:
+        codes = codes[..., :k]
+    else:
+        widths = [(0, 0)] * (codes.ndim - 1) + [(0, k - cur)]
+        codes = jnp.pad(codes, widths)
+    return pack_codes(codes, codebook_name)
 
 
 def fake_quant(w: jnp.ndarray, s: jnp.ndarray, codebook_name: str) -> jnp.ndarray:

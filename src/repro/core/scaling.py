@@ -21,6 +21,7 @@ __all__ = [
     "eff_block",
     "expand_block_scales",
     "svd_init",
+    "svd_init_blocks",
     "lords_init_from_weight",
     "scale_matrix",
     "clamp_scale",
@@ -80,6 +81,33 @@ def svd_init(s_dense: jnp.ndarray, rank: int) -> tuple[jnp.ndarray, jnp.ndarray]
     return b, a
 
 
+def svd_init_blocks(
+    s_blk: jnp.ndarray, block_size: int, rank: int,
+    col_scale: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`svd_init` of ``S = expand(s_blk) / col_scale`` without forming
+    or decomposing the dense (n, m) S.
+
+    Row i of the block expansion ``E·diag(1/c)`` is nonzero on block i
+    only, so its rows are orthogonal: ``E·diag(1/c) = Λ·Q`` with Λ the row
+    norms and Q orthonormal rows.  Hence ``S = (s_blk·Λ)·Q`` and the SVD of
+    the small (n, m/B) matrix ``s_blk·Λ`` gives S's: same U and Σ, V = Qᵀ·V'.
+    Exact for ``rank <= m/B`` (rank(S) <= m/B); an SVD whose smaller side is
+    m/B instead of min(n, m) is what makes the full-width init fast."""
+    n, nb = s_blk.shape
+    inv_c = (jnp.ones((nb * block_size,), s_blk.dtype) if col_scale is None
+             else 1.0 / col_scale)
+    rows = inv_c.reshape(nb, block_size)
+    lam = jnp.sqrt(jnp.sum(rows * rows, axis=1))             # (nb,)
+    u, sig, vt = jnp.linalg.svd(s_blk * lam[None, :], full_matrices=False)
+    r = min(rank, sig.shape[0])
+    root = jnp.sqrt(sig[:r])
+    b = u[:, :r] * root[None, :]
+    q = rows / lam[:, None]                                  # Q's row blocks
+    a = (root[:, None, None] * vt[:r, :, None] * q[None]).reshape(r, -1)
+    return b, a
+
+
 def lords_init_from_weight(
     w: jnp.ndarray,
     block_size: int,
@@ -87,7 +115,7 @@ def lords_init_from_weight(
     extra_rank: int = 0,
     channel_scale: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Full LoRDS init: block scales -> dense S -> truncated SVD -> (B, A).
+    """Full LoRDS init: block scales -> S -> truncated SVD -> (B, A).
 
     ``channel_scale`` (m,): SmoothQuant-style per-input-channel smoothing
     scales c_j, folded into the init — block scales are computed on the
@@ -101,14 +129,16 @@ def lords_init_from_weight(
     if rank is None:
         rank = parity_rank(n, m, block_size, extra_rank)
     block_size = eff_block(m, block_size)
+    c = None
     if channel_scale is not None:
         c = jnp.maximum(jnp.abs(channel_scale.astype(w.dtype)), SCALE_EPS)
-        s = expand_block_scales(
-            blockwise_scales(w * c[None, :], block_size), block_size)
-        s = s / c[None, :]
-    else:
-        s = expand_block_scales(blockwise_scales(w, block_size), block_size)
-    return svd_init(s, rank)
+    s_blk = blockwise_scales(w if c is None else w * c[None, :], block_size)
+    if rank <= s_blk.shape[1]:  # within rank(S): the factored SVD is exact
+        return svd_init_blocks(s_blk, block_size, rank, c)
+    # components past rank(S) span its null space, which only the dense
+    # SVD defines
+    s = expand_block_scales(s_blk, block_size)
+    return svd_init(s if c is None else s / c[None, :], rank)
 
 
 def scale_matrix(b: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:

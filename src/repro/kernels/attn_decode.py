@@ -19,15 +19,18 @@ runs the same kernels with the scale operands absent.
 Layouts — the caches are indexed **in their stored layouts** via the
 BlockSpec index maps (a host-side transpose would force XLA to copy the
 entire cache every decode step, tripling the traffic the kernels exist to
-minimize):
+minimize); only free reshapes are applied, so every block is lane-aligned:
   GQA:  q (b, nkv, g8, hd) · k/v (b, S, nkv, hd) [+ scales (b, S, nkv)],
         grid (b, nkv, S/bs) — one head-group per grid cell, q VMEM-resident,
-        KV tiles (1, bs, 1, hd) sliced straight from the cache arrays
+        KV tiles (1, bs, hd) sliced from the (b, S, nkv·hd) view, and the
+        (1, bs, nkv) scale tile narrowed to the cell's head in VMEM
   MLA:  q_lat (b, nh8, L) / q_rope (b, nh8, R) against the absorbed cache
         c (b, S, L) [+ c_scale (b, S)] and k_rope (b, S, R),
-        grid (b, S/bs) — output *is* the weighted latent (b, nh8, L)
+        grid (b, S/bs) — output *is* the weighted latent (b, nh8, L); the
+        latent scale folds into the softmax weights (p ⊙ scale)·c
 
-``kmask`` (b, S) f32 is the additive liveness mask (0 live / -1e30 dead):
+``kmask`` (b, S) f32 is the additive liveness mask (0 live / -1e30 dead),
+viewed as (b, S/bs, 1, bs) so its tiles are whole rows:
 positions beyond each sequence's ``pos`` and cache padding never
 contribute, with the same finite-NEG_INF / alpha-correction NaN hygiene as
 :mod:`repro.kernels.attn_prefill`.
@@ -64,9 +67,11 @@ DECODE_ROWS = 8     # sublane multiple query rows are padded to
 _STAT_LANES = 128
 
 
-def _online_update(s, v, m_ref, l_ref, acc_ref):
+def _online_update(s, v, m_ref, l_ref, acc_ref, p_scale=None):
     """Shared flash-2 step: fold the (rows, bs) score tile ``s`` and value
-    tile ``v`` into the running (m, l, acc) statistics."""
+    tile ``v`` into the running (m, l, acc) statistics.  ``p_scale``
+    (1, bs) scales the weights of the value product only (a per-key value
+    scale), never the normalizer."""
     m_prev = m_ref[:, :1]
     l_prev = l_ref[:, :1]
     m_curr = jnp.max(s, axis=1, keepdims=True)
@@ -76,11 +81,25 @@ def _online_update(s, v, m_ref, l_ref, acc_ref):
     l_next = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
+    pv = p if p_scale is None else p * p_scale
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        pv, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     return p
+
+
+def _head_column(tile, head):
+    """(bs, nkv) scale tile → the (bs, 1) column of kv head ``head``
+    (a masked lane reduction: no dynamic lane slice)."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    return jnp.sum(jnp.where(lanes == head, tile, 0.0), axis=1, keepdims=True)
+
+
+def _row_tiles(arr, bs: int):
+    """(b, S) per-position row → (b, S/bs, 1, bs): each tile a whole row."""
+    b, cap = arr.shape
+    return arr.reshape(b, cap // bs, 1, bs)
 
 
 def _gqa_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, nk, quantized):
@@ -88,7 +107,7 @@ def _gqa_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, nk, quantized):
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    ki = pl.program_id(2)
+    hi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -97,17 +116,16 @@ def _gqa_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, nk, quantized):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale              # (g8, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                   # (bs, hd)
+    k = k_ref[0].astype(jnp.float32)                         # (bs, hd)
+    v = v_ref[0].astype(jnp.float32)                         # (bs, hdv)
+    if quantized:  # per-(token, head) scales fold into the K / V rows
+        k = k * _head_column(ks_ref[0], hi)
+        v = v * _head_column(vs_ref[0], hi)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                        # (g8, bs)
-    if quantized:
-        s = s * ks_ref[0].reshape(1, -1)                     # (bs, 1) scales
-    s = s + mask_ref[...]                                    # (1, bs) additive
-    v = v_ref[0, :, 0].astype(jnp.float32)                   # (bs, hdv)
-    if quantized:
-        v = v * vs_ref[0]                                    # (bs, 1)
+    s = s + mask_ref[0, 0]                                   # (1, bs) additive
     _online_update(s, v, m_ref, l_ref, acc_ref)
 
     @pl.when(ki == nk - 1)
@@ -115,6 +133,59 @@ def _gqa_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, nk, quantized):
         l = l_ref[:, :1]
         inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
         o_ref[0, 0] = acc_ref[...] * inv
+
+
+def _gqa_call(q, k, v, kmask, k_scale, v_scale, *, q_map, kv_map,
+              scale_map, mask_map, grid, bs, nk, logit_scale, interpret,
+              prefetch=()):
+    """Shared pallas_call of the contiguous and paged GQA decode: the
+    kernel body and operand views are identical, only the KV / scale /
+    mask index maps (and the scalar-prefetched page table) differ."""
+    b, nkv, g8, hd = q.shape
+    hdv = v.shape[-1]
+    quantized = k_scale is not None
+    if quantized != (v_scale is not None):
+        raise ValueError("pass both k_scale and v_scale, or neither")
+    in_specs = [
+        pl.BlockSpec((1, 1, g8, hd), q_map),
+        pl.BlockSpec((1, bs, hd), kv_map),
+        pl.BlockSpec((1, bs, hdv), kv_map),
+        pl.BlockSpec((1, 1, 1, bs), mask_map),
+    ]
+    args = [q, k.reshape(k.shape[0], k.shape[1], nkv * hd),
+            v.reshape(v.shape[0], v.shape[1], nkv * hdv),
+            _row_tiles(kmask, bs)]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, bs, nkv), scale_map)] * 2
+        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    kern = functools.partial(
+        _gqa_kernel, scale=float(logit_scale), nk=nk, quantized=quantized)
+    if prefetch:
+        body = kern
+
+        def kern(*refs):  # scalar-prefetch operands arrive first
+            body(*refs[len(prefetch):])
+    out_spec = pl.BlockSpec((1, 1, g8, hdv), q_map)
+    scratch = [
+        pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((g8, hdv), jnp.float32),
+    ]
+    out_shape = jax.ShapeDtypeStruct((b, nkv, g8, hdv), jnp.float32)
+    if prefetch:
+        return pl.pallas_call(
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch), grid=grid,
+                in_specs=in_specs, out_specs=out_spec,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(*prefetch, *args)
+    return pl.pallas_call(
+        kern, grid=grid, in_specs=in_specs, out_specs=out_spec,
+        out_shape=out_shape, scratch_shapes=scratch, interpret=interpret,
+    )(*args)
 
 
 @functools.partial(
@@ -139,49 +210,21 @@ def attn_decode_gqa_pallas(
     per-head tiles, so no transposed copy of the cache ever exists.
     g8 must be a multiple of 8 and S of ``bs`` — the dispatch layer pads.
     """
-    b, nkv, g8, hd = q.shape
+    g8 = q.shape[2]
     cap = k.shape[1]
-    hdv = v.shape[-1]
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
     bs = min(bs, cap)
     if cap % bs or g8 % DECODE_ROWS:
         raise ValueError(
             f"cache length {cap} % tile {bs} or rows {g8} % {DECODE_ROWS}")
     nk = cap // bs
-    grid = (b, nkv, nk)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g8, hd), lambda bi, hi, ki: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda bi, hi, ki: (bi, ki, hi, 0)),
-        pl.BlockSpec((1, bs, 1, hdv), lambda bi, hi, ki: (bi, ki, hi, 0)),
-        pl.BlockSpec((1, bs), lambda bi, hi, ki: (bi, ki)),
-    ]
-    args = [q, k, v, kmask]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, 1), lambda bi, hi, ki: (bi, ki, hi)),
-            pl.BlockSpec((1, bs, 1), lambda bi, hi, ki: (bi, ki, hi)),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-
-    kern = functools.partial(
-        _gqa_kernel, scale=float(logit_scale), nk=nk, quantized=quantized)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g8, hdv),
-                               lambda bi, hi, ki: (bi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g8, hdv), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((g8, hdv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    return _gqa_call(
+        q, k, v, kmask, k_scale, v_scale,
+        q_map=lambda bi, hi, ki: (bi, hi, 0, 0),
+        kv_map=lambda bi, hi, ki: (bi, ki, hi),
+        scale_map=lambda bi, hi, ki: (bi, ki, 0),
+        mask_map=lambda bi, hi, ki: (bi, ki, 0, 0),
+        grid=(q.shape[0], q.shape[1], nk), bs=bs, nk=nk,
+        logit_scale=logit_scale, interpret=interpret)
 
 
 def _mla_kernel(ql_ref, qr_ref, c_ref, kr_ref, mask_ref, *rest, scale, nk,
@@ -206,16 +249,15 @@ def _mla_kernel(ql_ref, qr_ref, c_ref, kr_ref, mask_ref, *rest, scale, nk,
         ql, c, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                        # (nh8, bs)
+    cs = cs_ref[0, 0] if quantized else None                 # (1, bs)
     if quantized:
-        s_lat = s_lat * cs_ref[...]                          # (1, bs) scales
+        s_lat = s_lat * cs
     s = s_lat + jax.lax.dot_general(
         qr, kr, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    s = s * scale + mask_ref[...]
-    if quantized:
-        c = c * cs_ref[...].reshape(-1, 1)
-    _online_update(s, c, m_ref, l_ref, acc_ref)
+    s = s * scale + mask_ref[0, 0]
+    _online_update(s, c, m_ref, l_ref, acc_ref, p_scale=cs)
 
     @pl.when(ki == nk - 1)
     def _store():
@@ -259,12 +301,13 @@ def attn_decode_mla_pallas(
         pl.BlockSpec((1, nh8, rope), lambda bi, ki: (bi, 0, 0)),
         pl.BlockSpec((1, bs, lat), lambda bi, ki: (bi, ki, 0)),
         pl.BlockSpec((1, bs, rope), lambda bi, ki: (bi, ki, 0)),
-        pl.BlockSpec((1, bs), lambda bi, ki: (bi, ki)),
+        pl.BlockSpec((1, 1, 1, bs), lambda bi, ki: (bi, ki, 0, 0)),
     ]
-    args = [q_lat, q_rope, c, k_rope, kmask]
+    args = [q_lat, q_rope, c, k_rope, _row_tiles(kmask, bs)]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, bs), lambda bi, ki: (bi, ki)))
-        args.append(c_scale.astype(jnp.float32))
+        in_specs.append(
+            pl.BlockSpec((1, 1, 1, bs), lambda bi, ki: (bi, ki, 0, 0)))
+        args.append(_row_tiles(c_scale.astype(jnp.float32), bs))
 
     kern = functools.partial(
         _mla_kernel, scale=float(logit_scale), nk=nk, quantized=quantized)
@@ -311,65 +354,23 @@ def attn_decode_gqa_paged_pallas(
     inside the index maps (scalar prefetch) — the pool is read once, as
     stored, with scales folded in-kernel.  Returns (b, nkv, g8, hd_v) f32.
     """
-    b, nkv, g8, hd = q.shape
+    b, nkv, g8, _ = q.shape
     ps = k_pool.shape[1]
-    hdv = v_pool.shape[-1]
     npages = pt.shape[1]
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
     if ps % 8 or g8 % DECODE_ROWS:
         raise ValueError(
             f"page size {ps} % 8 or rows {g8} % {DECODE_ROWS}")
     if kmask.shape != (b, npages * ps):
         raise ValueError(
             f"kmask {kmask.shape} != (b, np*ps) = {(b, npages * ps)}")
-    grid = (b, nkv, npages)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g8, hd), lambda bi, hi, ki, pt_ref: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, hi, 0)),
-        pl.BlockSpec((1, ps, 1, hdv),
-                     lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, hi, 0)),
-        pl.BlockSpec((1, ps), lambda bi, hi, ki, pt_ref: (bi, ki)),
-    ]
-    args = [q, k_pool, v_pool, kmask]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, ps, 1),
-                         lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, hi)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, hi)),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-
-    body = functools.partial(
-        _gqa_kernel, scale=float(logit_scale), nk=npages,
-        quantized=quantized)
-
-    def kern(pt_ref, *refs):  # scalar-prefetch operand arrives first
-        del pt_ref
-        body(*refs)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g8, hdv),
-                               lambda bi, hi, ki, pt_ref: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((g8, hdv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g8, hdv), jnp.float32),
-        interpret=interpret,
-    )(pt, *args)
+    return _gqa_call(
+        q, k_pool, v_pool, kmask, k_scale, v_scale,
+        q_map=lambda bi, hi, ki, pt_ref: (bi, hi, 0, 0),
+        kv_map=lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, hi),
+        scale_map=lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, 0),
+        mask_map=lambda bi, hi, ki, pt_ref: (bi, ki, 0, 0),
+        grid=(b, nkv, npages), bs=ps, nk=npages,
+        logit_scale=logit_scale, interpret=interpret, prefetch=(pt,))
 
 
 @functools.partial(jax.jit, static_argnames=("logit_scale", "interpret"))
@@ -410,13 +411,13 @@ def attn_decode_mla_paged_pallas(
                      lambda bi, ki, pt_ref: (pt_ref[bi, ki], 0, 0)),
         pl.BlockSpec((1, ps, rope),
                      lambda bi, ki, pt_ref: (pt_ref[bi, ki], 0, 0)),
-        pl.BlockSpec((1, ps), lambda bi, ki, pt_ref: (bi, ki)),
+        pl.BlockSpec((1, 1, 1, ps), lambda bi, ki, pt_ref: (bi, ki, 0, 0)),
     ]
-    args = [q_lat, q_rope, c_pool, k_rope_pool, kmask]
+    args = [q_lat, q_rope, c_pool, k_rope_pool, _row_tiles(kmask, ps)]
     if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda bi, ki, pt_ref: (pt_ref[bi, ki], 0)))
-        args.append(c_scale.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, ps), lambda bi, ki, pt_ref: (pt_ref[bi, ki], 0, 0, 0)))
+        args.append(c_scale.astype(jnp.float32).reshape(-1, 1, 1, ps))
 
     body = functools.partial(
         _mla_kernel, scale=float(logit_scale), nk=npages,

@@ -11,11 +11,13 @@ ref oracle, but peaks at a (b, nh, chunk, S) f32 temporary the kernel
 never creates.
 
 Layout / tiling — all operands are indexed in the model's native
-(batch, seq, heads, head_dim) layout (no host-side transpose copies):
+(batch, seq, heads, head_dim) layout, viewed as (batch, seq, heads·head_dim)
+(a free reshape, no transpose copy), so a head is a lane-aligned hd-wide
+column block:
   grid = (b, nh, s/bq, S/bkv), KV innermost (the online-softmax reduction)
-    q tile    (1, bq, 1, hd)    — constant over the KV axis (VMEM-resident
+    q tile    (1, bq, hd)       — constant over the KV axis (VMEM-resident
                                   per Q tile)
-    k/v tile  (1, bkv, 1, hd)   — head-indexed ``h // group`` so GQA heads
+    k/v tile  (1, bkv, hd)      — head-indexed ``h // group`` so GQA heads
                                   read their shared KV head straight from
                                   the unexpanded (b, S, nkv, hd) arrays:
                                   the head-group broadcast costs zero HBM
@@ -59,8 +61,8 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale           # (bq, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                   # (bkv, hd)
+    q = q_ref[0].astype(jnp.float32) * scale                 # (bq, hd)
+    k = k_ref[0].astype(jnp.float32)                         # (bkv, hd)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -83,7 +85,7 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
     m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
 
-    v = v_ref[0, :, 0].astype(jnp.float32)                   # (bkv, hd)
+    v = v_ref[0].astype(jnp.float32)                         # (bkv, hd)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -93,7 +95,7 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
     def _store():
         l = l_ref[:, :1]
         inv = jnp.where(l == 0.0, 0.0, 1.0 / l)              # dead rows -> 0
-        o_ref[0, :, 0] = acc_ref[...] * inv
+        o_ref[0] = acc_ref[...] * inv
 
 
 @functools.partial(
@@ -131,27 +133,29 @@ def attn_prefill_pallas(
     grid = (b, nh, s // bq, nk)
 
     kern = functools.partial(_kernel, scale=float(logit_scale), nk=nk)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
+            pl.BlockSpec((1, bq, hd), lambda bi, hi, qi, ki: (bi, qi, hi)),
             # GQA broadcast in the index map: head hi reads KV head hi//g
-            pl.BlockSpec((1, bkv, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-            pl.BlockSpec((1, bkv, 1, hdv),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
+            pl.BlockSpec((1, bkv, hd),
+                         lambda bi, hi, qi, ki: (bi, ki, hi // group)),
+            pl.BlockSpec((1, bkv, hdv),
+                         lambda bi, hi, qi, ki: (bi, ki, hi // group)),
             pl.BlockSpec((1, bq, 1), lambda bi, hi, qi, ki: (bi, qi, 0)),
             pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hdv),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, nh, hdv), jnp.float32),
+        out_specs=pl.BlockSpec((1, bq, hdv),
+                               lambda bi, hi, qi, ki: (bi, qi, hi)),
+        out_shape=jax.ShapeDtypeStruct((b, s, nh * hdv), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
             pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
             pltpu.VMEM((bq, hdv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, qpos.reshape(b, s, 1), kpos.reshape(b, 1, cap))
+    )(q.reshape(b, s, nh * hd), k.reshape(b, cap, nkv * hd),
+      v.reshape(b, cap, nkv * hdv), qpos.reshape(b, s, 1),
+      kpos.reshape(b, 1, cap))
+    return out.reshape(b, s, nh, hdv)

@@ -3,8 +3,8 @@
 Same contract as :mod:`repro.kernels.lords_matmul` but with piecewise-constant
 block scales instead of the low-rank S = B·A.  Exists so the Fig.-2 style
 kernel comparison (bnb-NF4 vs QLoRA vs LoRDS) is apples-to-apples on TPU.
-Shares ``_lut_select`` with the lords kernels, so the LUT gather here is the
-same one-hot × lut MXU matmul (select-chain only for wide int8 tables).
+Shares the plane unpack and the bit-tree LUT select with the lords kernels
+(:mod:`repro.kernels.lords_matmul`).
 
 y[M,N] = x[M,K] @ (lut[Q] ⊙ repeat(s_blk))ᵀ
 """
@@ -18,28 +18,34 @@ from jax.experimental import pallas as pl
 
 from repro.core import lut as lut_mod
 from repro.core import quantize as quantize_mod
-from repro.kernels.lords_matmul import _lut_select, _unpack_tile
+from repro.kernels.lords_matmul import (
+    byte_plane_specs,
+    code_plane,
+    k_step,
+    lut_select,
+    plane_tiles,
+)
+from repro.kernels.lords_matmul_t import (
+    block_scale_spec,
+    expand_scales,
+    scales_view,
+)
 
 __all__ = ["block_matmul_pallas"]
 
 
-def _kernel(x_ref, q_ref, s_ref, lut_ref, o_ref, *, ps, n_levels, reps):
-    k = pl.program_id(2)
+def _kernel(x_ref, *refs, ps, levels, reps, nk):
+    *q_refs, s_ref, o_ref = refs
+    kk = pl.program_id(2)
 
-    @pl.when(k == 0)
+    @pl.when(kk == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = _unpack_tile(q_ref[...], ps)
-    vals = _lut_select(codes, lut_ref, n_levels)
-    s = s_ref[...]  # (bn, bk // block_size) or (bn, 1)
-    bn, nblk = s.shape
-    s_full = jnp.broadcast_to(s[:, :, None], (bn, nblk, reps)).reshape(
-        bn, nblk * reps
-    )
-    if s_full.shape[1] != vals.shape[1]:  # block spans multiple k tiles
-        s_full = jnp.broadcast_to(s, vals.shape)
-    w = (vals * s_full).astype(x_ref.dtype)
+    p, _, _ = k_step(kk, ps.group_codes, nk)
+    vals = lut_select(code_plane(q_refs, ps, p), levels)
+    w = (vals * expand_scales(s_ref[0], reps, vals.shape[1])).astype(
+        x_ref.dtype)
     o_ref[...] += jax.lax.dot_general(
         x_ref[...], w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -66,35 +72,30 @@ def block_matmul_pallas(
     m, kdim = x.shape
     n = q_packed.shape[0]
     ps = quantize_mod.pack_spec(codebook_name)
-    levels = lut_mod.codebook(codebook_name)
-    n_levels = levels.shape[0]
+    g = ps.group_codes
 
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, kdim)
-    if m % bm or n % bn or kdim % bk or bk % ps.group_codes:
+    if m % bm or n % bn:
         raise ValueError(f"({m},{n},{kdim}) not divisible by ({bm},{bn},{bk})")
-    if not (bk % block_size == 0 or block_size % bk == 0):
-        raise ValueError(f"bk {bk} incompatible with block_size {block_size}")
-    grid = (m // bm, n // bn, kdim // bk)
+    t, nk = plane_tiles(kdim, bk, ps)
+    grid = (m // bm, n // bn, g * nk)
+    tile = lambda i, j, kk: k_step(kk, g, nk)[2]  # noqa: E731
+    s_spec, c, reps = block_scale_spec(bn, t, block_size, tile,
+                                       lambda i, j, kk: j)
 
-    if bk >= block_size:
-        s_cols, reps = bk // block_size, block_size
-        s_index = lambda i, j, k: (j, k)
-    else:
-        s_cols, reps = 1, bk
-        s_index = lambda i, j, k: (j, k // (block_size // bk))
-
-    lut_arr = levels.reshape(1, -1).astype(jnp.float32)
-    kern = functools.partial(_kernel, ps=ps, n_levels=n_levels, reps=reps)
+    kern = functools.partial(_kernel, ps=ps,
+                             levels=lut_mod.static_levels(codebook_name),
+                             reps=reps, nk=nk)
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, ps.packed_width(bk)), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bn, s_cols), s_index),
-            pl.BlockSpec((1, n_levels), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((bm, t), lambda *grid: (grid[0], tile(*grid))),
+            *byte_plane_specs(ps, bn, t, nk,
+                              lambda i, j, kk: (j, kk // g)),
+            s_spec,
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, q_packed, s_blk.astype(jnp.float32), lut_arr)
+    )(x, *[q_packed] * ps.group_bytes, scales_view(s_blk, c))

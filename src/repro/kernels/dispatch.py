@@ -87,9 +87,9 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
+from repro.core.quantize import repack_width
 from repro.kernels import ref
 from repro.kernels.attn_decode import (
     DECODE_ROWS,
@@ -364,6 +364,17 @@ def load_autotune_table(path: str | None = None, *,
 load_autotune_table()  # import-time: benchmark-found tiles from prior runs
 
 
+def _dividing_tile(extent: int, unit: int, cap: int) -> int:
+    """The largest multiple of ``unit`` up to ``cap`` that divides
+    ``extent``, so the operand needs no padding; else ``cap`` shrunk to the
+    extent rounded up to ``unit``."""
+    if extent % unit == 0:
+        for t in range(cap // unit * unit, 0, -unit):
+            if extent % t == 0:
+                return t
+    return min(cap, _round_up(extent, unit))
+
+
 def tile_for(method: str, m: int, n: int, k: int, codebook: str, dtype,
              block_size: int | None = None) -> tuple[int, int, int]:
     """Tile choice: autotune-table hit, else a lane-aligned heuristic.
@@ -371,18 +382,21 @@ def tile_for(method: str, m: int, n: int, k: int, codebook: str, dtype,
     Defaults follow the kernel docstrings (bm 128 / bn 256 / bk 512), shrunk
     to the (padded) problem: bm to a sublane multiple, bn/bk to lane
     multiples, bk additionally to a pack multiple and — for blockwise — to a
-    block_size-compatible value (bk % bs == 0 or bs % bk == 0).
+    block_size-compatible value (the per-plane width bk/g nests with bs).
+    bn and bk prefer a smaller tile that divides N / K over padding: padding
+    K re-packs the whole code matrix on every call (:func:`_pad_codes`).
     """
     hit = lookup_tiles(method, m, n, k, codebook, dtype, block_size)
     if hit is not None:
         return hit
     ps = _spec_of(codebook)
     bm = min(128, _round_up(m, 8))
-    bn = min(256, _round_up(n, 128))
+    bn = _dividing_tile(n, 128, 256)
     if ps.group_bytes == 1:
         # historical unit: bk a multiple of 128·codes-per-byte so the packed
         # q tile width stays lane-aligned
-        bk = min(512, _round_up(k, 128 * ps.group_codes))
+        unit = 128 * ps.group_codes
+        bk = _dividing_tile(k, unit, max(512, unit))
     else:
         # cross-byte groups (3-bit): prefer the smallest bk whose packed
         # width is lane-aligned (1024 → 384 bytes = 3 lanes); for small K
@@ -392,12 +406,14 @@ def tile_for(method: str, m: int, n: int, k: int, codebook: str, dtype,
         bk = min(max(512, unit),
                  _round_up(k, math.lcm(ps.group_codes, 128)))
     if block_size is not None:
-        if bk >= block_size:
-            bk = max(block_size, (bk // block_size) * block_size)
-        elif block_size % bk:
-            bk = math.gcd(bk, block_size) or block_size
-        if bk % ps.group_codes:  # exotic block sizes: keep whole groups
-            bk = _round_up(bk, ps.group_codes)
+        # each kernel step covers t = bk/g columns of one code plane; t and
+        # the block size must nest (t % bs == 0 or bs % t == 0)
+        t = bk // ps.group_codes
+        if t >= block_size:
+            t = (t // block_size) * block_size
+        elif block_size % t:
+            t = math.gcd(t, block_size)
+        bk = t * ps.group_codes
     return bm, bn, bk
 
 
@@ -406,6 +422,17 @@ def _pad2(arr, rows, cols):
     if pr == 0 and pc == 0:
         return arr
     return jnp.pad(arr, ((0, pr), (0, pc)))
+
+
+def _pad_codes(q_packed, rows, kp, codebook):
+    """Packed codes padded to ``rows`` and a logical K of ``kp``.  Rows pad
+    with zero bytes; K re-packs (a plane is K/group wide, so a wider row's
+    planes are not a byte-prefix of the narrow one's), which unpacks and
+    packs the whole matrix.  :func:`tile_for` picks a bk dividing K wherever
+    a lane-aligned one exists, so only K that is not a multiple of
+    128·group codes (e.g. 896 at nf4) takes this path."""
+    q_packed = repack_width(q_packed, kp, codebook)
+    return _pad2(q_packed, rows, q_packed.shape[1])
 
 
 # ---------------------------------------------------------------------------
@@ -418,7 +445,6 @@ def _lords_forward(x2d, q_packed, b, a, codebook, backend, tiles):
         return ref.lords_matmul_ref(x2d, q_packed, b, a, codebook)
     m, k = x2d.shape
     n = q_packed.shape[0]
-    ps = _spec_of(codebook)
     bm, bn, bk = tiles or tile_for("lords", m, n, k, codebook, x2d.dtype)
     interp = backend == "interpret"
     if m <= DECODE_M_MAX:
@@ -427,7 +453,7 @@ def _lords_forward(x2d, q_packed, b, a, codebook, backend, tiles):
         np_, kp = _round_up(n, bn), _round_up(k, bk)
         y = lords_decode_pallas(
             _pad2(x2d, m, kp),
-            _pad2(q_packed, np_, ps.packed_width(kp)),
+            _pad_codes(q_packed, np_, kp, codebook),
             _pad2(b, np_, b.shape[1]),
             _pad2(a, a.shape[0], kp),
             codebook,
@@ -438,7 +464,7 @@ def _lords_forward(x2d, q_packed, b, a, codebook, backend, tiles):
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
     y = lords_matmul_pallas(
         _pad2(x2d, mp, kp),
-        _pad2(q_packed, np_, ps.packed_width(kp)),
+        _pad_codes(q_packed, np_, kp, codebook),
         _pad2(b, np_, b.shape[1]),
         _pad2(a, a.shape[0], kp),
         codebook,
@@ -458,14 +484,13 @@ def _lords_grads(g, x2d, q_packed, b, a, w, codebook, backend):
         return ref.lords_grads_ref(g, x2d, q_packed, b, a, codebook, w=w)
     m, k = x2d.shape
     n = q_packed.shape[0]
-    ps = _spec_of(codebook)
     # the `transposed` autotune key: one tile triple drives both bwd kernels
     bm, bn, bk = tile_for("lords_t", m, n, k, codebook, jnp.float32)
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
     interp = backend == "interpret"
     g32 = _pad2(g.astype(jnp.float32), mp, np_)
     x32 = _pad2(x2d.astype(jnp.float32), mp, kp)
-    qp = _pad2(q_packed, np_, ps.packed_width(kp))
+    qp = _pad_codes(q_packed, np_, kp, codebook)
     bp = _pad2(b.astype(jnp.float32), np_, b.shape[1])
     ap = _pad2(a.astype(jnp.float32), a.shape[0], kp)
     dx = lords_matmul_t_pallas(
@@ -529,9 +554,10 @@ def _lords_qat_forward(x2d, w, b, a, codebook, backend, tiles):
         _pad2(x2d, mp, kp), qp, bp, ap, codebook,
         bm=bm, bn=bn, bk=bk, interpret=interp,
     )
-    # slice codes back to the logical K, rounded up to whole pack groups —
+    # codes back at the logical K, rounded up to whole pack groups —
     # trailing codes past k (if any) decode under zero-padded activations
-    return y[:m, :n], qp[:n, : ps.packed_width(_round_up(k, ps.group_codes))]
+    return y[:m, :n], repack_width(qp[:n], _round_up(k, ps.group_codes),
+                                   codebook)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -563,14 +589,15 @@ _lords_qat_qmatmul.defvjp(_lords_qat_fwd, _lords_qat_bwd)
 # ---------------------------------------------------------------------------
 
 
-def _block_padded(q_packed, s_blk, m, n, k, block_size, bm, bn, bk, ps):
+def _block_padded(q_packed, s_blk, m, n, k, block_size, bm, bn, bk,
+                  codebook):
     """Shared fwd/bwd block-operand padding: K rounds to lcm(bk, block_size)
     so tiles and blocks stay commensurate, padded scales are 1.0 (never the
     eps clamp), padded rows/cols contribute zeros.  One helper so the
     forward and its VJP can never pad differently."""
     kmult = bk * block_size // math.gcd(bk, block_size)  # lcm: tiles + blocks
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, kmult)
-    qp = _pad2(q_packed, np_, ps.packed_width(kp))
+    qp = _pad_codes(q_packed, np_, kp, codebook)
     s_pad = jnp.pad(
         s_blk,
         ((0, np_ - n), (0, kp // block_size - s_blk.shape[1])),
@@ -584,11 +611,10 @@ def _block_forward(x2d, q_packed, s_blk, block_size, codebook, backend, tiles):
         return ref.block_matmul_ref(x2d, q_packed, s_blk, block_size, codebook)
     m, k = x2d.shape
     n = q_packed.shape[0]
-    ps = _spec_of(codebook)
     bm, bn, bk = tiles or tile_for(
         "blockwise", m, n, k, codebook, x2d.dtype, block_size=block_size)
     qp, s_pad, mp, np_, kp = _block_padded(
-        q_packed, s_blk, m, n, k, block_size, bm, bn, bk, ps)
+        q_packed, s_blk, m, n, k, block_size, bm, bn, bk, codebook)
     y = block_matmul_pallas(
         _pad2(x2d, mp, kp),
         qp,
@@ -621,12 +647,11 @@ def _block_grads(g, x2d, q_packed, s_blk, block_size, codebook, backend):
                                    codebook)
     m, k = x2d.shape
     n = q_packed.shape[0]
-    ps = _spec_of(codebook)
     bm, bn, bk = tile_for("blockwise_t", m, n, k, codebook, jnp.float32,
                           block_size=block_size)
     qp, s_pad, mp, np_, kp = _block_padded(
         q_packed, s_blk.astype(jnp.float32), m, n, k, block_size,
-        bm, bn, bk, ps)
+        bm, bn, bk, codebook)
     interp = backend == "interpret"
     g32 = _pad2(g.astype(jnp.float32), mp, np_)
     x32 = _pad2(x2d.astype(jnp.float32), mp, kp)
@@ -705,11 +730,11 @@ def _tp_specs(axis, batch: tuple):
 def _shlords_qmatmul(x2d, q_packed, b, a, codebook, backend, mesh, axis,
                      tiles):
     xs, row, rep, out = _tp_specs(axis, _dp_axes(mesh, axis, x2d.shape[0]))
-    return shard_map(
+    return jax.shard_map(
         lambda xl, ql, bl, al: _lords_forward(
             xl, ql, bl, al, codebook, backend, tiles),
         mesh=mesh, in_specs=(xs, row, row, rep), out_specs=out,
-        check_rep=False,
+        check_vma=False,
     )(x2d, q_packed, b, a)
 
 
@@ -728,9 +753,9 @@ def _shlords_bwd(codebook, backend, mesh, axis, tiles, res, g):
         dx, db, da = _lords_grads(gl, xl, ql, bl, al, None, codebook, backend)
         return jax.lax.psum(dx, axis), _psum(db, dp), _psum(da, dp + (axis,))
 
-    dx, db, da = shard_map(
+    dx, db, da = jax.shard_map(
         body, mesh=mesh, in_specs=(out, xs, row, row, rep),
-        out_specs=(xs, row, rep), check_rep=False,
+        out_specs=(xs, row, rep), check_vma=False,
     )(g, x2d, q_packed, b, a)
     dq = np.zeros(q_packed.shape, jax.dtypes.float0)
     return (dx.astype(x2d.dtype), dq, db.astype(b.dtype), da.astype(a.dtype))
@@ -742,11 +767,11 @@ _shlords_qmatmul.defvjp(_shlords_fwd, _shlords_bwd)
 def _shlords_qat_forward(x2d, w, b, a, codebook, backend, mesh, axis, tiles):
     """Shared primal/fwd body: returns (y, row-sharded packed codes)."""
     xs, row, rep, out = _tp_specs(axis, _dp_axes(mesh, axis, x2d.shape[0]))
-    return shard_map(
+    return jax.shard_map(
         lambda xl, wl, bl, al: _lords_qat_forward(
             xl, wl, bl, al, codebook, backend, tiles),
         mesh=mesh, in_specs=(xs, row, row, rep), out_specs=(out, row),
-        check_rep=False,
+        check_vma=False,
     )(x2d, w, b, a)
 
 
@@ -776,9 +801,9 @@ def _shlords_qat_bwd(codebook, backend, mesh, axis, tiles, res, g):
         return (jax.lax.psum(dx, axis), _psum(db, dp),
                 _psum(da, dp + (axis,)), _psum(dw, dp))
 
-    dx, db, da, dw = shard_map(
+    dx, db, da, dw = jax.shard_map(
         body, mesh=mesh, in_specs=(out, xs, row, row, rep, row),
-        out_specs=(xs, row, rep, row), check_rep=False,
+        out_specs=(xs, row, rep, row), check_vma=False,
     )(g, x2d, q_packed, b, a, w)
     return (dx.astype(x2d.dtype), dw.astype(w.dtype),
             db.astype(b.dtype), da.astype(a.dtype))
@@ -791,11 +816,11 @@ _shlords_qat_qmatmul.defvjp(_shlords_qat_fwd, _shlords_qat_bwd)
 def _shblock_qmatmul(x2d, q_packed, s_blk, block_size, codebook, backend,
                      mesh, axis, tiles):
     xs, row, rep, out = _tp_specs(axis, _dp_axes(mesh, axis, x2d.shape[0]))
-    return shard_map(
+    return jax.shard_map(
         lambda xl, ql, sl: _block_forward(
             xl, ql, sl, block_size, codebook, backend, tiles),
         mesh=mesh, in_specs=(xs, row, row), out_specs=out,
-        check_rep=False,
+        check_vma=False,
     )(x2d, q_packed, s_blk)
 
 
@@ -815,9 +840,9 @@ def _shblock_bwd(block_size, codebook, backend, mesh, axis, tiles, res, g):
         dx, ds = _block_grads(gl, xl, ql, sl, block_size, codebook, backend)
         return jax.lax.psum(dx, axis), _psum(ds, dp)
 
-    dx, ds_blk = shard_map(
+    dx, ds_blk = jax.shard_map(
         body, mesh=mesh, in_specs=(out, xs, row, row),
-        out_specs=(xs, row), check_rep=False,
+        out_specs=(xs, row), check_vma=False,
     )(g, x2d, q_packed, s_blk)
     dq = np.zeros(q_packed.shape, jax.dtypes.float0)
     return dx.astype(x2d.dtype), dq, ds_blk.astype(s_blk.dtype)
@@ -1055,11 +1080,11 @@ def _attn_prefill_fused(q, k, v, positions, logit_scale, backend, tiles):
     bspec = dp if len(dp) > 1 else (dp[0] if dp else None)
     hspec = PartitionSpec(bspec, None, axis, None)
     pspec = PartitionSpec(bspec, None)
-    return shard_map(
+    return jax.shard_map(
         lambda ql, kl, vl, pl_: _attn_prefill_run(
             ql, kl, vl, pl_, logit_scale, backend, tiles),
         mesh=mesh, in_specs=(hspec, hspec, hspec, pspec), out_specs=hspec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, positions)
 
 
@@ -1128,11 +1153,11 @@ def _attn_chunk_fused(q, k, v, qpos, kpos, logit_scale, backend, tiles):
     bspec = dp if len(dp) > 1 else (dp[0] if dp else None)
     hspec = PartitionSpec(bspec, None, axis, None)
     pspec = PartitionSpec(bspec, None)
-    return shard_map(
+    return jax.shard_map(
         lambda ql, kl, vl, qpl, kpl: _attn_chunk_run(
             ql, kl, vl, qpl, kpl, logit_scale, backend, tiles),
         mesh=mesh, in_specs=(hspec, hspec, hspec, pspec, pspec),
-        out_specs=hspec, check_rep=False,
+        out_specs=hspec, check_vma=False,
     )(q, k, v, qpos, kpos)
 
 
@@ -1186,15 +1211,15 @@ def _attn_decode_fused(q, k, v, pos, k_scale, v_scale, logit_scale, backend,
                                 backend, tiles)
 
     if k_scale is None:
-        return shard_map(
+        return jax.shard_map(
             lambda ql, kl, vl, posl: body(ql, kl, vl, posl, None, None),
             mesh=mesh, in_specs=(qspec, cspec, cspec, pspec),
-            out_specs=qspec, check_rep=False,
+            out_specs=qspec, check_vma=False,
         )(q, k, v, pos)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, cspec, cspec, pspec, sspec, sspec),
-        out_specs=qspec, check_rep=False,
+        out_specs=qspec, check_vma=False,
     )(q, k, v, pos, k_scale, v_scale)
 
 
@@ -1243,16 +1268,16 @@ def _attn_mla_fused(q_lat, q_rope, c, k_rope, pos, c_scale, logit_scale,
                              backend, tiles)
 
     if c_scale is None:
-        return shard_map(
+        return jax.shard_map(
             lambda qll, qrl, cl, krl, posl: body(qll, qrl, cl, krl, posl,
                                                  None),
             mesh=mesh, in_specs=(qspec, qspec, cspec, cspec, pspec),
-            out_specs=qspec, check_rep=False,
+            out_specs=qspec, check_vma=False,
         )(q_lat, q_rope, c, k_rope, pos)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, qspec, cspec, cspec, pspec, sspec),
-        out_specs=qspec, check_rep=False,
+        out_specs=qspec, check_vma=False,
     )(q_lat, q_rope, c, k_rope, pos, c_scale)
 
 
@@ -1299,17 +1324,17 @@ def _attn_paged_fused(q, k_pool, v_pool, pt, pos, k_scale, v_scale,
                                backend)
 
     if k_scale is None:
-        return shard_map(
+        return jax.shard_map(
             lambda ql, kl, vl, ptl, posl: body(ql, kl, vl, ptl, posl, None,
                                                None),
             mesh=mesh, in_specs=(qspec, poolspec, poolspec, ptspec, pspec),
-            out_specs=qspec, check_rep=False,
+            out_specs=qspec, check_vma=False,
         )(q, k_pool, v_pool, pt, pos)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, poolspec, poolspec, ptspec, pspec, spoolspec,
                   spoolspec),
-        out_specs=qspec, check_rep=False,
+        out_specs=qspec, check_vma=False,
     )(q, k_pool, v_pool, pt, pos, k_scale, v_scale)
 
 
@@ -1353,18 +1378,18 @@ def _attn_mla_paged_fused(q_lat, q_rope, c_pool, k_rope_pool, pt, pos,
                                    logit_scale, backend)
 
     if c_scale is None:
-        return shard_map(
+        return jax.shard_map(
             lambda qll, qrl, cl, krl, ptl, posl: body(qll, qrl, cl, krl,
                                                       ptl, posl, None),
             mesh=mesh,
             in_specs=(qspec, qspec, poolspec, poolspec, ptspec, pspec),
-            out_specs=qspec, check_rep=False,
+            out_specs=qspec, check_vma=False,
         )(q_lat, q_rope, c_pool, k_rope_pool, pt, pos)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, qspec, poolspec, poolspec, ptspec, pspec,
                   spoolspec),
-        out_specs=qspec, check_rep=False,
+        out_specs=qspec, check_vma=False,
     )(q_lat, q_rope, c_pool, k_rope_pool, pt, pos, c_scale)
 
 

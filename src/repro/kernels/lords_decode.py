@@ -31,7 +31,9 @@ that invariant rather than around MXU occupancy like the prefill kernel
     keeping it out of the kernel keeps the accumulator tile pure f32 and
     the kernel shape-agnostic about what the caller chains after it).
 
-Per tile:  S = bTᵀ·a  (rank-r contraction), W = lut[q] ⊙ S, acc += x·Wᵀ —
+Per tile, for each code plane of the packed tile (its contiguous K slice
+of the resident x / a — see :mod:`repro.core.quantize` for the layout):
+S = bTᵀ·a  (rank-r contraction), W = lut[q] ⊙ S, acc += x·Wᵀ —
 identical math to the prefill kernel, so the pure-jnp oracle
 (:func:`repro.kernels.ref.lords_matmul_ref`) is the parity reference for
 both.
@@ -47,34 +49,42 @@ from jax.experimental import pallas as pl
 from repro.core import lut as lut_mod
 from repro.core import quantize as quantize_mod
 from repro.core.scaling import clamp_scale
-from repro.kernels.lords_matmul import _lut_select, _unpack_tile
+from repro.kernels.lords_matmul import (
+    byte_plane_specs,
+    code_plane,
+    lut_select,
+    plane_tiles,
+)
 
 __all__ = ["lords_decode_pallas", "DECODE_M_MAX"]
 
 DECODE_M_MAX = 8  # one f32 sublane tile: the M-bucket this kernel serves
 
 
-def _kernel(x_ref, q_ref, bt_ref, a_ref, lut_ref, o_ref, *, ps, n_levels,
-            eps, bk):
+def _kernel(x_ref, *refs, ps, levels, eps, t, kplane):
+    *q_refs, bt_ref, a_ref, o_ref = refs
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ks = pl.multiple_of(k * bk, bk)  # live K columns of the resident x/a
-    codes = _unpack_tile(q_ref[...], ps)                      # (bn, bk)
-    vals = _lut_select(codes, lut_ref, n_levels)              # (bn, bk) f32
-    s = jax.lax.dot_general(
-        bt_ref[...], a_ref[:, pl.ds(ks, bk)], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                          # (bn, bk)
-    s = clamp_scale(s, eps)
-    w = (vals * s).astype(x_ref.dtype)                        # (bn, bk)
-    o_ref[...] += jax.lax.dot_general(
-        x_ref[:, pl.ds(ks, bk)], w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                          # (8, bn)
+    ks = pl.multiple_of(k * t, t)
+    # every code plane of the packed tile in one step: plane p covers the
+    # resident x/a columns p*K/g + [k*t, (k+1)*t)
+    for p in range(ps.group_codes):
+        cols = pl.ds(p * kplane + ks, t)
+        vals = lut_select(code_plane(q_refs, ps, p), levels)  # (bn, t) f32
+        s = jax.lax.dot_general(
+            bt_ref[...], a_ref[:, cols], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                      # (bn, t)
+        s = clamp_scale(s, eps)
+        w = (vals * s).astype(x_ref.dtype)                    # (bn, t)
+        o_ref[...] += jax.lax.dot_general(
+            x_ref[:, cols], w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                      # (8, bn)
 
 
 @functools.partial(
@@ -104,23 +114,21 @@ def lords_decode_pallas(
             "use lords_matmul_pallas for prefill-shaped inputs"
         )
     ps = quantize_mod.pack_spec(codebook_name)
-    levels = lut_mod.codebook(codebook_name)
-    n_levels = levels.shape[0]
 
     bn = min(bn, n)
     bk = min(bk, kdim)
-    if n % bn or kdim % bk or bk % ps.group_codes:
+    if n % bn:
         raise ValueError(
             f"shape (N={n}, K={kdim}) not divisible by blocks ({bn},{bk})"
         )
+    t, nk = plane_tiles(kdim, bk, ps)
     if m < DECODE_M_MAX:  # pad M to the f32 sublane tile; sliced off below
         x = jnp.pad(x, ((0, DECODE_M_MAX - m), (0, 0)))
-    grid = (n // bn, kdim // bk)  # K innermost: weights stream exactly once
+    grid = (n // bn, nk)  # K innermost: weights stream exactly once
 
-    bt = b.T  # (r, N)
-    lut_arr = levels.reshape(1, -1).astype(jnp.float32)
     kern = functools.partial(
-        _kernel, ps=ps, n_levels=n_levels, eps=SCALE_EPS, bk=bk
+        _kernel, ps=ps, levels=lut_mod.static_levels(codebook_name),
+        eps=SCALE_EPS, t=t, kplane=kdim // ps.group_codes,
     )
     y = pl.pallas_call(
         kern,
@@ -128,15 +136,14 @@ def lords_decode_pallas(
         in_specs=[
             # x and a: constant index map = fetched once, VMEM-resident
             pl.BlockSpec((DECODE_M_MAX, kdim), lambda j, k: (0, 0)),
-            pl.BlockSpec((bn, ps.packed_width(bk)), lambda j, k: (j, k)),
+            *byte_plane_specs(ps, bn, t, nk, lambda j, k: (j, k)),
             pl.BlockSpec((r, bn), lambda j, k: (0, j)),
             pl.BlockSpec((r, kdim), lambda j, k: (0, 0)),
-            pl.BlockSpec((1, n_levels), lambda j, k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((DECODE_M_MAX, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((DECODE_M_MAX, n), jnp.float32),
         interpret=interpret,
-    )(x, q_packed, bt, a, lut_arr)
+    )(x, *[q_packed] * ps.group_bytes, b.T, a)
     y = y[:m]
     if residual is not None:
         y = y + residual.astype(y.dtype)

@@ -18,8 +18,10 @@ VMEM scratch (never HBM) and collapse it straight into the small outputs:
       ∂S = ∂L/∂Ŵ ⊙ (lut[Q] − W ⊘ S) ⊙ 1[|S| ≥ eps]  Eq. 5
       dB / dA as above
 
-Tiling:  grid = (N/bn, K/bk, M/bm), M innermost (the ∂L/∂Ŵ reduction).
-Per (j, k) tile the scratch ``acc`` (bn, bk) f32 accumulates gᵀ·x over the
+Tiling:  grid = (N/bn, g·K/bk, M/bm), M innermost (the ∂L/∂Ŵ reduction);
+each K step is one code plane of a packed tile, as in
+:mod:`repro.kernels.lords_matmul` (``t = bk/g`` logical columns).
+Per (j, k) tile the scratch ``acc`` (bn, t) f32 accumulates gᵀ·x over the
 M axis; at the last M step the tile is dequant-masked and contracted on the
 MXU into the rank-space outputs.  The q/bT/a (and W for qat) tiles have
 M-independent index maps, so Pallas fetches each exactly once per (j, k) —
@@ -51,40 +53,50 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import lut as lut_mod
 from repro.core import quantize as quantize_mod
 from repro.core.scaling import clamp_scale
-from repro.kernels.lords_matmul import _lut_select, _unpack_tile
+from repro.kernels.lords_matmul import (
+    byte_plane_specs,
+    code_plane,
+    k_step,
+    lut_select,
+    plane_tiles,
+)
+from repro.kernels.lords_matmul_t import block_scale_spec
 
 __all__ = ["lords_grad_pallas", "block_grad_pallas"]
 
 
-def _body(x_ref, g_ref, q_ref, bt_ref, a_ref, lut_ref, w_ref, dbt_ref,
-          dap_ref, dw_ref, acc_ref, *, ps, n_levels, eps):
-    k, m = pl.program_id(1), pl.program_id(2)
+def _kernel(x_ref, g_ref, *refs, ps, levels, eps, nk, qat):
+    if qat:
+        *q_refs, bt_ref, a_ref, w_ref, dbt_ref, dap_ref, dw_ref, acc_ref = refs
+    else:
+        *q_refs, bt_ref, a_ref, dbt_ref, dap_ref, acc_ref = refs
+    kk, m = pl.program_id(1), pl.program_id(2)
     nm = pl.num_programs(2)
 
     @pl.when(m == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_and(k == 0, m == 0))
-    def _zero_dbt():  # dbT tile is resident across the whole (k, m) sweep
+    @pl.when(jnp.logical_and(kk == 0, m == 0))
+    def _zero_dbt():  # dbT tile is resident across the whole (kk, m) sweep
         dbt_ref[...] = jnp.zeros_like(dbt_ref)
 
     acc_ref[...] += jax.lax.dot_general(
         g_ref[...], x_ref[...], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )                                                          # ∂L/∂Ŵ (bn, bk)
+    )                                                          # ∂L/∂Ŵ (bn, t)
 
     @pl.when(m == nm - 1)
     def _reduce():
-        codes = _unpack_tile(q_ref[...], ps)
-        vals = _lut_select(codes, lut_ref, n_levels)           # (bn, bk) f32
+        p, _, _ = k_step(kk, ps.group_codes, nk)
+        vals = lut_select(code_plane(q_refs, ps, p), levels)   # (bn, t) f32
         s_raw = jax.lax.dot_general(
             bt_ref[...], a_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         mask = (jnp.abs(s_raw) >= eps).astype(jnp.float32)
         dw_hat = acc_ref[...]
-        if w_ref is None:                                      # frozen / peft
+        if not qat:                                            # frozen / peft
             ds = dw_hat * vals * mask
         else:                                                  # qat STE
             s = clamp_scale(s_raw, eps)
@@ -99,19 +111,7 @@ def _body(x_ref, g_ref, q_ref, bt_ref, a_ref, lut_ref, w_ref, dbt_ref,
         dap_ref[...] = jax.lax.dot_general(
             bt_ref[...], ds, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )[None]                                                # (1, r, bk)
-
-
-def _kernel_frozen(x_ref, g_ref, q_ref, bt_ref, a_ref, lut_ref, dbt_ref,
-                   dap_ref, acc_ref, *, ps, n_levels, eps):
-    _body(x_ref, g_ref, q_ref, bt_ref, a_ref, lut_ref, None, dbt_ref,
-          dap_ref, None, acc_ref, ps=ps, n_levels=n_levels, eps=eps)
-
-
-def _kernel_qat(x_ref, g_ref, q_ref, bt_ref, a_ref, lut_ref, w_ref, dbt_ref,
-                dap_ref, dw_ref, acc_ref, *, ps, n_levels, eps):
-    _body(x_ref, g_ref, q_ref, bt_ref, a_ref, lut_ref, w_ref, dbt_ref,
-          dap_ref, dw_ref, acc_ref, ps=ps, n_levels=n_levels, eps=eps)
+        )[None]                                                # (1, r, t)
 
 
 @functools.partial(
@@ -139,46 +139,47 @@ def lords_grad_pallas(
     m, kdim = x.shape
     n, r = b.shape
     ps = quantize_mod.pack_spec(codebook_name)
-    levels = lut_mod.codebook(codebook_name)
-    n_levels = levels.shape[0]
+    gc = ps.group_codes
 
     bm = min(bm, m)
     bn = min(bn, n)
     bk = min(bk, kdim)
-    if m % bm or n % bn or kdim % bk or bk % ps.group_codes:
+    if m % bm or n % bn:
         raise ValueError(
             f"shape ({m},{n},{kdim}) not divisible by blocks ({bm},{bn},{bk})"
         )
-    grid = (n // bn, kdim // bk, m // bm)  # M innermost: the ∂L/∂Ŵ reduction
+    t, nk = plane_tiles(kdim, bk, ps)
+    grid = (n // bn, gc * nk, m // bm)  # M innermost: the ∂L/∂Ŵ reduction
+    tile = lambda kk: k_step(kk, gc, nk)[2]  # noqa: E731
 
-    bt = b.T  # (r, N)
-    lut_arr = levels.reshape(1, -1).astype(jnp.float32)
     qat = w is not None
     kern = functools.partial(
-        _kernel_qat if qat else _kernel_frozen,
-        ps=ps, n_levels=n_levels, eps=SCALE_EPS,
+        _kernel, ps=ps, levels=lut_mod.static_levels(codebook_name),
+        eps=SCALE_EPS, nk=nk, qat=qat,
     )
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda j, k, m: (m, k)),        # x
-        pl.BlockSpec((bm, bn), lambda j, k, m: (m, j)),        # g
-        pl.BlockSpec((bn, ps.packed_width(bk)), lambda j, k, m: (j, k)),  # q
-        pl.BlockSpec((r, bn), lambda j, k, m: (0, j)),         # bT
-        pl.BlockSpec((r, bk), lambda j, k, m: (0, k)),         # a
-        pl.BlockSpec((1, n_levels), lambda j, k, m: (0, 0)),   # lut
+        pl.BlockSpec((bm, t), lambda j, kk, m: (m, tile(kk))),    # x
+        pl.BlockSpec((bm, bn), lambda j, kk, m: (m, j)),          # g
+        *byte_plane_specs(ps, bn, t, nk,
+                          lambda j, kk, m: (j, kk // gc)),        # q
+        pl.BlockSpec((r, bn), lambda j, kk, m: (0, j)),           # bT
+        pl.BlockSpec((r, t), lambda j, kk, m: (0, tile(kk))),     # a
     ]
-    inputs = [x, g, q_packed, bt, a, lut_arr]
+    inputs = [x, g, *[q_packed] * ps.group_bytes, b.T, a]
     out_specs = [
-        pl.BlockSpec((r, bn), lambda j, k, m: (0, j)),         # dbT
-        pl.BlockSpec((1, r, bk), lambda j, k, m: (j, 0, k)),   # da_part
+        pl.BlockSpec((r, bn), lambda j, kk, m: (0, j)),           # dbT
+        pl.BlockSpec((1, r, t), lambda j, kk, m: (j, 0, tile(kk))),  # da_part
     ]
     out_shape = [
         jax.ShapeDtypeStruct((r, n), jnp.float32),
         jax.ShapeDtypeStruct((n // bn, r, kdim), jnp.float32),
     ]
     if qat:
-        in_specs.append(pl.BlockSpec((bn, bk), lambda j, k, m: (j, k)))  # w
+        in_specs.append(
+            pl.BlockSpec((bn, t), lambda j, kk, m: (j, tile(kk))))  # w
         inputs.append(w)
-        out_specs.append(pl.BlockSpec((bn, bk), lambda j, k, m: (j, k)))
+        out_specs.append(
+            pl.BlockSpec((bn, t), lambda j, kk, m: (j, tile(kk))))  # dW
         out_shape.append(jax.ShapeDtypeStruct((n, kdim), jnp.float32))
     return pl.pallas_call(
         kern,
@@ -186,7 +187,7 @@ def lords_grad_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bn, bk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bn, t), jnp.float32)],
         interpret=interpret,
     )(*inputs)
 
@@ -196,16 +197,17 @@ def lords_grad_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _block_body(x_ref, g_ref, q_ref, lut_ref, o_ref, acc_ref, *, ps,
-                n_levels, group, blocks_per_tile):
-    k, m = pl.program_id(1), pl.program_id(2)
+def _block_body(x_ref, g_ref, *refs, ps, levels, nk, group,
+                blocks_per_tile):
+    *q_refs, o_ref, acc_ref = refs
+    kk, m = pl.program_id(1), pl.program_id(2)
     nm = pl.num_programs(2)
 
     @pl.when(m == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_and(k % group == 0, m == 0))
+    @pl.when(jnp.logical_and(kk % group == 0, m == 0))
     def _zero_out():  # out tile is resident for `group` consecutive k steps
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -216,12 +218,11 @@ def _block_body(x_ref, g_ref, q_ref, lut_ref, o_ref, acc_ref, *, ps,
 
     @pl.when(m == nm - 1)
     def _reduce():
-        codes = _unpack_tile(q_ref[...], ps)
-        vals = _lut_select(codes, lut_ref, n_levels)
-        ds = acc_ref[...] * vals                               # (bn, bk)
-        bn, bk = ds.shape
-        o_ref[...] += ds.reshape(bn, blocks_per_tile,
-                                 bk // blocks_per_tile).sum(-1)
+        vals = lut_select(code_plane(q_refs, ps, kk // nk), levels)
+        ds = acc_ref[...] * vals                               # (bn, t)
+        bn, t = ds.shape
+        o_ref[0] += ds.reshape(bn, blocks_per_tile,
+                               t // blocks_per_tile).sum(-1)
 
 
 @functools.partial(
@@ -241,47 +242,43 @@ def block_grad_pallas(
     bk: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """∂s_blk (N, K/block_size) for the block-wise dequant matmul."""
+    """∂s_blk (N, K/block_size) for the block-wise dequant matmul.
+
+    Steps walk the logical K tiles in order (plane ``kk // nk``, packed
+    tile ``kk % nk``) so a block spanning several tiles accumulates into
+    its resident output column on consecutive steps."""
     m, kdim = x.shape
     n = q_packed.shape[0]
     ps = quantize_mod.pack_spec(codebook_name)
-    levels = lut_mod.codebook(codebook_name)
-    n_levels = levels.shape[0]
 
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, kdim)
-    if m % bm or n % bn or kdim % bk or bk % ps.group_codes:
+    if m % bm or n % bn:
         raise ValueError(
             f"shape ({m},{n},{kdim}) not divisible by blocks ({bm},{bn},{bk})"
         )
-    if not (bk % block_size == 0 or block_size % bk == 0):
-        raise ValueError(f"bk {bk} incompatible with block_size {block_size}")
-    grid = (n // bn, kdim // bk, m // bm)
+    t, nk = plane_tiles(kdim, bk, ps)
+    grid = (n // bn, ps.group_codes * nk, m // bm)
+    s_spec, c, _ = block_scale_spec(bn, t, block_size, lambda j, kk, m: kk,
+                                    lambda j, kk, m: j)
+    group = max(block_size // t, 1)        # tiles sharing one block column
+    blocks_per_tile = max(t // block_size, 1)
 
-    if bk >= block_size:
-        # each k tile owns bk/block_size whole blocks
-        s_cols, group, blocks_per_tile = bk // block_size, 1, bk // block_size
-        s_index = lambda j, k, m: (j, k)
-    else:
-        # one block spans `group` consecutive k tiles: the (bn, 1) output
-        # column stays resident and accumulates across them
-        group = block_size // bk
-        s_cols, blocks_per_tile = 1, 1
-        s_index = lambda j, k, m: (j, k // group)
-
-    lut_arr = levels.reshape(1, -1).astype(jnp.float32)
-    kern = functools.partial(_block_body, ps=ps, n_levels=n_levels,
+    kern = functools.partial(_block_body, ps=ps,
+                             levels=lut_mod.static_levels(codebook_name), nk=nk,
                              group=group, blocks_per_tile=blocks_per_tile)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda j, k, m: (m, k)),
-            pl.BlockSpec((bm, bn), lambda j, k, m: (m, j)),
-            pl.BlockSpec((bn, ps.packed_width(bk)), lambda j, k, m: (j, k)),
-            pl.BlockSpec((1, n_levels), lambda j, k, m: (0, 0)),
+            pl.BlockSpec((bm, t), lambda j, kk, m: (m, kk)),
+            pl.BlockSpec((bm, bn), lambda j, kk, m: (m, j)),
+            *byte_plane_specs(ps, bn, t, nk,
+                              lambda j, kk, m: (j, kk % nk)),
         ],
-        out_specs=pl.BlockSpec((bn, s_cols), s_index),
-        out_shape=jax.ShapeDtypeStruct((n, kdim // block_size), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bn, bk), jnp.float32)],
+        out_specs=s_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (kdim // (c * block_size), n, c), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bn, t), jnp.float32)],
         interpret=interpret,
-    )(x, g, q_packed, lut_arr)
+    )(x, g, *[q_packed] * ps.group_bytes)
+    return out.transpose(1, 0, 2).reshape(n, kdim // block_size)

@@ -10,18 +10,23 @@ HBM traffic beyond the packed codes themselves — the entire reason LoRDS
 serving matches block-wise NF4 speed while QLoRA pays for an extra adapter
 GEMM.
 
-Tiling (all VMEM):
-  grid = (M/bm, N/bn, K/bk), K innermost for accumulation
-    x tile   (bm, bk)            input activations
-    q tile   (bn, packed(bk)) uint8 packed codes (bk·bits/8 bytes)
+Packed codes are laid out in slot-major planes (:mod:`repro.core.quantize`):
+code slot ``p`` of a packed column tile holds the contiguous logical K range
+``p*K/g + [k*t, (k+1)*t)``.  So one grid step takes one plane of one packed
+tile — a shift/mask of the byte tile — against the matching x / A columns.
+
+Tiling (all VMEM), with ``g`` codes per group and ``t = bk/g``:
+  grid = (M/bm, N/bn, g·K/bk), K innermost for accumulation, ordered so the
+  g plane steps of one packed tile are consecutive (fetched once)
+    x tile   (bm, t)             input activations of the step's plane
+    q tiles  (bn, t) uint8 ×B    packed codes, one tile per byte plane
     bT tile  (r, bn)             scale factor B, transposed so the tiny rank
-    a tile   (r, bk)             dim sits in sublanes (lane dim stays 128-al.)
-    lut      (1, L) f32          codebook levels
+    a tile   (r, t)              dim sits in sublanes (lane dim stays 128-al.)
     out tile (bm, bn) f32        accumulated across the K grid axis
 
-Per tile:  S = bTᵀ·a  (r-contraction, r ≤ 32), W = lut[q]⊙S, acc += x·Wᵀ.
-The MXU sees two matmuls: the tiny (bn×r)×(r×bk) scale product and the main
-(bm×bk)×(bk×bn) GEMM — dequant itself is pure VPU elementwise work.
+Per step:  S = bTᵀ·a  (r-contraction, r ≤ 32), W = lut[q]⊙S, acc += x·Wᵀ.
+The MXU sees two matmuls: the tiny (bn×r)×(r×t) scale product and the main
+(bm×t)×(t×bn) GEMM — dequant itself is pure VPU elementwise work.
 
 Weight-stationary layout note: with grid order (i, j, k) the q/bT/a tiles are
 re-fetched for every i; for decode (M small → one i) this is optimal
@@ -42,90 +47,85 @@ from repro.core.scaling import clamp_scale
 __all__ = ["lords_matmul_pallas"]
 
 
-def _unpack_tile(q, ps: quantize_mod.PackSpec):
-    """(bn, bkp) uint8 -> (bn, logical(bkp)) int32 codes, little-endian.
+def plane_tiles(kdim: int, bk: int, ps: quantize_mod.PackSpec):
+    """``(t, nk)`` for a K extent of ``kdim`` codes in ``bk``-code tiles:
+    each tile is ``g`` plane slices of ``t = bk/g`` columns, and each plane
+    spans ``nk = kdim/bk`` of them."""
+    g = ps.group_codes
+    if kdim % bk or bk % g:
+        raise ValueError(f"K={kdim} not divisible by tile bk={bk}, or bk not "
+                         f"a multiple of the {g}-code pack group")
+    return bk // g, kdim // bk
 
-    Cross-byte groups (3-bit: 8 codes / 3 bytes) first assemble each group's
-    bytes into one int32 word, then shift/mask out the codes — pure VPU
-    bit work feeding the one-hot×LUT MXU gather, no dense unpack in HBM.
-    """
+
+def k_step(kk, g: int, nk: int):
+    """Grid step ``kk`` → (plane, packed tile, logical K tile).  Steps run
+    packed-tile major, so the ``g`` steps reading one packed tile are
+    consecutive and Pallas fetches that tile once."""
+    p, k = kk % g, kk // g
+    return p, k, p * nk + k
+
+
+def byte_plane_specs(ps, bn: int, t: int, nk: int, index):
+    """BlockSpecs of the ``group_bytes`` byte planes of a packed operand;
+    ``index(grid..) -> (row tile, packed tile)``."""
+    def spec(c):
+        def imap(*grid):
+            j, k = index(*grid)
+            return j, c * nk + k
+        return pl.BlockSpec((bn, t), imap)
+    return [spec(c) for c in range(ps.group_bytes)]
+
+
+def code_plane(q_refs, ps, p):
+    """Code plane ``p`` (static or traced) of the byte-plane tiles: the
+    ``(bn, t)`` int32 codes of one contiguous logical K slice.  Pure VPU
+    shift/mask work — no lane interleave, no full-width code array."""
+    word = q_refs[0][...].astype(jnp.int32)
+    for c in range(1, ps.group_bytes):
+        word = word | (q_refs[c][...].astype(jnp.int32) << (8 * c))
     if ps.group_codes == 1:
-        return q.astype(jnp.int32)
-    bn, bkp = q.shape
-    word = q.astype(jnp.int32)
-    if ps.group_bytes > 1:
-        grp = word.reshape(bn, bkp // ps.group_bytes, ps.group_bytes)
-        word = grp[:, :, 0]
-        for j in range(1, ps.group_bytes):
-            word |= grp[:, :, j] << (8 * j)
-    mask = (1 << ps.bits) - 1
-    parts = [(word >> (ps.bits * i)) & mask for i in range(ps.group_codes)]
-    stacked = jnp.stack(parts, axis=-1)  # (bn, groups, group_codes)
-    return stacked.reshape(bn, ps.logical_width(bkp))
+        return word
+    return (word >> (ps.bits * p)) & ((1 << ps.bits) - 1)
 
 
-# One-hot tensors above this LUT width would dwarf the codes tile in VMEM
-# (L× the f32 tile) — int8's 256-level table stays on the select chain.
-_ONE_HOT_MAX_LEVELS = 32
-# Column slab for the one-hot: bounds the live (bn, slab, L) f32 intermediate
-# to ~2 MiB at bn=256/L=16 regardless of bk, so default prefill tiles
-# (bn 256 × bk 512, which would be an 8 MiB one-hot in one shot) still fit
-# VMEM next to the double-buffered operand tiles and the accumulator.
-_ONE_HOT_SLAB = 128
+def lut_select(codes, levels: tuple[float, ...]):
+    """``levels[codes]`` as a bit-tree of selects: bit b of the code picks
+    between the two halves of each 2^(b+1)-level subtree.  L−1 selects over
+    log2(L) bit masks, evaluated depth first so only a handful of tiles are
+    live; the levels are scalar constants (Mosaic-friendly, no gather)."""
+    n_levels = len(levels)
+    nbits = max((n_levels - 1).bit_length(), 1)
+    bit = [(codes & (1 << b)) != 0 for b in range(nbits)]
+
+    def pick(lo: int, b: int):
+        if b < 0:
+            return jnp.float32(levels[min(lo, n_levels - 1)])
+        half = 1 << b
+        if lo + half >= n_levels:  # upper half unused (non power-of-2 L)
+            return pick(lo, b - 1)
+        return jnp.where(bit[b], pick(lo + half, b - 1), pick(lo, b - 1))
+
+    return jnp.broadcast_to(pick(0, nbits - 1), codes.shape)
 
 
-def _lut_select(codes, lut_ref, n_levels: int):
-    """LUT gather as one-hot × lut matmul: the L-way gather becomes
-    (bn, slab, L) · (L,) contractions the MXU executes, instead of the O(L)
-    compare-select chain the VPU had to walk per element.  The K dimension
-    is processed in lane slabs so the one-hot intermediate stays a bounded
-    VMEM transient.  Wide tables (int8: L=256) keep the chain — their
-    one-hot would be L× the tile.  No dynamic gather either way
-    (Mosaic-friendly)."""
-    if n_levels > _ONE_HOT_MAX_LEVELS:
-        out = jnp.zeros(codes.shape, jnp.float32)
-        for l in range(n_levels):
-            out = jnp.where(codes == l, lut_ref[0, l], out)
-        return out
+def _kernel(x_ref, *refs, ps, levels, eps, nk):
+    *q_refs, bt_ref, a_ref, o_ref = refs
+    kk = pl.program_id(2)
 
-    def slab_vals(slab):
-        iota = jax.lax.broadcasted_iota(
-            jnp.int32, (*slab.shape, n_levels), slab.ndim)
-        one_hot = (slab[..., None] == iota).astype(jnp.float32)
-        out = jax.lax.dot_general(
-            one_hot, lut_ref[...],  # lut (1, L): contract L, drop the 1
-            (((slab.ndim,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return out[..., 0]
-
-    kdim = codes.shape[-1]
-    if kdim <= _ONE_HOT_SLAB:
-        return slab_vals(codes)
-    # non-multiple K tiles get a short trailing slab — the bound must hold
-    # for every bk the kernels accept, not just the 128-multiple defaults
-    slabs = [slab_vals(codes[..., i : i + _ONE_HOT_SLAB])
-             for i in range(0, kdim, _ONE_HOT_SLAB)]
-    return jnp.concatenate(slabs, axis=-1)
-
-
-def _kernel(x_ref, q_ref, bt_ref, a_ref, lut_ref, o_ref, *, ps, n_levels,
-            eps):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
+    @pl.when(kk == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = _unpack_tile(q_ref[...], ps)                      # (bn, bk)
-    vals = _lut_select(codes, lut_ref, n_levels)              # (bn, bk) f32
-    # low-rank scale tile: S = Bᵀᵀ·A  -> (bn, bk), r-contraction on the MXU
+    p, _, _ = k_step(kk, ps.group_codes, nk)
+    vals = lut_select(code_plane(q_refs, ps, p), levels)      # (bn, t) f32
+    # low-rank scale tile: S = Bᵀᵀ·A  -> (bn, t), r-contraction on the MXU
     s = jax.lax.dot_general(
         bt_ref[...], a_ref[...], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     s = clamp_scale(s, eps)
-    w = (vals * s).astype(x_ref.dtype)                        # (bn, bk)
+    w = (vals * s).astype(x_ref.dtype)                        # (bn, t)
     acc = jax.lax.dot_general(
         x_ref[...], w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -155,35 +155,34 @@ def lords_matmul_pallas(
     m, kdim = x.shape
     n, r = b.shape
     ps = quantize_mod.pack_spec(codebook_name)
-    levels = lut_mod.codebook(codebook_name)
-    n_levels = levels.shape[0]
+    g = ps.group_codes
 
     bm = min(bm, m)
     bn = min(bn, n)
     bk = min(bk, kdim)
-    if m % bm or n % bn or kdim % bk or bk % ps.group_codes:
+    if m % bm or n % bn:
         raise ValueError(
             f"shape ({m},{n},{kdim}) not divisible by blocks ({bm},{bn},{bk})"
         )
-    grid = (m // bm, n // bn, kdim // bk)
-
-    bt = b.T  # (r, N): keep the tiny rank dim out of the lane dimension
-    lut_arr = levels.reshape(1, -1).astype(jnp.float32)
+    t, nk = plane_tiles(kdim, bk, ps)
+    grid = (m // bm, n // bn, g * nk)
+    tile = lambda kk: k_step(kk, g, nk)[2]  # noqa: E731
 
     kern = functools.partial(
-        _kernel, ps=ps, n_levels=n_levels, eps=SCALE_EPS
+        _kernel, ps=ps, levels=lut_mod.static_levels(codebook_name),
+        eps=SCALE_EPS, nk=nk,
     )
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, ps.packed_width(bk)), lambda i, j, k: (j, k)),
-            pl.BlockSpec((r, bn), lambda i, j, k: (0, j)),
-            pl.BlockSpec((r, bk), lambda i, j, k: (0, k)),
-            pl.BlockSpec((1, n_levels), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((bm, t), lambda i, j, kk: (i, tile(kk))),
+            *byte_plane_specs(ps, bn, t, nk,
+                              lambda i, j, kk: (j, kk // g)),
+            pl.BlockSpec((r, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((r, t), lambda i, j, kk: (0, tile(kk))),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, q_packed, bt, a, lut_arr)
+    )(x, *[q_packed] * ps.group_bytes, b.T, a)

@@ -8,13 +8,17 @@ inside the PTQ refinement loop and the QAT fake-quant forward, where it fuses
 the S = B·A product, the division, the midpoint compare tree and the bit
 packing into one VMEM pass over W.
 
-Tiling: grid = (N/bn, K/bk); W tile (bn, bk); bT (r, bn); a (r, bk);
-midpoints (1, L-1); out tile (bn, packed(bk)) uint8.
+Tiling: grid = (N/bn, g·K/bk), one code plane per K step, packed-tile
+major (the slot-major layout of :mod:`repro.core.quantize`); W tile
+(bn, t); bT (r, bn); a (r, t); out tiles (bn, t) uint8, one per byte plane
+(``t = bk/g``).  The g plane steps of one packed tile OR their codes into
+an int32 group-word scratch, and the last one writes the bytes.
 
 Non-tile-divisible (n, kdim) are zero-padded up to the tile grid (mirroring
-``dispatch.qmatmul``) and the output sliced back; the trailing partial pack
-group, if kdim is not a multiple of ``group_codes``, keeps its deterministic
-padded codes (callers that slice by logical width never read them).
+``dispatch.qmatmul``) and the output re-packed to the logical width; the
+trailing partial pack group, if kdim is not a multiple of ``group_codes``,
+keeps its deterministic padded codes (callers that slice by logical width
+never read them).
 
 The nearest-level search is a static compare tree over the L−1 midpoints
 (code = Σ_l [ratio > mid_l]) — branch-free, VPU-only, no dynamic gather.
@@ -26,10 +30,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lut as lut_mod
 from repro.core import quantize as quantize_mod
 from repro.core.scaling import clamp_scale
+from repro.kernels.lords_matmul import k_step, plane_tiles
 
 __all__ = ["lut_quantize_pallas"]
 
@@ -38,7 +44,9 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-def _kernel(w_ref, bt_ref, a_ref, mids_ref, o_ref, *, ps, n_mids, eps):
+def _kernel(w_ref, bt_ref, a_ref, *refs, ps, mids, eps, nk):
+    *o_refs, word_ref = refs
+    p, _, _ = k_step(pl.program_id(1), ps.group_codes, nk)
     s = jax.lax.dot_general(
         bt_ref[...], a_ref[...], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -46,22 +54,25 @@ def _kernel(w_ref, bt_ref, a_ref, mids_ref, o_ref, *, ps, n_mids, eps):
     s = clamp_scale(s, eps)
     ratio = w_ref[...].astype(jnp.float32) / s
     codes = jnp.zeros(ratio.shape, jnp.int32)
-    for l in range(n_mids):
-        codes += (ratio > mids_ref[0, l]).astype(jnp.int32)
+    for mid in mids:
+        codes += (ratio > mid).astype(jnp.int32)
     if ps.group_codes == 1:
-        o_ref[...] = codes.astype(jnp.uint8)
+        o_refs[0][...] = codes.astype(jnp.uint8)
         return
-    bn, bk = codes.shape
-    grp = codes.reshape(bn, bk // ps.group_codes, ps.group_codes)
-    word = jnp.zeros((bn, bk // ps.group_codes), jnp.int32)
-    for i in range(ps.group_codes):
-        word |= grp[:, :, i] << (ps.bits * i)
-    if ps.group_bytes == 1:
-        o_ref[...] = word.astype(jnp.uint8)
-        return
-    parts = [(word >> (8 * j)) & 0xFF for j in range(ps.group_bytes)]
-    stacked = jnp.stack(parts, axis=-1)  # (bn, groups, group_bytes)
-    o_ref[...] = stacked.reshape(bn, -1).astype(jnp.uint8)
+
+    @pl.when(p == 0)
+    def _first():
+        word_ref[...] = codes
+
+    @pl.when(p > 0)
+    def _or():
+        word_ref[...] |= codes << (ps.bits * p)
+
+    @pl.when(p == ps.group_codes - 1)
+    def _emit():
+        word = word_ref[...]
+        for c, o_ref in enumerate(o_refs):
+            o_ref[...] = ((word >> (8 * c)) & 0xFF).astype(jnp.uint8)
 
 
 @functools.partial(
@@ -82,37 +93,38 @@ def lut_quantize_pallas(
     n, kdim = w.shape
     _, r = b.shape
     ps = quantize_mod.pack_spec(codebook_name)
-    mids = lut_mod.midpoints(codebook_name).reshape(1, -1).astype(jnp.float32)
-    n_mids = mids.shape[1]
+    g = ps.group_codes
+    mids = lut_mod.static_midpoints(codebook_name)
 
     bn = min(bn, n)
     # bk % group_codes must hold on the (possibly padded) tile so every tile
     # packs whole groups
-    bk = _round_up(min(bk, kdim), ps.group_codes)
+    bk = _round_up(min(bk, kdim), g)
     np_ = _round_up(n, bn)
     kp = _round_up(kdim, bk)
     if (np_, kp) != (n, kdim):
         w = jnp.pad(w, ((0, np_ - n), (0, kp - kdim)))
         b = jnp.pad(b, ((0, np_ - n), (0, 0)))
         a = jnp.pad(a, ((0, 0), (0, kp - kdim)))
-    grid = (np_ // bn, kp // bk)
+    t, nk = plane_tiles(kp, bk, ps)
+    grid = (np_ // bn, g * nk)
+    tile = lambda kk: k_step(kk, g, nk)[2]  # noqa: E731
 
-    kern = functools.partial(_kernel, ps=ps, n_mids=n_mids, eps=SCALE_EPS)
-    out = pl.pallas_call(
+    kern = functools.partial(_kernel, ps=ps, mids=mids, eps=SCALE_EPS, nk=nk)
+    planes = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bn, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((r, bn), lambda i, k: (0, i)),
-            pl.BlockSpec((r, bk), lambda i, k: (0, k)),
-            pl.BlockSpec((1, n_mids), lambda i, k: (0, 0)),
+            pl.BlockSpec((bn, t), lambda i, kk: (i, tile(kk))),
+            pl.BlockSpec((r, bn), lambda i, kk: (0, i)),
+            pl.BlockSpec((r, t), lambda i, kk: (0, tile(kk))),
         ],
-        out_specs=pl.BlockSpec(
-            (bn, ps.packed_width(bk)), lambda i, k: (i, k)
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (np_, ps.packed_width(kp)), jnp.uint8
-        ),
+        out_specs=[pl.BlockSpec((bn, t), lambda i, kk: (i, kk // g))
+                   for _ in range(ps.group_bytes)],
+        out_shape=[jax.ShapeDtypeStruct((np_, kp // g), jnp.uint8)
+                   for _ in range(ps.group_bytes)],
+        scratch_shapes=[pltpu.VMEM((bn, t), jnp.int32)],
         interpret=interpret,
-    )(w, b.T, a, mids)
-    return out[:n, : ps.packed_width(_round_up(kdim, ps.group_codes))]
+    )(w, b.T, a)
+    out = jnp.concatenate(planes, axis=1)[:n]
+    return quantize_mod.repack_width(out, _round_up(kdim, g), codebook_name)

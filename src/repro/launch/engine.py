@@ -199,11 +199,11 @@ class Engine:
             params, _ = split_tree(model_init(jax.random.PRNGKey(seed), cfg))
         pools, _ = split_tree(
             paged_cache_init(cfg, total_pages, page_size))
-        if self._multi:
-            params = jax.device_put(params, self.chunk_plan.in_shardings[0])
-            pools = jax.device_put(pools, self.chunk_plan.in_shardings[2])
-        self.params = params
-        self.pools = pools
+        # committed to the plan layout up front: the steps' outputs carry
+        # that layout in their types, so inputs that already do compile
+        # each step exactly once
+        self.params = jax.device_put(params, self.chunk_plan.in_shardings[0])
+        self.pools = jax.device_put(pools, self.chunk_plan.in_shardings[2])
         self._key = jax.random.PRNGKey(seed + 1)
 
         self._slots = [_Slot() for _ in range(slots)]
@@ -229,22 +229,23 @@ class Engine:
         self.burst_plan = (build_paged_generate_plan(
             self.cfg, self.mesh, gen=self.burst, **self._step_kw)
             if self.burst > 1 else self.decode_plan)
-        self._multi = int(np.prod(tuple(self.mesh.shape.values()))) > 1
-        self._chunk_step = jax.jit(self.chunk_plan.step_fn,
-                                   donate_argnums=(2,))
-        self._decode_step = jax.jit(self.decode_plan.step_fn,
-                                    donate_argnums=(2,))
-        self._burst_step = (jax.jit(self.burst_plan.step_fn,
-                                    donate_argnums=(2,))
-                            if self.burst > 1 else self._decode_step)
+        # each step returns the pools in the layout it takes them in, so the
+        # pools one step hands the next never select another executable
+        jit = lambda plan: jax.jit(  # noqa: E731
+            plan.step_fn, out_shardings=plan.out_shardings,
+            donate_argnums=(2,))
+        self._chunk_step = jit(self.chunk_plan)
+        self._decode_step = jit(self.decode_plan)
+        self._burst_step = (jit(self.burst_plan) if self.burst > 1
+                            else self._decode_step)
         self._warm = False
 
     def warmup(self):
-        """Compile and steady-state every step function before serving:
-        two calls each, because the first call sees uncommitted input
-        buffers and the second (donated, committed) hits a separate jit
-        cache entry — without this the second compile lands inside the
-        first timed run.  All-dead inputs (positions -1, page tables 0)
+        """Compile every step function before serving, so a compile error
+        raises here and no compile lands inside a timed run.  Params and
+        pools are committed to the plan layout and every step returns the
+        pools in it, so one call per step is the steady state
+        (:meth:`compile_counts`).  All-dead inputs (positions -1, page tables 0)
         only ever write the dummy page, so the pools stay semantically
         empty."""
         if self._warm:
@@ -254,19 +255,25 @@ class Engine:
         z_pos = jnp.zeros((self.slots,), jnp.int32)
         z_pt = jnp.zeros((self.slots, self.max_pages), jnp.int32)
         z_t = jnp.zeros((self.slots,), jnp.int32)
-        for _ in range(2):
-            tok1, self.pools = self._chunk_step(
-                self.params, z_tok, self.pools, z_pt, z_qpos, z_pos,
-                self._split_key())
-            toks, self.pools = self._decode_step(
-                self.params, z_t, self.pools, z_pt, z_pos,
-                self._split_key())
-            if self._burst_step is not self._decode_step:
-                toks, self.pools = self._burst_step(
-                    self.params, z_t, self.pools, z_pt, z_pos,
-                    self._split_key())
-            jax.block_until_ready(toks)
+        tok1, self.pools = self._chunk_step(
+            self.params, z_tok, self.pools, z_pt, z_qpos, z_pos,
+            self._split_key())
+        toks, self.pools = self._decode_step(
+            self.params, z_t, self.pools, z_pt, z_pos, self._split_key())
+        if self._burst_step is not self._decode_step:
+            toks, self.pools = self._burst_step(
+                self.params, z_t, self.pools, z_pt, z_pos, self._split_key())
+        jax.block_until_ready((tok1, toks))
         self._warm = True
+
+    def compile_counts(self) -> dict:
+        """Executables each step function holds.  Each is 1 after
+        ``warmup()``; a count that grows during ``run()`` means a compile
+        landed inside the serving window."""
+        steps = {"chunk": self._chunk_step, "decode": self._decode_step}
+        if self._burst_step is not self._decode_step:
+            steps["burst"] = self._burst_step
+        return {name: fn._cache_size() for name, fn in steps.items()}
 
     # ---- page accounting ------------------------------------------------
 
@@ -444,9 +451,7 @@ class Engine:
         donated pools' state is unknown)."""
         pools, _ = split_tree(
             paged_cache_init(self.cfg, self.total_pages, self.page_size))
-        if self._multi:
-            pools = jax.device_put(pools, self.chunk_plan.in_shardings[2])
-        self.pools = pools
+        self.pools = jax.device_put(pools, self.chunk_plan.in_shardings[2])
         self._free_pages = list(range(1, self.total_pages))
         self._poisoned = set()
 
@@ -473,9 +478,8 @@ class Engine:
         self.stats["lost_devices"] += old - data * model
         self.mesh = make_host_mesh(data=data, model=model)
         self._build_plans()
-        self.params = jax.device_put(
-            self.params, self.chunk_plan.in_shardings[0]) if self._multi \
-            else jax.device_put(self.params, self.mesh.devices.flat[0])
+        self.params = jax.device_put(self.params,
+                                     self.chunk_plan.in_shardings[0])
         self.stats["resharded_restores"] += 1
         # every active sequence's KV lived (in part) on the lost devices:
         # requeue oldest-frontmost for recompute, then rebuild the pool on
@@ -492,14 +496,17 @@ class Engine:
         return True
 
     def _step_failure(self, participants, queue: deque, *, injected: bool,
-                      phase: str):
+                      phase: str, error: Exception | None = None):
         """Recover from a failed step launch.  Participants are charged a
         retry (``failed`` once the budget is gone) and requeued at the
         front for recompute.  Injected faults fire *before* the launch, so
         bystander slots keep their pages and KV; an organic failure cannot
         trust the donated pool state, so the pool is rebuilt and every
-        active sequence recomputes."""
+        active sequence recomputes.  An organic error is kept in
+        ``stats['step_errors']`` so a caller can report why."""
         self.stats["step_failures"] += 1
+        if error is not None:
+            self.stats["step_errors"].append(f"{phase}: {error!r}")
         affected = (list(participants) if injected
                     else [s for s in self._slots if s.state != _FREE])
         charged = {id(s) for s in participants}
@@ -591,7 +598,8 @@ class Engine:
                       "shed": 0, "deadline_cancels": 0, "nan_injections": 0,
                       "preempted": False, "mesh_rebuilds": 0,
                       "lost_devices": 0, "resharded_restores": 0,
-                      "collective_timeouts": 0, "straggler_flags": []}
+                      "collective_timeouts": 0, "straggler_flags": [],
+                      "step_errors": []}
         t0 = time.perf_counter()
         self._t0 = t0
         now = self._now
@@ -784,9 +792,9 @@ class Engine:
             self._step_failure(prefilling, queue, injected=True,
                                phase="prefill")
             return
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — any launch error retries
             self._step_failure(prefilling, queue, injected=False,
-                               phase="prefill")
+                               phase="prefill", error=e)
             return
         tok1 = np.asarray(tok1)
         self.stats["prefill_ms"] += (time.perf_counter() - t0) * 1e3
@@ -867,9 +875,9 @@ class Engine:
             self._step_failure(decoding, queue, injected=True,
                                phase="decode")
             return
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — any launch error retries
             self._step_failure(decoding, queue, injected=False,
-                               phase="decode")
+                               phase="decode", error=e)
             return
         toks = np.asarray(toks)
         self.stats["decode_ms"] += (time.perf_counter() - t0) * 1e3

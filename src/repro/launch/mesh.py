@@ -20,7 +20,7 @@ so sharding policies for the full arch zoo are testable anywhere.
 from __future__ import annotations
 
 import jax
-from jax.sharding import AbstractMesh
+from jax.sharding import AbstractMesh, AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_abstract_mesh"]
 
@@ -30,10 +30,18 @@ _SINGLE_SHAPE = (16, 16)
 _SINGLE_AXES = ("data", "model")
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings stay out of the
+    array types, so the jitted steps, ``device_put`` layouts and shard_map
+    bodies mix freely (Explicit axes, the current default, would make
+    mismatched update shardings a type error)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = _POD_SHAPE if multi_pod else _SINGLE_SHAPE
     axes = _POD_AXES if multi_pod else _SINGLE_AXES
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -44,11 +52,11 @@ def make_host_mesh(data: int = 1, model: int = 1):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for a (2, 4)
     data×tensor-parallel mesh (what ``make test-multidevice`` does).
     """
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_abstract_mesh(*, multi_pod: bool = False) -> AbstractMesh:
     """AbstractMesh twin of :func:`make_production_mesh` (no devices)."""
     shape = _POD_SHAPE if multi_pod else _SINGLE_SHAPE
     axes = _POD_AXES if multi_pod else _SINGLE_AXES
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

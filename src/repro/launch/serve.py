@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ShapeCfg, get_config, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_generate_plan, build_plan, sample_token
 from repro.models import cache_init, model_init, split_tree
@@ -251,6 +252,7 @@ def main(argv=None):
                          "devices; on CPU force them via XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -285,6 +287,10 @@ def main(argv=None):
               f"p99 {stats['latency_p99_s'] * 1e3:.0f}ms "
               f"evictions {stats['evictions']} shed {stats['shed']} "
               f"page_audit_ok {stats['page_audit']['ok']}")
+        n_failed = stats["statuses"].get("failed", 0)
+        if n_failed:
+            raise SystemExit(f"[serve] {n_failed} request(s) failed; step "
+                             f"errors: {stats['step_errors'][-3:]}")
         return
     out = serve_batch(cfg, batch=args.batch, prompt_len=args.prompt_len,
                       gen=args.gen, mesh=mesh,

@@ -5,25 +5,14 @@
 
 Production path: real mesh via ``make_production_mesh``, checkpoint/restore
 via ``repro.checkpoint``, preemption-safe, straggler-monitored, deterministic
-restartable data pipeline.  On this CPU container it runs reduced configs end
-to end (examples/finetune_peft.py drives a ~100M-param model this way).
-
-XLA flags for real TPU runs (latency-hiding overlap of the collectives the
-dry-run surfaces) are in ``TPU_PERF_FLAGS`` — applied when backend == tpu.
+restartable data pipeline.  On a CPU host it runs reduced configs end to
+end (examples/finetune_peft.py drives a ~100M-param model this way).
 """
 from __future__ import annotations
 
 import argparse
 import os
 import time
-
-TPU_PERF_FLAGS = (
-    "--xla_enable_async_collective_permute=true "
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
-    "--xla_tpu_overlap_compute_collective_tc=true "
-    "--xla_tpu_data_parallel_opt_different_sized_ops=true"
-)
 
 import jax
 
@@ -39,6 +28,7 @@ from repro.core import peft
 from repro.data import SyntheticLM, make_batch_iterator
 from repro.distributed.desync import desync_spread, replica_digests
 from repro.distributed.fault_tolerance import PreemptionGuard, StragglerMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import build_plan
 from repro.models import model_init, split_tree
@@ -341,6 +331,7 @@ def main(argv=None):
                     help="decorrelated-jitter fraction for IO retries "
                          "(0 = deterministic exponential)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

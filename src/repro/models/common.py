@@ -9,6 +9,7 @@ PartitionSpecs without introspecting module code.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Any, NamedTuple
 
@@ -87,12 +88,24 @@ def tree_axes(tree):
 
 
 def stack_periods(period_trees: list):
-    """Stack per-period param trees along a new leading 'layers' axis."""
-    def stack(*leaves):
-        vals = [l.value for l in leaves]
-        return P(jnp.stack(vals, axis=0), ("layers",) + leaves[0].axes)
+    """Stack per-period param trees along a new leading 'layers' axis.
 
-    return jax.tree.map(stack, *period_trees, is_leaf=_is_p)
+    Consumes ``period_trees`` (the list is emptied): each leaf's per-period
+    arrays are dropped as soon as its stacked copy exists, so stacking a
+    full-size model peaks at one model plus one leaf, not two models."""
+    flat, treedef = [], None
+    while period_trees:
+        leaves, treedef = jax.tree.flatten(period_trees.pop(0),
+                                           is_leaf=_is_p)
+        flat.append(leaves)
+    stacked = []
+    for i, first in enumerate(flat[0]):
+        vals = [leaves[i].value for leaves in flat]
+        for leaves in flat:
+            leaves[i] = None
+        stacked.append(P(jnp.stack(vals, axis=0), ("layers",) + first.axes))
+        del vals
+    return jax.tree.unflatten(treedef, stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -100,12 +113,23 @@ def stack_periods(period_trees: list):
 # ---------------------------------------------------------------------------
 
 
+@functools.cache
+def _jitted_linear_init():
+    """One compiled init per distinct (n, m, spec, bias): a full-width model
+    re-runs a handful of programs instead of dispatching every draw, SVD
+    and pack of its hundreds of linears op by op."""
+    from repro.core import init_quantized_linear
+
+    return jax.jit(init_quantized_linear,
+                   static_argnames=("n", "m", "spec", "use_bias"))
+
+
 def qlinear_init(key, n, m, quant_spec, out_axis, in_axis, w=None,
                  use_bias=False):
     """Quantized linear (repro.core) wrapped in P leaves with logical axes."""
-    from repro.core import init_quantized_linear, linear_param_specs
+    from repro.core import linear_param_specs
 
-    params = init_quantized_linear(key, n, m, quant_spec, w=w,
+    params = _jitted_linear_init()(key, n=n, m=m, spec=quant_spec, w=w,
                                    use_bias=use_bias)
     axes = linear_param_specs(quant_spec, out_axis, in_axis, use_bias=use_bias)
     return {k: P(v, axes[k]) for k, v in params.items()}
@@ -118,12 +142,19 @@ def qlinear_apply(params, x, quant_spec, n, m):
     return qmatmul(params, x, quant_spec, n, m)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _scaled_normal(key, shape, dtype, scale):
+    """One program: the f32 draw, its scaled copy and the cast fuse, so a
+    (vocab, d_model) bf16 table costs its own bytes while it is drawn, not
+    five times them (each op run eagerly holds its f32 input and output)."""
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
 def dense_init(key, shape, axes, dtype=jnp.bfloat16, scale=None):
     """Unquantized dense weight (router, embeddings, conv, gates...)."""
     if scale is None:
         scale = 1.0 / jnp.sqrt(shape[-1])
-    w = jax.random.normal(key, shape, jnp.float32) * scale
-    return P(w.astype(dtype), axes)
+    return P(_scaled_normal(key, tuple(shape), dtype, scale), axes)
 
 
 # ---------------------------------------------------------------------------

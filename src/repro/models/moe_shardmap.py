@@ -21,7 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.common import current_rules  # ambient rules (mesh + axes)
 
@@ -149,12 +148,12 @@ def moe_apply_shard_map(params, x, cfg, quant):
             else aux
         return y.reshape(bl, sl, d).astype(x_loc.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, w_spec["router"], w_spec["w_gate"],
                   w_spec["w_up"], w_spec["w_down"]),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
     return y, aux

@@ -48,7 +48,7 @@ from repro.core.baselines import (
     hadamard_transform,
     smooth_scales,
 )
-from repro.core.quantize import dequantize_codes, unpack_codes
+from repro.core.quantize import PACK_LAYOUT, dequantize_codes, unpack_codes
 from repro.core.scaling import scale_matrix
 from repro.distributed.sharding import row_shard
 from repro.kernels import dispatch
@@ -144,7 +144,9 @@ class StreamPlan:
               "seed": self.seed, "pretransform": self.pretransform,
               "smooth_alpha": self.smooth_alpha,
               "act_weighted": self.act_weighted,
-              "calib_shards": self.calib_shards}
+              "calib_shards": self.calib_shards,
+              # a ledger of shards packed in another layout is refused
+              "pack_layout": PACK_LAYOUT}
         if self.overrides:  # absent for uniform plans: fingerprint-stable
             fp["overrides"] = [list(o) for o in self.overrides]
         return fp

@@ -132,6 +132,28 @@ def test_v1_layout_read_compat(tmp_path):
     np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(state["w"]))
 
 
+def test_packed_codes_of_another_layout_are_refused(tmp_path):
+    """A checkpoint whose spec records no pack layout (or another one) and
+    holds uint8 packed codes is refused; one without codes still restores."""
+    ck = Checkpointer(str(tmp_path))
+    state = _quant_state()
+    ck.save(3, state)
+    path = tmp_path / "step_3" / "spec.json"
+    spec = json.loads(path.read_text())
+    assert spec.pop("pack_layout") == "planes"
+    path.write_text(json.dumps(spec))
+    with pytest.raises(ValueError, match="pack layout None"):
+        ck.restore(state)
+    floats = {"b": state["params"]["b"], "data_step": 7}
+    ck.save(4, floats)
+    path = tmp_path / "step_4" / "spec.json"
+    spec = json.loads(path.read_text())
+    del spec["pack_layout"]
+    path.write_text(json.dumps(spec))
+    np.testing.assert_array_equal(np.asarray(ck.restore(floats)["b"]),
+                                  np.asarray(floats["b"]))
+
+
 def test_manifest_records_pspecs_unsharded(tmp_path):
     ck = Checkpointer(str(tmp_path))
     ck.save(1, _quant_state())

@@ -84,19 +84,9 @@ def test_subbyte_dispatch_parity_non_tile_aligned(codebook, n, k):
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_subbyte_decode_has_no_dense_unpack_temporary():
-    """The fused sub-byte path must unpack shift/mask *inside the tile*:
-    no integer-typed (N, K) code array may appear anywhere in the jaxpr
-    (that full-width temporary is exactly what true packing removes)."""
-    m, n, k, r = 8, 128, 512, 4
-    x, w, qp, b, a = _setup(m, n, k, r, "nf3")
-
-    def fused(x, qp, b, a):
-        return ops.lords_matmul(x, qp, b, a, "nf3", use_pallas=True,
-                                interpret=True, bm=8, bn=64, bk=128)
-
-    jaxpr = jax.make_jaxpr(fused)(x, qp, b, a)
-
+def _int_code_matrices(fn, *args, n, k):
+    """Every 2-D integer array of at least n·k elements in ``fn``'s jaxpr,
+    nested jaxprs included: a dense unpacked (N, K) code matrix."""
     def int_avals(jx):
         for eqn in jx.eqns:
             for v in list(eqn.invars) + list(eqn.outvars):
@@ -107,12 +97,48 @@ def test_subbyte_decode_has_no_dense_unpack_temporary():
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from int_avals(sub)
 
-    # a dense unpack temporary would be a 2-D integer (N, K) code matrix;
-    # the tile-level one-hot (bn, bk, levels) is 3-D and allowed — it IS
-    # the MXU gather
-    offenders = [a_ for a_ in int_avals(jaxpr.jaxpr)
-                 if a_.ndim == 2 and a_.size >= n * k]
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return [a_ for a_ in int_avals(jaxpr.jaxpr)
+            if a_.ndim == 2 and a_.size >= n * k]
+
+
+def test_subbyte_decode_has_no_dense_unpack_temporary():
+    """The fused sub-byte path must unpack shift/mask *inside the tile*:
+    no integer-typed (N, K) code array may appear anywhere in the jaxpr
+    (that full-width temporary is exactly what true packing removes) — at
+    a tile-aligned K, and through the dispatcher's own tiles at K that 512
+    does not divide (minicpm3's 6400, internvl2's 4864), forward at GEMV
+    and GEMM widths and the fused backward."""
+    from repro.core import QuantSpec, init_quantized_linear
+    from repro.kernels import dispatch
+
+    m, n, k, r = 8, 128, 512, 4
+    x, w, qp, b, a = _setup(m, n, k, r, "nf3")
+
+    def fused(x, qp, b, a):
+        return ops.lords_matmul(x, qp, b, a, "nf3", use_pallas=True,
+                                interpret=True, bm=8, bn=64, bk=128)
+
+    offenders = _int_code_matrices(fused, x, qp, b, a, n=n, k=k)
     assert not offenders, f"full-width unpack temporaries: {offenders}"
+
+    spec = QuantSpec(method="lords", codebook="nf4", block_size=128,
+                     rank=4, mode="peft")
+    for k in (6400, 4864):
+        params = init_quantized_linear(jax.random.PRNGKey(k), n, k, spec)
+
+        def fwd_bwd(x_, b_, a_):
+            p = {**params, "b": b_, "a": a_}
+            return jnp.sum(dispatch.qmatmul(p, x_, spec, n, k,
+                                            backend="interpret") ** 2)
+
+        for m in (8, 16):
+            x = jnp.ones((m, k), jnp.float32)
+            fn = jax.value_and_grad(fwd_bwd, argnums=(0, 1, 2))
+            offenders = _int_code_matrices(fn, x, params["b"], params["a"],
+                                           n=n, k=k)
+            assert not offenders, (f"K={k}, M={m}: full-width unpack "
+                                   f"temporaries {offenders}")
 
 
 @settings(max_examples=10, deadline=None)

@@ -391,6 +391,23 @@ def test_engine_sharded_matches_single_device():
 
 
 @multidevice
+def test_engine_sharded_run_after_warmup_compiles_nothing():
+    """On a tensor-parallel mesh too, warmup() leaves nothing to compile."""
+    cfg = _smoke("llama3-8b", "int8")
+    params, _ = split_tree(model_init(jax.random.PRNGKey(0), cfg))
+    eng = Engine(cfg, slots=2, total_pages=12, page_size=8, max_pages=4,
+                 chunk=16, burst=4, mesh=tp_mesh(),
+                 kernel_backend="interpret", params=params)
+    eng.warmup()
+    assert eng.compile_counts() == {"chunk": 1, "decode": 1, "burst": 1}
+    reqs = [Request(rid=i, tokens=p, max_new=6, arrival=0.0)
+            for i, p in enumerate(_prompts(cfg, [10, 6, 13], seed=5))]
+    stats = eng.run(reqs, timeout_s=600)
+    assert stats["all_completed"], stats
+    assert eng.compile_counts() == {"chunk": 1, "decode": 1, "burst": 1}
+
+
+@multidevice
 def test_paged_decode_kernel_sharded_matches_ref():
     """Fused paged decode under shard_map (kv heads over 'model') matches
     the unsharded gather oracle."""
@@ -453,6 +470,17 @@ def _trace(cfg, plens, gens, gap=0.0, seed=7, deadline=None):
     return [Request(rid=i, tokens=p, max_new=g, arrival=gap * i,
                     deadline_s=deadline)
             for i, (p, g) in enumerate(zip(prompts, gens))]
+
+
+def test_engine_run_after_warmup_compiles_nothing(heng):
+    """warmup() calls each step once; params and pools are committed to
+    the plan layout, so no later call of a run may compile again."""
+    cfg, _, eng = heng
+    assert eng.compile_counts() == {"chunk": 1, "decode": 1, "burst": 1}
+    stats = eng.run(_trace(cfg, [10, 20, 6], [6, 3, 9]), timeout_s=600)
+    assert stats["all_completed"], stats
+    assert stats["chunk_steps"] and stats["decode_steps"]
+    assert eng.compile_counts() == {"chunk": 1, "decode": 1, "burst": 1}
 
 
 def test_engine_global_timeout_returns_instead_of_raising(heng):

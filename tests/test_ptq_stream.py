@@ -203,6 +203,26 @@ def test_resume_refuses_mismatched_plan(source, plan, tmp_path):
         stream_quantize(source, out, other, resume=True)
 
 
+def test_resume_refuses_ledger_of_another_pack_layout(source, plan, tmp_path):
+    """A ledger whose plan predates the pack-layout tag (its shards hold
+    codes in the earlier interleaved layout, with CRCs that still match) is
+    refused on resume and by the audit, not decoded in the wrong order."""
+    out = str(tmp_path / "run")
+    faults = FaultPlan(0, {"ptq.kill_at_block": {"at": (1,)}})
+    with pytest.raises(InjectedFault):
+        stream_quantize(source, out, plan, faults=faults)
+    path = os.path.join(out, "ledger.json")
+    with open(path) as f:
+        data = json.load(f)
+    assert data["plan"].pop("pack_layout") == "planes"
+    with open(path, "w") as f:
+        json.dump(data, f)
+    with pytest.raises(ValueError, match="different quantization plan"):
+        stream_quantize(source, out, plan, resume=True)
+    aud = audit_artifact(out, source, plan)
+    assert not aud["clean"] and "different quantization plan" in aud["reason"]
+
+
 # ---------------------------------------------------------------------------
 # transient IO + shard write protocol
 # ---------------------------------------------------------------------------

@@ -30,11 +30,12 @@ def test_pack_spec_layout():
     assert quantize.pack_spec("nf3").packed_width(256) == 96  # 3 bits/code
     assert quantize.pack_spec("nf2").packed_width(256) == 64
     assert quantize.pack_spec("int8").packed_width(256) == 256
-    # nf4/nf2 stay byte-identical to the historical single-byte layout:
-    # code i lives at bits [bits*i, bits*(i+1)) of its byte
+    # slot-major planes: a row of K codes is g planes of W = K/g codes;
+    # group j holds code i*W + j in slot i (bits [bits*i, bits*(i+1)))
     codes = jnp.asarray([[1, 2, 3, 0]], jnp.uint8)
     assert np.asarray(quantize.pack_codes(codes, "nf4")).tolist() \
-        == [[1 | (2 << 4), 3]]
+        == [[1 | (3 << 4), 2 | (0 << 4)]]
+    # one group per row (W = 1): the plain little-endian group
     assert np.asarray(quantize.pack_codes(codes, "nf2")).tolist() \
         == [[1 | (2 << 2) | (3 << 4)]]
     # nf3 group: 8 codes -> one little-endian 24-bit word -> 3 bytes
@@ -42,6 +43,19 @@ def test_pack_spec_layout():
     word = sum(c << (3 * i) for i, c in enumerate([5, 1, 7, 2, 0, 3, 6, 4]))
     assert np.asarray(quantize.pack_codes(codes, "nf3")).tolist() \
         == [[word & 0xFF, (word >> 8) & 0xFF, (word >> 16) & 0xFF]]
+    # nf3 with W = 2 groups: byte c of group j sits at column c*W + j, so
+    # each byte plane is contiguous
+    row = [5, 1, 7, 2, 0, 3, 6, 4, 1, 1, 2, 3, 5, 7, 0, 6]
+    words = [sum(row[i * 2 + j] << (3 * i) for i in range(8))
+             for j in range(2)]
+    want = [(words[j] >> (8 * c)) & 0xFF for c in range(3) for j in range(2)]
+    got = quantize.pack_codes(jnp.asarray([row], jnp.uint8), "nf3")
+    assert np.asarray(got).tolist() == [want]
+    # K padding re-packs: the planes of a wider row are re-laid out
+    wide = quantize.repack_width(got, 32, "nf3")
+    np.testing.assert_array_equal(
+        np.asarray(quantize.unpack_codes(wide, "nf3")),
+        np.asarray([row + [0] * 16], np.uint8))
 
 
 def test_pack_errors_are_descriptive():

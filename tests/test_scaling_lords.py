@@ -41,6 +41,25 @@ def test_svd_init_exact_when_rank_sufficient(key):
                                rtol=1e-4, atol=1e-6)
 
 
+@pytest.mark.parametrize("rank", [2, 4])
+@pytest.mark.parametrize("smooth", [False, True])
+def test_svd_init_blocks_matches_dense_svd(key, rank, smooth):
+    """The factored SVD of the (n, m/B) block scales gives the same rank-r
+    truncation of S as the dense SVD of the expanded (n, m) S."""
+    w = jax.random.normal(key, (48, 256)) * 0.02
+    c = (jnp.exp(jax.random.normal(jax.random.PRNGKey(3), (256,)) * 0.5)
+         if smooth else None)
+    s_blk = scaling.blockwise_scales(w if c is None else w * c, 64)
+    s_dense = scaling.expand_block_scales(s_blk, 64)
+    if c is not None:
+        s_dense = s_dense / c[None, :]
+    b0, a0 = scaling.svd_init(s_dense, rank)
+    b, a = scaling.svd_init_blocks(s_blk, 64, rank, c)
+    assert b.shape == b0.shape and a.shape == a0.shape
+    np.testing.assert_allclose(np.asarray(b @ a), np.asarray(b0 @ a0),
+                               rtol=1e-4, atol=1e-6)
+
+
 def test_ptq_refinement_beats_blockwise(key):
     """The paper's central PTQ claim at parity budget: refined continuous
     low-rank scaling reconstructs better than rigid block-wise scaling."""

@@ -1,0 +1,149 @@
+"""The main-path Pallas kernels compile for a TPU v5e at llama3-8b widths.
+
+Nothing runs: each kernel is lowered and compiled by the installed TPU
+compiler for a *described* v5e chip (no accelerator needed), which refuses
+what interpret mode cannot see — unaligned blocks, scoped-VMEM overruns,
+layouts Mosaic cannot lower.  Shapes are llama3-8b's (d_model 4096, d_ff
+14336, 32 heads / 8 KV heads of 128), nf4 codes at the parity rank of
+block 128, with the tiles dispatch picks for them.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and every test worker imports this
+file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import QuantSpec, quantize
+from repro.kernels import dispatch
+from repro.kernels.attn_decode import (
+    attn_decode_gqa_paged_pallas,
+    attn_decode_gqa_pallas,
+)
+from repro.kernels.attn_prefill import attn_prefill_pallas
+from repro.kernels.block_matmul import block_matmul_pallas
+from repro.kernels.lords_decode import lords_decode_pallas
+from repro.kernels.lords_grad import lords_grad_pallas
+from repro.kernels.lords_matmul import lords_matmul_pallas
+from repro.kernels.lords_matmul_t import lords_matmul_t_pallas
+
+CODEBOOK = "nf4"
+# (N, K) of the llama3-8b linears: q/o, k/v, gate/up, down
+LINEARS = [(4096, 4096), (1024, 4096), (14336, 4096), (4096, 14336)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A described chip, with the persistent compilation cache off: such
+    entries could not be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, *args, **static):
+    text = jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static).compile().as_text()
+    assert "tpu_custom_call" in text  # the Pallas kernel, not a fallback
+    return text
+
+
+def _lords_operands(sharding, m, n, k, x_dtype):
+    r = QuantSpec(block_size=128).lords_rank(n, k)
+    ps = quantize.pack_spec(CODEBOOK)
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                sharding=sharding)
+    return (sd((m, k), x_dtype), sd((n, ps.packed_width(k)), jnp.uint8),
+            sd((n, r), jnp.float32), sd((r, k), jnp.float32))
+
+
+@pytest.mark.parametrize("n,k", LINEARS)
+def test_lords_decode_compiles(one_chip, n, k):
+    x, q, b, a = _lords_operands(one_chip, 8, n, k, jnp.bfloat16)
+    _, bn, bk = dispatch.tile_for("lords", 8, n, k, CODEBOOK, jnp.bfloat16)
+    _compile(lords_decode_pallas, x, q, b, a, codebook_name=CODEBOOK,
+             bn=bn, bk=bk)
+
+
+@pytest.mark.parametrize("n,k", LINEARS)
+def test_lords_matmul_compiles(one_chip, n, k):
+    x, q, b, a = _lords_operands(one_chip, 256, n, k, jnp.bfloat16)
+    bm, bn, bk = dispatch.tile_for("lords", 256, n, k, CODEBOOK,
+                                   jnp.bfloat16)
+    _compile(lords_matmul_pallas, x, q, b, a, codebook_name=CODEBOOK,
+             bm=bm, bn=bn, bk=bk)
+
+
+@pytest.mark.parametrize("n,k", LINEARS)
+def test_lords_backward_compiles(one_chip, n, k):
+    """dx (transposed matmul) and dB/dA (grad reduction) of a PEFT step."""
+    x, q, b, a = _lords_operands(one_chip, 256, n, k, jnp.float32)
+    g = jax.ShapeDtypeStruct((256, n), jnp.float32, sharding=one_chip)
+    bm, bn, bk = dispatch.tile_for("lords_t", 256, n, k, CODEBOOK,
+                                   jnp.float32)
+    tiles = dict(codebook_name=CODEBOOK, bm=bm, bn=bn, bk=bk)
+    _compile(lords_matmul_t_pallas, g, q, b, a, **tiles)
+    _compile(lords_grad_pallas, x, g, q, b, a, **tiles)
+
+
+def test_block_matmul_compiles(one_chip):
+    """The block-wise nf4 baseline kernel (block 64) at the q/o width."""
+    n = k = 4096
+    bs = 64
+    ps = quantize.pack_spec(CODEBOOK)
+    bm, bn, bk = dispatch.tile_for("blockwise", 256, n, k, CODEBOOK,
+                                   jnp.bfloat16, block_size=bs)
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    _compile(block_matmul_pallas, sd((256, k), jnp.bfloat16),
+             sd((n, ps.packed_width(k)), jnp.uint8),
+             sd((n, k // bs), jnp.float32), block_size=bs,
+             codebook_name=CODEBOOK, bm=bm, bn=bn, bk=bk)
+
+
+def test_attention_compiles(one_chip):
+    """Flash prefill (bf16), the contiguous int8-cache decode, and the paged
+    decode over an int8 page pool."""
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    b, s, nh, nkv, hd = 1, 512, 32, 8, 128
+    bq, bkv = dispatch.attn_tile_for("prefill", s, nh, hd, jnp.bfloat16,
+                                     (128, 128))
+    _compile(attn_prefill_pallas, sd((b, s, nh, hd), jnp.bfloat16),
+             sd((b, s, nkv, hd), jnp.bfloat16),
+             sd((b, s, nkv, hd), jnp.bfloat16), sd((b, s), jnp.int32),
+             sd((b, s), jnp.int32), logit_scale=hd ** -0.5, bq=bq, bkv=bkv)
+    slots, pages, page, pool = 8, 34, 16, 8 * 34 + 1
+    cap = 640
+    _compile(attn_decode_gqa_pallas, sd((slots, nkv, 8, hd), jnp.bfloat16),
+             sd((slots, cap, nkv, hd), jnp.int8),
+             sd((slots, cap, nkv, hd), jnp.int8),
+             sd((slots, cap), jnp.float32), sd((slots, cap, nkv), jnp.float32),
+             sd((slots, cap, nkv), jnp.float32), logit_scale=hd ** -0.5,
+             bs=128)
+    _compile(attn_decode_gqa_paged_pallas, sd((slots, pages), jnp.int32),
+             sd((slots, nkv, 8, hd), jnp.bfloat16),
+             sd((pool, page, nkv, hd), jnp.int8),
+             sd((pool, page, nkv, hd), jnp.int8),
+             sd((slots, pages * page), jnp.float32),
+             sd((pool, page, nkv), jnp.float32),
+             sd((pool, page, nkv), jnp.float32), logit_scale=hd ** -0.5)

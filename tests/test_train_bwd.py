@@ -9,6 +9,7 @@ through the interpreter, and transposed-key autotune persistence.
 import json
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -195,11 +196,12 @@ def test_vmap_moe_expert_stack_grads():
 # primitives allowed to produce (>=N, >=K)-shaped float arrays in the fused
 # path: kernel launches (their tile-level internals live in VMEM, not HBM),
 # operand padding, slicing kernel outputs (the QAT dW *parameter gradient*
-# flows through these), and call boundaries (pjit: pass-through — their
-# bodies are walked separately).  Anything else — dot_general for S=B·A,
-# gather for lut[Q], mul for vals⊙S — is dense-path dequantization.
+# flows through these), and call boundaries (jit / pjit, by JAX version:
+# pass-through — their bodies are walked separately).  Anything else —
+# dot_general for S=B·A, gather for lut[Q], mul for vals⊙S — is dense-path
+# dequantization.
 _ALLOWED = {"pallas_call", "pad", "slice", "dynamic_slice", "squeeze",
-            "reshape", "copy", "transpose", "pjit"}
+            "reshape", "copy", "transpose", "pjit", "jit"}
 
 
 def _nk_float_eqns(fn, *args, n, k):
@@ -223,9 +225,9 @@ def _nk_float_eqns(fn, *args, n, k):
                     walk(sub)
 
     def _subjaxprs(val):
-        if isinstance(val, jax.core.ClosedJaxpr):
+        if isinstance(val, jax_core.ClosedJaxpr):
             yield val.jaxpr
-        elif isinstance(val, jax.core.Jaxpr):
+        elif isinstance(val, jax_core.Jaxpr):
             yield val
         elif isinstance(val, (tuple, list)):
             for v in val:

@@ -895,7 +895,14 @@ def qmatmul(params: dict, x: jnp.ndarray, spec, n: int, m: int, *,
     ``x`` may carry arbitrary leading batch dims over the in-features axis
     ``m``; the result replaces that axis with ``n``.  Backend selection,
     padding, and differentiability are described in the module docstring.
+    Its ops, the kernel launch and the glue around it, run in the named
+    scope ``qmatmul``.
     """
+    with jax.named_scope("qmatmul"):
+        return _qmatmul(params, x, spec, n, m, backend, tiles)
+
+
+def _qmatmul(params, x, spec, n, m, backend, tiles):
     backend = _resolve(backend)
     method, mode = spec.method, spec.mode
     cd = spec.compute_dtype
@@ -1420,11 +1427,17 @@ def qattention(kind: str, *args, logit_scale: float,
     pad-to-tile and optional shard_map; ``ref``/``dense`` run the
     materializing oracles from :mod:`repro.kernels.ref` — numerically the
     same contract, and the parity reference the tests pin the kernels to.
-    Results are f32; callers cast.
+    Results are f32; callers cast.  Its ops run in the named scope
+    ``qattention_<kind>``.
     """
     if kind not in _ATTN_KINDS:
         raise ValueError(f"unknown attention kind {kind!r}; "
                          f"expected one of {_ATTN_KINDS}")
+    with jax.named_scope(f"qattention_{kind}"):
+        return _qattention(kind, args, logit_scale, backend, tiles)
+
+
+def _qattention(kind, args, logit_scale, backend, tiles):
     backend = _resolve(backend)
     if kind == "prefill":
         q, k, v, positions = args

@@ -84,9 +84,24 @@ Every recovery action is counted in ``Engine.stats`` (``evictions``,
 :meth:`Engine.audit_pages` checks the page-pool invariant
 (``free + held == total_pages - 1``, no page in two places) after each
 recovery when faults are active and always at exit.
+
+**Spans**: ``run()`` times its phases as nested spans on the
+profiler's clock.  Each span enters a ``jax.profiler.TraceAnnotation`` (a
+no-op unless a profiler is running) and adds ``count``, ``ms`` and
+``self_ms`` (its time less its child spans') under ``stats['spans']``:
+``engine.start``; one ``engine.tick`` per loop iteration (a
+``StepTraceAnnotation`` numbered by the tick) holding ``engine.intake``,
+``engine.admit``, ``engine.claim``, ``engine.pack``, ``engine.step`` (kept
+as ``engine.step.<plan>`` for ``chunk | decode | burst``; children
+``engine.dispatch``, the jitted call, and ``engine.fetch``, the blocking
+read of its tokens) and ``engine.commit``; then ``engine.finish``.
+``prefill_ms`` and ``decode_ms`` are the ``engine.step`` sums of their
+plans.  ``stats['compiles']`` counts the executables built during the run
+(compiled or loaded from the persistent cache, eager ops included).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -108,6 +123,9 @@ from repro.robustness import NO_FAULTS, InjectedFault
 __all__ = ["Request", "Engine", "TERMINAL_STATUSES"]
 
 TERMINAL_STATUSES = ("completed", "timeout", "rejected", "failed")
+# jax.monitoring event around every executable build of a jit call
+# (pxla._cached_compilation: a compile or a persistent-cache load)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass
@@ -216,6 +234,7 @@ class Engine:
         self._retries: dict = {}
         self._drain_reason: str | None = None
         self.stats: dict = {}
+        self._span_stack: list = []   # child ms of each open span
 
     def _build_plans(self):
         """(Re)build the three fixed-shape step plans and their jits on
@@ -577,21 +596,12 @@ class Engine:
         Returns a stats dict: one terminal record per request (status in
         ``completed | timeout | rejected | failed``), goodput (completed
         generated tokens / wall second), latency percentiles over completed
-        requests, per-phase prefill/decode milliseconds, recovery counters
-        and the exit page-pool audit.  ``timeout_s`` is a drain guard, not
-        an exception: on expiry the engine stops admitting, keeps partial
+        requests, per-phase prefill/decode milliseconds, the spans and
+        compile count of the module docstring, recovery counters and the
+        exit page-pool audit.  ``timeout_s`` is a drain guard, not an
+        exception: on expiry the engine stops admitting, keeps partial
         results, and returns.
         """
-        for r in requests:
-            self._validate(r)
-        self.warmup()
-        pending = deque(sorted(requests, key=lambda r: r.arrival))
-        queue: deque = deque()
-        self._records = []
-        self._recorded = set()
-        self._retries = {}
-        self._poisoned = set()
-        self._drain_reason = None
         self.stats = {"evictions": 0, "chunk_steps": 0, "decode_steps": 0,
                       "prefill_ms": 0.0, "decode_ms": 0.0,
                       "step_failures": 0, "retries": 0, "quarantined": 0,
@@ -599,7 +609,57 @@ class Engine:
                       "preempted": False, "mesh_rebuilds": 0,
                       "lost_devices": 0, "resharded_restores": 0,
                       "collective_timeouts": 0, "straggler_flags": [],
-                      "step_errors": []}
+                      "step_errors": [], "spans": {}, "compiles": 0}
+
+        def count_compile(event, duration_secs, **kwargs):
+            if event == _BACKEND_COMPILE_EVENT:
+                self.stats["compiles"] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(count_compile)
+        try:
+            return self._run(requests, timeout_s)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(count_compile)
+
+    @contextlib.contextmanager
+    def _span(self, name: str, *, key: str | None = None,
+              tick: int | None = None, **tags):
+        """One span of the module docstring: a profiler annotation named
+        ``name`` with ``tags`` (a step annotation numbered ``tick``), timed
+        into ``stats['spans'][key or name]``."""
+        ann = (jax.profiler.StepTraceAnnotation(name, step_num=tick, **tags)
+               if tick is not None
+               else jax.profiler.TraceAnnotation(name, **tags))
+        stack = self._span_stack
+        stack.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            with ann:
+                yield
+        finally:
+            ms = (time.perf_counter() - t0) * 1e3
+            child = stack.pop()
+            if stack:
+                stack[-1] += ms
+            rec = self.stats["spans"].setdefault(
+                key or name, {"count": 0, "ms": 0.0, "self_ms": 0.0})
+            rec["count"] += 1
+            rec["ms"] += ms
+            rec["self_ms"] += ms - child
+
+    def _run(self, requests, timeout_s: float) -> dict:
+        span = self._span
+        with span("engine.start"):
+            for r in requests:
+                self._validate(r)
+            self.warmup()
+            pending = deque(sorted(requests, key=lambda r: r.arrival))
+            queue: deque = deque()
+            self._records = []
+            self._recorded = set()
+            self._retries = {}
+            self._poisoned = set()
+            self._drain_reason = None
         t0 = time.perf_counter()
         self._t0 = t0
         now = self._now
@@ -608,122 +668,138 @@ class Engine:
         n_shards = int(np.prod(tuple(self.mesh.shape.values())))
 
         while pending or queue or any(s.state != _FREE for s in self._slots):
-            if now() > timeout_s:
-                self._drain_reason = "timeout"
-                self._drain_all(pending, queue, "global_timeout")
-                break
-            # fast-forward: nothing is runnable and the next arrival lands
-            # beyond the drain guard — declare the timeout now instead of
-            # sleeping into it
-            if (not queue and pending
-                    and all(s.state == _FREE for s in self._slots)
-                    and pending[0].arrival > timeout_s):
-                self._drain_reason = "timeout"
-                self._drain_all(pending, queue, "global_timeout")
-                break
-
-            if self._drain_reason is None and (
-                    (self._guard is not None and self._guard.preempted)
-                    or self.faults.fires("engine.preempt")):
-                # graceful drain: reject everything waiting (structured,
-                # immediate), let in-flight slots run to completion
-                self._drain_reason = "preempted"
-                self.stats["preempted"] = True
-                while queue:
-                    self._record(queue.popleft(), "rejected",
-                                 reason="preempted")
-                while pending:
-                    self._record(pending.popleft(), "rejected",
-                                 reason="preempted")
-
-            if (self.faults.enabled
-                    and self.stats["mesh_rebuilds"] < self.max_mesh_rebuilds
-                    and self.faults.fires("dist.device_loss")):
-                self._elastic_rebuild(queue)
-                n_shards = int(np.prod(tuple(self.mesh.shape.values())))
-
-            self.faults.fires("engine.straggler")   # sleeps when it fires
-            # straggler watchdog: per-shard injection streams (one RNG per
-            # shard index — deterministic across process counts) plus an
-            # EMA z-score over tick wall time that flags organic slowness
             tick += 1
-            mon.start_step()
-            slow_shards = []
-            if self.faults.enabled:
-                for sidx in range(n_shards):
-                    if self.faults.fires("dist.straggler", index=sidx):
-                        slow_shards.append(sidx)  # fires() slept in-line
-
-            while pending and pending[0].arrival <= now():
-                r = pending.popleft()
-                if (self.admission_budget is not None
-                        and len(queue) >= self.admission_budget):
-                    self._record(r, "rejected", reason="overload")
-                    self.stats["shed"] += 1
-                else:
-                    queue.append(r)
-
-            self._enforce_deadlines(queue)
-
-            # admission: FIFO while a slot is free and the pool can cover
-            # the whole prompt (gating on full prompt pages, not just the
-            # first chunk, keeps overcommit — and eviction thrash — down;
-            # pages past the first chunk are still allocated lazily)
-            for slot in self._slots:
-                if not queue or slot.state != _FREE:
-                    continue
-                req = queue[0]
-                if len(self._free_pages) < -(-len(req.tokens)
-                                             // self.page_size):
+            with span("engine.tick", tick=tick):
+                # timeout: nothing more is admitted or stepped; the drain
+                # runs in engine.finish
+                if now() > timeout_s:
+                    self._drain_reason = "timeout"
                     break
-                first = -(-min(len(req.tokens), self.chunk)
-                          // self.page_size)
-                queue.popleft()
-                slot.state = _PREFILL
-                slot.req = req
-                slot.pages = [self._free_pages.pop() for _ in range(first)]
-                slot.admit_seq = self._admit_seq
-                self._admit_seq += 1
-                slot.admit_t = now()
+                # fast-forward: nothing is runnable and the next arrival
+                # lands beyond the drain guard — declare the timeout now
+                # instead of sleeping into it
+                if (not queue and pending
+                        and all(s.state == _FREE for s in self._slots)
+                        and pending[0].arrival > timeout_s):
+                    self._drain_reason = "timeout"
+                    break
 
-            prefilling = [s for s in self._slots if s.state == _PREFILL]
-            if prefilling:
-                self._run_chunk(prefilling, queue)
+                if self._drain_reason is None and (
+                        (self._guard is not None and self._guard.preempted)
+                        or self.faults.fires("engine.preempt")):
+                    # graceful drain: reject everything waiting (structured,
+                    # immediate), let in-flight slots run to completion
+                    self._drain_reason = "preempted"
+                    self.stats["preempted"] = True
+                    while queue:
+                        self._record(queue.popleft(), "rejected",
+                                     reason="preempted")
+                    while pending:
+                        self._record(pending.popleft(), "rejected",
+                                     reason="preempted")
 
-            decoding = [s for s in self._slots if s.state == _DECODE]
-            if decoding:
-                # burst only when nothing competes for the device: no
-                # prefill in flight, and no admissible work waiting (a
-                # non-empty queue with every slot busy can't be admitted,
-                # so it doesn't force single-stepping)
-                can_admit = any(s.state == _FREE for s in self._slots)
-                waiting = bool(queue) or (
-                    pending and pending[0].arrival <= now() + 1e-3)
-                quiet = not prefilling and not (can_admit and waiting)
-                n = self.burst if quiet else 1
-                n = min(n, max(len(s.req.tokens) + s.req.max_new - s.pos - 1
-                               for s in decoding))
-                self._run_decode(decoding, max(n, 1), queue)
+                if (self.faults.enabled and self.stats["mesh_rebuilds"]
+                        < self.max_mesh_rebuilds
+                        and self.faults.fires("dist.device_loss")):
+                    self._elastic_rebuild(queue)
+                    n_shards = int(np.prod(tuple(self.mesh.shape.values())))
 
-            if (prefilling or decoding) and (
-                    mon.end_step(tick) or slow_shards):
-                flagged = mon.flags[-1] if mon.flags else None
-                self.stats["straggler_flags"].append({
-                    "tick": tick, "shards": slow_shards,
-                    "injected": bool(slow_shards),
-                    "dt_s": flagged[1] if flagged else None,
-                    "zscore": flagged[2] if flagged else None})
+                self.faults.fires("engine.straggler")  # sleeps when it fires
+                # straggler watchdog: per-shard injection streams (one RNG
+                # per shard index — deterministic across process counts)
+                # plus an EMA z-score over tick wall time that flags organic
+                # slowness
+                mon.start_step()
+                slow_shards = []
+                if self.faults.enabled:
+                    for sidx in range(n_shards):
+                        if self.faults.fires("dist.straggler", index=sidx):
+                            slow_shards.append(sidx)  # fires() slept in-line
+
+                with span("engine.intake"):
+                    while pending and pending[0].arrival <= now():
+                        r = pending.popleft()
+                        if (self.admission_budget is not None
+                                and len(queue) >= self.admission_budget):
+                            self._record(r, "rejected", reason="overload")
+                            self.stats["shed"] += 1
+                        else:
+                            queue.append(r)
+                    self._enforce_deadlines(queue)
+
+                with span("engine.admit"):
+                    self._admit(queue)
+
+                prefilling = [s for s in self._slots if s.state == _PREFILL]
+                if prefilling:
+                    self._run_chunk(prefilling, queue)
+
+                decoding = [s for s in self._slots if s.state == _DECODE]
+                if decoding:
+                    # burst only when nothing competes for the device: no
+                    # prefill in flight, and no admissible work waiting (a
+                    # non-empty queue with every slot busy can't be
+                    # admitted, so it doesn't force single-stepping)
+                    can_admit = any(s.state == _FREE for s in self._slots)
+                    waiting = bool(queue) or (
+                        pending and pending[0].arrival <= now() + 1e-3)
+                    quiet = not prefilling and not (can_admit and waiting)
+                    n = self.burst if quiet else 1
+                    n = min(n, max(len(s.req.tokens) + s.req.max_new
+                                   - s.pos - 1 for s in decoding))
+                    self._run_decode(decoding, max(n, 1), queue)
+
+                if (prefilling or decoding) and (
+                        mon.end_step(tick) or slow_shards):
+                    flagged = mon.flags[-1] if mon.flags else None
+                    self.stats["straggler_flags"].append({
+                        "tick": tick, "shards": slow_shards,
+                        "injected": bool(slow_shards),
+                        "dt_s": flagged[1] if flagged else None,
+                        "zscore": flagged[2] if flagged else None})
 
             if not prefilling and not decoding and not queue and pending:
                 time.sleep(min(max(pending[0].arrival - now(), 0.0), 0.05))
 
-        wall = now()
+        with span("engine.finish"):
+            if self._drain_reason == "timeout":
+                self._drain_all(pending, queue, "global_timeout")
+            return self._summary(requests, now())
+
+    def _admit(self, queue: deque):
+        """FIFO admission while a slot is free and the pool can cover the
+        whole prompt (gating on full prompt pages, not just the first
+        chunk, keeps overcommit — and eviction thrash — down; pages past
+        the first chunk are still allocated lazily)."""
+        for slot in self._slots:
+            if not queue or slot.state != _FREE:
+                continue
+            req = queue[0]
+            if len(self._free_pages) < -(-len(req.tokens) // self.page_size):
+                break
+            first = -(-min(len(req.tokens), self.chunk) // self.page_size)
+            queue.popleft()
+            slot.state = _PREFILL
+            slot.req = req
+            slot.pages = [self._free_pages.pop() for _ in range(first)]
+            slot.admit_seq = self._admit_seq
+            self._admit_seq += 1
+            slot.admit_t = self._now()
+
+    def _summary(self, requests, wall: float) -> dict:
+        """The run's stats: records, statuses, goodput, latency, the step
+        time of each phase (its engine.step spans) and the exit audit."""
         records = self._records
         completed = [r for r in records if r["status"] == "completed"]
         lat = sorted(r["latency"] for r in completed)
 
         def pct(p):
             return lat[min(int(p * len(lat)), len(lat) - 1)] if lat else 0.0
+
+        def step_ms(*plans):
+            spans = self.stats["spans"]
+            return sum(spans[f"engine.step.{p}"]["ms"] for p in plans
+                       if f"engine.step.{p}" in spans)
 
         statuses: dict = {}
         for r in records:
@@ -740,6 +816,8 @@ class Engine:
             "generated_tokens": gen_tokens,
             "latency_p50_s": pct(0.50),
             "latency_p99_s": pct(0.99),
+            "prefill_ms": step_ms("chunk"),
+            "decode_ms": step_ms("decode", "burst"),
             "records": records,
             "page_audit": self.audit_pages(),
             "faults": self.faults.summary(),
@@ -747,6 +825,49 @@ class Engine:
         return dict(self.stats)
 
     # ---- phase steps ----------------------------------------------------
+
+    def _launch(self, plan: str, step, host, participants, queue, *,
+                tokens: int):
+        """One step launch under its ``engine.step`` span: ``step(params,
+        host[0], pools, *host[1:], key)``, then the blocking fetch of the
+        tokens it returns.  Returns them as numpy, or None after a failed
+        launch has been recovered (participants requeued or failed)."""
+        phase = "prefill" if plan == "chunk" else "decode"
+        failure = None
+        with self._span("engine.step", key=f"engine.step.{plan}", plan=plan,
+                        live=len(participants), tokens=tokens):
+            try:
+                if self.faults.fires("dist.collective_timeout"):
+                    self.stats["collective_timeouts"] += 1
+                    raise InjectedFault(
+                        f"injected collective timeout ({phase})")
+                if self.faults.fires("engine.step"):
+                    raise InjectedFault(f"injected {plan}-step failure")
+                with self._span("engine.dispatch"):
+                    first, *rest = (jnp.asarray(a) for a in host)
+                    toks, self.pools = step(self.params, first, self.pools,
+                                            *rest, self._split_key())
+            except InjectedFault:
+                failure = (True, None)
+            except Exception as e:  # noqa: BLE001 — any launch error retries
+                failure = (False, e)
+            else:
+                with self._span("engine.fetch"):
+                    toks = np.asarray(toks)
+        if failure is not None:
+            injected, error = failure
+            self._step_failure(participants, queue, injected=injected,
+                               phase=phase, error=error)
+            return None
+        return toks
+
+    def _page_tables(self, live) -> np.ndarray:
+        pt = np.zeros((self.slots, self.max_pages), np.int32)
+        ids = {id(s) for s in live}
+        for i, s in enumerate(self._slots):
+            if id(s) in ids:
+                pt[i, : len(s.pages)] = s.pages
+        return pt
 
     def _run_chunk(self, prefilling, queue):
         cs = self.chunk
@@ -757,63 +878,46 @@ class Engine:
             return (min(s.chunk_done + cs, len(s.req.tokens)) - 1) \
                 // self.page_size
 
-        prefilling = self._claim(
-            prefilling, pages_for_chunk, queue,
-            can_wait=any(s.state == _DECODE for s in self._slots))
+        with self._span("engine.claim"):
+            prefilling = self._claim(
+                prefilling, pages_for_chunk, queue,
+                can_wait=any(s.state == _DECODE for s in self._slots))
         if not prefilling:
             return
-        tokens = np.zeros((self.slots, cs), np.int32)
-        qpos = np.full((self.slots, cs), -1, np.int32)
-        pos0 = np.zeros((self.slots,), np.int32)
-        live = {id(s) for s in prefilling}
-        for s in prefilling:
-            i = self._slots.index(s)
-            seg = np.asarray(s.req.tokens[s.chunk_done: s.chunk_done + cs],
-                             np.int32)
-            tokens[i, : len(seg)] = seg
-            qpos[i, : len(seg)] = s.chunk_done + np.arange(len(seg))
-            pos0[i] = s.chunk_done
-        pt = np.zeros((self.slots, self.max_pages), np.int32)
-        for i, s in enumerate(self._slots):
-            if id(s) in live:
-                pt[i, : len(s.pages)] = s.pages
-        t0 = time.perf_counter()
-        try:
-            if self.faults.fires("dist.collective_timeout"):
-                self.stats["collective_timeouts"] += 1
-                raise InjectedFault("injected collective timeout (prefill)")
-            if self.faults.fires("engine.step"):
-                raise InjectedFault("injected chunk-step failure")
-            tok1, self.pools = self._chunk_step(
-                self.params, jnp.asarray(tokens), self.pools,
-                jnp.asarray(pt), jnp.asarray(qpos), jnp.asarray(pos0),
-                self._split_key())
-        except InjectedFault:
-            self._step_failure(prefilling, queue, injected=True,
-                               phase="prefill")
+        with self._span("engine.pack"):
+            tokens = np.zeros((self.slots, cs), np.int32)
+            qpos = np.full((self.slots, cs), -1, np.int32)
+            pos0 = np.zeros((self.slots,), np.int32)
+            for s in prefilling:
+                i = self._slots.index(s)
+                seg = np.asarray(
+                    s.req.tokens[s.chunk_done: s.chunk_done + cs], np.int32)
+                tokens[i, : len(seg)] = seg
+                qpos[i, : len(seg)] = s.chunk_done + np.arange(len(seg))
+                pos0[i] = s.chunk_done
+            pt = self._page_tables(prefilling)
+        tok1 = self._launch("chunk", self._chunk_step,
+                            (tokens, pt, qpos, pos0), prefilling, queue,
+                            tokens=int((qpos >= 0).sum()))
+        if tok1 is None:
             return
-        except Exception as e:  # noqa: BLE001 — any launch error retries
-            self._step_failure(prefilling, queue, injected=False,
-                               phase="prefill", error=e)
-            return
-        tok1 = np.asarray(tok1)
-        self.stats["prefill_ms"] += (time.perf_counter() - t0) * 1e3
-        self.stats["chunk_steps"] += 1
-        for s in prefilling:
-            i = self._slots.index(s)
-            s.chunk_done += cs
-            if s.chunk_done < len(s.req.tokens):
-                continue
-            if int(tok1[i]) == NONFINITE_TOKEN:
-                self._quarantine(s)
-                continue
-            s.state = _DECODE
-            s.tok = int(tok1[i])
-            s.pos = len(s.req.tokens)
-            s.out = [s.tok]
-            s.first_tok_t = time.perf_counter() - self._t0
-            if len(s.out) >= s.req.max_new:
-                self._finish(s)
+        with self._span("engine.commit"):
+            self.stats["chunk_steps"] += 1
+            for s in prefilling:
+                i = self._slots.index(s)
+                s.chunk_done += cs
+                if s.chunk_done < len(s.req.tokens):
+                    continue
+                if int(tok1[i]) == NONFINITE_TOKEN:
+                    self._quarantine(s)
+                    continue
+                s.state = _DECODE
+                s.tok = int(tok1[i])
+                s.pos = len(s.req.tokens)
+                s.out = [s.tok]
+                s.first_tok_t = time.perf_counter() - self._t0
+                if len(s.out) >= s.req.max_new:
+                    self._finish(s)
 
     def _poison_page(self, page: int):
         """Inject NaNs into one physical page across every float pool leaf
@@ -837,65 +941,47 @@ class Engine:
                        (len(s.req.tokens) + s.req.max_new - 2)
                        // self.page_size)
 
-        decoding = self._claim(decoding, pages_for_burst, queue,
-                               can_wait=False)
+        with self._span("engine.claim"):
+            decoding = self._claim(decoding, pages_for_burst, queue,
+                                   can_wait=False)
         if not decoding:
             return
         if self.faults.fires("engine.nan_logits"):
             victim = min(decoding, key=lambda s: s.admit_seq)
             if victim.pages:
                 self._poison_page(victim.pages[0])
-        tok = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        live = {id(s) for s in decoding}
-        for s in decoding:
-            i = self._slots.index(s)
-            tok[i] = s.tok
-            pos[i] = s.pos
-        pt = np.zeros((self.slots, self.max_pages), np.int32)
-        for i, s in enumerate(self._slots):
-            if id(s) in live:
-                pt[i, : len(s.pages)] = s.pages
-        step = self._burst_step if n == self.burst and self.burst > 1 \
-            else self._decode_step
-        if n not in (1, self.burst):
-            step = self._decode_step
-            n = 1
-        t0 = time.perf_counter()
-        try:
-            if self.faults.fires("dist.collective_timeout"):
-                self.stats["collective_timeouts"] += 1
-                raise InjectedFault("injected collective timeout (decode)")
-            if self.faults.fires("engine.step"):
-                raise InjectedFault("injected decode-step failure")
-            toks, self.pools = step(
-                self.params, jnp.asarray(tok), self.pools, jnp.asarray(pt),
-                jnp.asarray(pos), self._split_key())
-        except InjectedFault:
-            self._step_failure(decoding, queue, injected=True,
-                               phase="decode")
+        with self._span("engine.pack"):
+            tok = np.zeros((self.slots,), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            for s in decoding:
+                i = self._slots.index(s)
+                tok[i] = s.tok
+                pos[i] = s.pos
+            pt = self._page_tables(decoding)
+        if n == self.burst and self.burst > 1:
+            plan, step = "burst", self._burst_step
+        else:
+            plan, step, n = "decode", self._decode_step, 1
+        toks = self._launch(plan, step, (tok, pt, pos), decoding, queue,
+                            tokens=n * len(decoding))
+        if toks is None:
             return
-        except Exception as e:  # noqa: BLE001 — any launch error retries
-            self._step_failure(decoding, queue, injected=False,
-                               phase="decode", error=e)
-            return
-        toks = np.asarray(toks)
-        self.stats["decode_ms"] += (time.perf_counter() - t0) * 1e3
-        self.stats["decode_steps"] += n
-        for s in decoding:
-            i = self._slots.index(s)
-            poisoned = False
-            for j in range(toks.shape[1]):
-                if len(s.out) >= s.req.max_new:
-                    break
-                t = int(toks[i, j])
-                if t == NONFINITE_TOKEN:
-                    poisoned = True
-                    break
-                s.out.append(t)
-                s.tok = t
-                s.pos += 1
-            if poisoned:
-                self._quarantine(s)
-            elif len(s.out) >= s.req.max_new:
-                self._finish(s)
+        with self._span("engine.commit"):
+            self.stats["decode_steps"] += n
+            for s in decoding:
+                i = self._slots.index(s)
+                poisoned = False
+                for j in range(toks.shape[1]):
+                    if len(s.out) >= s.req.max_new:
+                        break
+                    t = int(toks[i, j])
+                    if t == NONFINITE_TOKEN:
+                        poisoned = True
+                        break
+                    s.out.append(t)
+                    s.tok = t
+                    s.pos += 1
+                if poisoned:
+                    self._quarantine(s)
+                elif len(s.out) >= s.req.max_new:
+                    self._finish(s)

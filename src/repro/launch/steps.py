@@ -17,9 +17,16 @@ Modes:
 ``jax.lax.scan`` over the whole generation budget: one jit, one dispatch,
 donated cache — decode cost becomes kernel-bound instead of paying a host
 round-trip per token (the decode fast path the paper's §4.4 speedup needs).
+
+Every step function runs inside a ``jax.named_scope`` named from its plan
+(``step_train``, ``step_prefill``, ``step_decode``, ``step_generate_g<gen>``,
+``step_chunk_prefill``, ``step_paged_generate_g<gen>``), with ``sample``
+around token sampling.  Scopes are op metadata: they name the ops in a
+profiler trace and leave the compiled program as it is.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable
@@ -71,6 +78,16 @@ def _meta_sharding(mesh, rules) -> dict:
     return dict(rules.summary(),
                 mesh={k: int(v) for k, v in dict(mesh.shape).items()},
                 model_parallel=int(dict(mesh.shape).get("model", 1)))
+
+
+@contextlib.contextmanager
+def _step_scope(name: str, rules, kernel_backend: str | None, mesh):
+    """What every step function traces under: its named scope, the plan's
+    activation rules, the pinned kernel backend and the shard scope."""
+    with jax.named_scope(name), activation_rules(rules.act_rules), \
+            dispatch.backend_scope(kernel_backend), \
+            dispatch.shard_scope(mesh):
+        yield
 
 
 @dataclasses.dataclass
@@ -204,9 +221,7 @@ def build_plan(cfg, mesh, shape_cfg, *, lr: float = 1e-4,
                                       shape_cfg.seq_len, tgt))
 
         def train_step(trainable, frozen, opt_state, batch, max_gnorm=None):
-            with activation_rules(rules.act_rules), \
-                    dispatch.backend_scope(kernel_backend), \
-                    dispatch.shard_scope(mesh):
+            with _step_scope("step_train", rules, kernel_backend, mesh):
                 def loss_fn(t, mb):
                     params = peft.combine(t, frozen)
                     loss, metrics = forward_train(params, cfg, mb)
@@ -285,9 +300,7 @@ def build_plan(cfg, mesh, shape_cfg, *, lr: float = 1e-4,
             # optional "positions" (b, s) rides in the batch dict: ragged
             # prompt lengths mask their padding out of the window (see
             # forward_prefill); absent = the aligned arange as before
-            with activation_rules(rules.act_rules), \
-                    dispatch.backend_scope(kernel_backend), \
-                    dispatch.shard_scope(mesh):
+            with _step_scope("step_prefill", rules, kernel_backend, mesh):
                 logits, new_cache = forward_prefill(
                     params, cfg, batch, cache, batch.get("positions"))
             return logits, new_cache
@@ -311,9 +324,7 @@ def build_plan(cfg, mesh, shape_cfg, *, lr: float = 1e-4,
         cfg, shape_cfg, mesh, rules, decode=True)
 
     def decode_step(params, batch, cache, pos):
-        with activation_rules(rules.act_rules), \
-                dispatch.backend_scope(kernel_backend), \
-                dispatch.shard_scope(mesh):
+        with _step_scope("step_decode", rules, kernel_backend, mesh):
             logits, new_cache = forward_decode(params, cfg, batch, cache, pos)
         return logits, new_cache
 
@@ -388,9 +399,8 @@ def build_generate_plan(cfg, mesh, shape_cfg, *, gen: int,
     embeds0 = batch.get("embeds")
 
     def generate_step(params, tok0, cache, pos0, key, embeds0=None):
-        with activation_rules(rules.act_rules), \
-                dispatch.backend_scope(kernel_backend), \
-                dispatch.shard_scope(mesh):
+        with _step_scope(f"step_generate_g{gen}", rules, kernel_backend,
+                         mesh):
             def body(carry, _):
                 tok, cache, pos, key = carry
                 if cfg.input_kind == "tokens":
@@ -400,8 +410,9 @@ def build_generate_plan(cfg, mesh, shape_cfg, *, gen: int,
                 logits, cache = forward_decode(params, cfg, step_in, cache,
                                                pos)
                 key, sub = jax.random.split(key)
-                nxt = sample_token(logits[:, -1, : cfg.vocab_size], sub,
-                                   temperature)
+                with jax.named_scope("sample"):
+                    nxt = sample_token(logits[:, -1, : cfg.vocab_size], sub,
+                                       temperature)
                 return (nxt, cache, pos + 1, key), nxt
 
             (_, cache, _, _), toks = jax.lax.scan(
@@ -479,13 +490,12 @@ def build_prefill_chunk_plan(cfg, mesh, *, slots: int, chunk: int,
     key_arg = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
     def chunk_step(params, tokens, pools, pt, qpos, pos0, key):
-        with activation_rules(rules.act_rules), \
-                dispatch.backend_scope(kernel_backend), \
-                dispatch.shard_scope(mesh):
+        with _step_scope("step_chunk_prefill", rules, kernel_backend, mesh):
             logits, pools = forward_prefill_chunk(
                 params, cfg, {"tokens": tokens}, pools, pt, qpos, pos0)
-            tok1 = sample_token_guarded(logits[:, -1, : cfg.vocab_size], key,
-                                        temperature)
+            with jax.named_scope("sample"):
+                tok1 = sample_token_guarded(
+                    logits[:, -1, : cfg.vocab_size], key, temperature)
         return tok1, pools
 
     return StepPlan(
@@ -533,16 +543,16 @@ def build_paged_generate_plan(cfg, mesh, *, slots: int, gen: int,
     key_arg = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
     def generate_step(params, tok0, pools, pt, pos0, key):
-        with activation_rules(rules.act_rules), \
-                dispatch.backend_scope(kernel_backend), \
-                dispatch.shard_scope(mesh):
+        with _step_scope(f"step_paged_generate_g{gen}", rules,
+                         kernel_backend, mesh):
             def body(carry, _):
                 tok, pools, pos, key = carry
                 logits, pools = forward_decode_paged(
                     params, cfg, {"tokens": tok}, pools, pt, pos)
                 key, sub = jax.random.split(key)
-                nxt = sample_token_guarded(logits[:, -1, : cfg.vocab_size],
-                                           sub, temperature)
+                with jax.named_scope("sample"):
+                    nxt = sample_token_guarded(
+                        logits[:, -1, : cfg.vocab_size], sub, temperature)
                 # a quarantined (-1) row keeps scanning on token 0 so its
                 # embedding lookup stays in range; the emitted -1 persists
                 # (its KV history is poisoned, logits stay non-finite) and

@@ -504,36 +504,39 @@ def _paged_store(pool, name, new, pt, pos=None, pos0=None):
     """Paged analogue of :func:`_kv_store`: quantize ``new`` to the pool's
     storage format and scatter it through the page table.  Exactly one of
     ``pos`` (b,) (single-token decode write) / ``pos0`` (b,) (page-aligned
-    chunk write) must be given."""
+    chunk write) must be given.  Named scope ``kv_store``."""
     scatter = (functools.partial(_paged_scatter_token, pt=pt, pos=pos)
                if pos is not None
                else functools.partial(_paged_scatter_chunk, pt=pt,
                                       pos0=pos0))
-    if f"{name}_scale" in pool:
-        codes, scale = kv_quantize(new)
-        return {name: scatter(pool[name], codes),
-                f"{name}_scale": scatter(pool[f"{name}_scale"], scale)}
-    return {name: scatter(pool[name], new)}
+    with jax.named_scope("kv_store"):
+        if f"{name}_scale" in pool:
+            codes, scale = kv_quantize(new)
+            return {name: scatter(pool[name], codes),
+                    f"{name}_scale": scatter(pool[f"{name}_scale"], scale)}
+        return {name: scatter(pool[name], new)}
 
 
 def _paged_window(pool, name, pt, dtype):
     """Gather + dequantize the full logical window (b, np*ps, ...) of slot
     ``name`` — the *prefix* read of chunked prefill (a chunk's queries
     attend to everything earlier sequences of chunks wrote).  Decode never
-    calls this: its reads go through the paged kernels."""
+    calls this: its reads go through the paged kernels.  Named scope
+    ``kv_window``."""
     arr = pool[name]
     P_, ps = arr.shape[:2]
     b = pt.shape[0]
-    flat = arr.reshape((P_ * ps,) + arr.shape[2:])
-    idx = (pt[:, :, None] * ps
-           + jnp.arange(ps, dtype=pt.dtype)[None, None]).reshape(b, -1)
-    win = jnp.take(flat, idx, axis=0)                   # (b, np*ps, ...)
-    if f"{name}_scale" in pool:
-        sarr = pool[f"{name}_scale"]
-        swin = jnp.take(sarr.reshape((P_ * ps,) + sarr.shape[2:]), idx,
-                        axis=0)
-        return kv_dequantize(win, swin, dtype=dtype)
-    return win.astype(dtype)
+    with jax.named_scope("kv_window"):
+        flat = arr.reshape((P_ * ps,) + arr.shape[2:])
+        idx = (pt[:, :, None] * ps
+               + jnp.arange(ps, dtype=pt.dtype)[None, None]).reshape(b, -1)
+        win = jnp.take(flat, idx, axis=0)               # (b, np*ps, ...)
+        if f"{name}_scale" in pool:
+            sarr = pool[f"{name}_scale"]
+            swin = jnp.take(sarr.reshape((P_ * ps,) + sarr.shape[2:]), idx,
+                            axis=0)
+            return kv_dequantize(win, swin, dtype=dtype)
+        return win.astype(dtype)
 
 
 def gqa_paged_cache_init(cfg, total_pages, page_size, dtype=jnp.bfloat16):

@@ -17,6 +17,10 @@ Modes
 Every linear is a quantized linear (cfg.quant) — LoRDS PEFT/QAT/frozen or any
 baseline.  VLM/audio archs (`input_kind='embeddings'`) take pre-computed
 frontend embeddings (the frontend itself is stubbed per assignment).
+
+The serving forwards name their parts with ``jax.named_scope``: ``embed``,
+``attn`` (the mixer with its norm and residual; ``mamba``/``mlstm``/``slstm``
+for recurrent mixers), ``mlp`` and ``final_norm_head``.
 """
 from __future__ import annotations
 
@@ -160,51 +164,59 @@ def paged_cache_init(cfg, total_pages, page_size):
 def _mlp_residual(blk, x, cfg, mlp_kind):
     """Shared post-mixer MLP residual (inference paths discard moe aux)."""
     q = cfg.quant
-    if mlp_kind == "dense":
-        h = rmsnorm(blk["ln2"], x, cfg.norm_eps)
-        x = x + moe_mod.dense_mlp_apply(blk["mlp"], h, cfg.d_model, cfg.d_ff,
-                                        q)
-    elif mlp_kind == "moe":
-        h = rmsnorm(blk["ln2"], x, cfg.norm_eps)
-        y, _ = moe_mod.moe_apply(blk["mlp"], h, cfg, q)
-        x = x + y
+    with jax.named_scope("mlp"):
+        if mlp_kind == "dense":
+            h = rmsnorm(blk["ln2"], x, cfg.norm_eps)
+            x = x + moe_mod.dense_mlp_apply(blk["mlp"], h, cfg.d_model,
+                                            cfg.d_ff, q)
+        elif mlp_kind == "moe":
+            h = rmsnorm(blk["ln2"], x, cfg.norm_eps)
+            y, _ = moe_mod.moe_apply(blk["mlp"], h, cfg, q)
+            x = x + y
     return x
 
 
 def _block_decode(blk, x, cfg, kind, cache, pos):
     mixer_kind, mlp_kind = kind
     q = cfg.quant
-    h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
-    if mixer_kind == "attn":
-        if cfg.attn_kind == "mla":
-            y, cache = attn.mla_decode(blk["mixer"], h, cfg, q, cache, pos)
+    with jax.named_scope(mixer_kind):
+        h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
+        if mixer_kind == "attn":
+            if cfg.attn_kind == "mla":
+                y, cache = attn.mla_decode(blk["mixer"], h, cfg, q, cache,
+                                           pos)
+            else:
+                y, cache = attn.gqa_decode(blk["mixer"], h, cfg, q, cache,
+                                           pos)
+        elif mixer_kind == "mamba":
+            y, cache = ssm.mamba_decode(blk["mixer"], h, cfg, q, cache, pos)
+        elif mixer_kind == "mlstm":
+            y, cache = ssm.mlstm_decode(blk["mixer"], h, cfg, q, cache, pos)
         else:
-            y, cache = attn.gqa_decode(blk["mixer"], h, cfg, q, cache, pos)
-    elif mixer_kind == "mamba":
-        y, cache = ssm.mamba_decode(blk["mixer"], h, cfg, q, cache, pos)
-    elif mixer_kind == "mlstm":
-        y, cache = ssm.mlstm_decode(blk["mixer"], h, cfg, q, cache, pos)
-    else:
-        y, cache = ssm.slstm_decode(blk["mixer"], h, cfg, q, cache, pos)
-    x = x + y
+            y, cache = ssm.slstm_decode(blk["mixer"], h, cfg, q, cache, pos)
+        x = x + y
     return _mlp_residual(blk, x, cfg, mlp_kind), cache
 
 
 def _block_prefill(blk, x, cfg, kind, cache, positions):
     mixer_kind, mlp_kind = kind
     q = cfg.quant
-    h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
-    if mixer_kind == "attn":
-        if cfg.attn_kind == "mla":
-            y, cache = attn.mla_prefill(blk["mixer"], h, cfg, q, positions, cache)
+    with jax.named_scope(mixer_kind):
+        h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
+        if mixer_kind == "attn":
+            if cfg.attn_kind == "mla":
+                y, cache = attn.mla_prefill(blk["mixer"], h, cfg, q,
+                                            positions, cache)
+            else:
+                y, cache = attn.gqa_prefill(blk["mixer"], h, cfg, q,
+                                            positions, cache)
         else:
-            y, cache = attn.gqa_prefill(blk["mixer"], h, cfg, q, positions, cache)
-    else:
-        # recurrent mixers: run the train path, then rebuild the final state
-        # by a single decode step is wasteful; instead run train path and keep
-        # zero states (prefill for SSM archs is exercised via train path).
-        y = _mixer_train(blk["mixer"], h, cfg, mixer_kind, positions)
-    x = x + y
+            # recurrent mixers: run the train path, then rebuild the final
+            # state by a single decode step is wasteful; instead run train
+            # path and keep zero states (prefill for SSM archs is exercised
+            # via train path).
+            y = _mixer_train(blk["mixer"], h, cfg, mixer_kind, positions)
+        x = x + y
     return _mlp_residual(blk, x, cfg, mlp_kind), cache
 
 
@@ -246,6 +258,27 @@ def _embed_in(params, cfg, batch):
 
 def _head_matrix(params, cfg):
     return params["head"] if "head" in params else params["embed"]
+
+
+def _final_norm_head(params, cfg, x, live=None):
+    """Final norm and head: x (b, t, d) -> f32 logits (b, t, Vp).  With
+    ``live`` (b, t) positions, only each row's last live token (t = 1)."""
+    with jax.named_scope("final_norm_head"):
+        if live is not None:
+            last = jnp.argmax(live, axis=1)
+            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = _head_matrix(params, cfg)
+        return f32_einsum("btd,vd->btv", x.astype(head.dtype), head)
+
+
+def _embed_step(params, cfg, batch):
+    """Decode-step input (b, 1, d): the token's embedding or the given
+    embedding."""
+    with jax.named_scope("embed"):
+        if cfg.input_kind == "tokens":
+            return jnp.take(params["embed"], batch["tokens"][:, None], axis=0)
+        return batch["embeds"].astype(jnp.bfloat16)
 
 
 def _remat_policy(cfg):
@@ -343,7 +376,8 @@ def forward_prefill(params, cfg, batch, cache, positions=None):
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    x = _embed_in(params, cfg, batch)
+    with jax.named_scope("embed"):
+        x = _embed_in(params, cfg, batch)
     kinds = cfg.layer_kinds()
 
     def period_body(x, inp):
@@ -367,20 +401,12 @@ def forward_prefill(params, cfg, batch, cache, positions=None):
                              _index_period(cache, p)))
             outs.append(nc)
         new_cache = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
-    last = jnp.argmax(positions, axis=1)                   # (b,) last live
-    x = jnp.take_along_axis(x, last[:, None, None], axis=1)  # (b, 1, d)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = _head_matrix(params, cfg)
-    logits = f32_einsum("btd,vd->btv", x.astype(head.dtype), head)
-    return logits, new_cache
+    return _final_norm_head(params, cfg, x, positions), new_cache
 
 
 def forward_decode(params, cfg, batch, cache, pos):
     """One decode step.  batch: token (b,) or embed (b,1,d); pos (b,) int32."""
-    if cfg.input_kind == "tokens":
-        x = jnp.take(params["embed"], batch["tokens"][:, None], axis=0)
-    else:
-        x = batch["embeds"].astype(jnp.bfloat16)
+    x = _embed_step(params, cfg, batch)
     kinds = cfg.layer_kinds()
 
     def period_body(x, inp):
@@ -401,19 +427,13 @@ def forward_decode(params, cfg, batch, cache, pos):
                                     _index_period(cache, p)))
             outs.append(nc)
         new_cache = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = _head_matrix(params, cfg)
-    logits = f32_einsum("btd,vd->btv", x.astype(head.dtype), head)
-    return logits, new_cache
+    return _final_norm_head(params, cfg, x), new_cache
 
 
 def forward_decode_paged(params, cfg, batch, pools, pt, pos):
     """One decode step against the page pools.  batch: token (b,) or embed
     (b,1,d); pt (b, np) page table; pos (b,) int32 current positions."""
-    if cfg.input_kind == "tokens":
-        x = jnp.take(params["embed"], batch["tokens"][:, None], axis=0)
-    else:
-        x = batch["embeds"].astype(jnp.bfloat16)
+    x = _embed_step(params, cfg, batch)
     dec = (attn.mla_decode_paged if cfg.attn_kind == "mla"
            else attn.gqa_decode_paged)
     q = cfg.quant
@@ -424,10 +444,12 @@ def forward_decode_paged(params, cfg, batch, pools, pt, pos):
         new_pools = {}
         for i in range(cfg.period):
             blk = layer_params[f"blk{i}"]
-            h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
-            y, new_pools[f"blk{i}"] = dec(blk["mixer"], h, cfg, q,
-                                          layer_pools[f"blk{i}"], pt, pos)
-            x = x + y
+            with jax.named_scope("attn"):
+                h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
+                y, new_pools[f"blk{i}"] = dec(blk["mixer"], h, cfg, q,
+                                              layer_pools[f"blk{i}"], pt,
+                                              pos)
+                x = x + y
             x = _mlp_residual(blk, x, cfg, kinds[i][1])
         return x, new_pools
 
@@ -441,10 +463,7 @@ def forward_decode_paged(params, cfg, batch, pools, pt, pos):
                                      _index_period(pools, p)))
             outs.append(np_)
         new_pools = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = _head_matrix(params, cfg)
-    logits = f32_einsum("btd,vd->btv", x.astype(head.dtype), head)
-    return logits, new_pools
+    return _final_norm_head(params, cfg, x), new_pools
 
 
 def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0):
@@ -455,11 +474,8 @@ def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0):
     each row's argmax(qpos) column — only meaningful for slots whose final
     prompt token is in this chunk (the scheduler samples token 1 from them
     then, and ignores them for slots still mid-prompt)."""
-    if cfg.input_kind == "tokens":
-        x = jnp.take(params["embed"], batch["tokens"], axis=0)
-    else:
-        x = batch["embeds"].astype(jnp.bfloat16)
-    x = shard(x, "batch", "seq", None)
+    with jax.named_scope("embed"):
+        x = _embed_in(params, cfg, batch)
     pre = (attn.mla_prefill_chunk if cfg.attn_kind == "mla"
            else attn.gqa_prefill_chunk)
     q = cfg.quant
@@ -470,10 +486,12 @@ def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0):
         new_pools = {}
         for i in range(cfg.period):
             blk = layer_params[f"blk{i}"]
-            h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
-            y, new_pools[f"blk{i}"] = pre(blk["mixer"], h, cfg, q, qpos,
-                                          pos0, layer_pools[f"blk{i}"], pt)
-            x = x + y
+            with jax.named_scope("attn"):
+                h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
+                y, new_pools[f"blk{i}"] = pre(blk["mixer"], h, cfg, q, qpos,
+                                              pos0, layer_pools[f"blk{i}"],
+                                              pt)
+                x = x + y
             x = _mlp_residual(blk, x, cfg, kinds[i][1])
         return x, new_pools
 
@@ -487,9 +505,4 @@ def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0):
                                      _index_period(pools, p)))
             outs.append(np_)
         new_pools = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
-    last = jnp.argmax(qpos, axis=1)
-    x = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = _head_matrix(params, cfg)
-    logits = f32_einsum("btd,vd->btv", x.astype(head.dtype), head)
-    return logits, new_pools
+    return _final_norm_head(params, cfg, x, qpos), new_pools

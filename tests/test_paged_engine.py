@@ -668,3 +668,76 @@ def test_engine_page_audit_detects_corruption(heng):
     assert not a["ok"] and any("duplicate" in s for s in a["issues"]), a
     eng._free_pages.pop()
     assert eng.audit_pages()["ok"]
+
+
+# ---------------------------------------------------------------------------
+# spans and the compile counter of Engine.run
+# ---------------------------------------------------------------------------
+
+_SPANS = ("engine.start", "engine.tick", "engine.intake", "engine.admit",
+          "engine.claim", "engine.pack", "engine.dispatch", "engine.fetch",
+          "engine.commit", "engine.finish")
+
+
+@pytest.mark.parametrize("fresh_jit", [False, True])
+def test_engine_spans_and_compile_count(heng, fresh_jit):
+    """Every phase of a run is a span; the engine.step spans count the
+    launches of each plan and sum to prefill_ms / decode_ms; no self time
+    is negative.  After warmup() nothing compiles in the window, unless a
+    jit the engine has not run yet is called there."""
+    cfg, _, eng = heng
+    if fresh_jit:
+        split = jax.jit(lambda k: jax.random.split(k))
+
+        def split_key():
+            eng._key, sub = split(eng._key)
+            return sub
+
+        eng._split_key = split_key
+    try:
+        stats = eng.run(_trace(cfg, [10, 20, 6], [6, 3, 9]), timeout_s=600)
+    finally:
+        eng.__dict__.pop("_split_key", None)
+    assert stats["all_completed"], stats
+    sp = stats["spans"]
+    assert set(_SPANS) <= set(sp), sorted(sp)
+    steps = {p: sp.get(f"engine.step.{p}", {"count": 0, "ms": 0.0})
+             for p in ("chunk", "decode", "burst")}
+    launches = sum(r["count"] for r in steps.values())
+    assert steps["chunk"]["count"] == stats["chunk_steps"] > 0
+    assert (steps["decode"]["count"] + eng.burst * steps["burst"]["count"]
+            == stats["decode_steps"] > 0)
+    assert sp["engine.dispatch"]["count"] == sp["engine.fetch"]["count"] \
+        == launches
+    assert sp["engine.start"]["count"] == sp["engine.finish"]["count"] == 1
+    assert stats["prefill_ms"] == steps["chunk"]["ms"]
+    assert stats["decode_ms"] == steps["decode"]["ms"] + steps["burst"]["ms"]
+    for name, rec in sp.items():
+        assert rec["ms"] >= rec["self_ms"] >= -1e-9, (name, rec)
+    if fresh_jit:
+        assert stats["compiles"] > 0
+    else:
+        assert stats["compiles"] == 0
+
+
+def test_engine_spans_reach_the_profiler(heng, tmp_path):
+    """Under a profiler the engine's spans are host events of the trace,
+    each step tagged with its plan."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg, _, eng = heng
+    with jax.profiler.trace(str(tmp_path)):
+        stats = eng.run(_trace(cfg, [10, 6], [4, 4]), timeout_s=600)
+    assert stats["all_completed"], stats
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = [ev for p in ProfileData.from_file(path).planes
+              for ln in p.lines for ev in ln.events]
+    names = {ev.name for ev in events}
+    assert set(_SPANS) | {"engine.step"} <= names, sorted(
+        n for n in names if n.startswith("engine."))
+    plans = {dict(ev.stats).get("plan") for ev in events
+             if ev.name == "engine.step"}
+    assert plans and plans <= {"chunk", "decode", "burst"}, plans

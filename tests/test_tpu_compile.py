@@ -147,3 +147,64 @@ def test_attention_compiles(one_chip):
              sd((slots, pages * page), jnp.float32),
              sd((pool, page, nkv), jnp.float32),
              sd((pool, page, nkv), jnp.float32), logit_scale=hd ** -0.5)
+
+
+def _kernel_bodies_without_locations(hlo: str) -> str:
+    """Each Mosaic kernel body (MLIR bytecode in the custom call's backend
+    config) replaced by its text without debug locations: a named scope
+    around a launch reaches only those locations."""
+    import base64
+    import re
+
+    from jax._src.lib import tpu  # noqa: F401 — registers the tpu dialect
+    from jax._src.lib.mlir import ir
+
+    def strip(m):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            mod = ir.Module.parse(base64.b64decode(m.group(1)))
+            return '"body":"%s"' % mod.operation.get_asm(
+                enable_debug_info=False).replace('"', "'")
+
+    return re.sub(r'"body":"([A-Za-z0-9+/=]+)"', strip, hlo)
+
+
+def test_paged_decode_step_scopes_compile_away(topo, one_chip, monkeypatch):
+    """The paged decode step at llama3-8b widths (2 layers, 4 slots)
+    compiles for the v5e to the unscoped program: the same instructions,
+    operands and kernels once metadata and instruction names are set
+    aside."""
+    import contextlib
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+    from test_named_scopes import canonical
+
+    from repro.configs import get_config
+    from repro.launch.steps import build_paged_generate_plan
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
+    rep = NamedSharding(mesh, PartitionSpec())
+    cfg = get_config("llama3-8b").with_(num_layers=2, kv_cache_dtype="int8")
+
+    def compiled():
+        plan = build_paged_generate_plan(
+            cfg, mesh, slots=4, gen=1, total_pages=33, page_size=16,
+            max_pages=8, kernel_backend="pallas")
+        args = tuple(
+            jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=s), a, sh)
+            if sh is not None else jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=rep), a)
+            for a, sh in zip(plan.abstract_args, plan.in_shardings))
+        text = jax.jit(plan.step_fn, out_shardings=plan.out_shardings,
+                       donate_argnums=(2,)).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return canonical(_kernel_bodies_without_locations(text))
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda n: contextlib.nullcontext())
+    assert compiled() == scoped

@@ -36,17 +36,36 @@ contribute, with the same finite-NEG_INF / alpha-correction NaN hygiene as
 :mod:`repro.kernels.attn_prefill`.
 
 Paged variants — the continuous-batching engine stores KV in a global pool
-of fixed-size pages (page == kv tile) with a per-sequence page table
-``pt`` (b, np) int32.  ``pt`` rides in as a *scalar-prefetch* operand
-(:class:`pltpu.PrefetchScalarGridSpec`), so the BlockSpec index maps
-dereference it directly —
+of fixed-size pages with a per-sequence page table ``pt`` (b, np) int32:
+logical page ``pi`` of sequence ``b`` lives at physical page ``pt[b, pi]``.
 
-    k_pool tile for (seq b, logical page pi) = k_pool[pt[b, pi]]
-
-— and the pool tiles DMA straight from their stored (possibly int8)
-layout, exactly like the contiguous kernels: no gather into a contiguous
-per-sequence temp, no out-of-kernel dequant.  The kernel bodies are the
-*same functions* as the contiguous path; only the index maps change.
+  GQA:  the code pools stay in HBM as stored (P, ps, nkv, hd), viewed
+        (P, ps·nkv, hd): rows in (token, head) order, a free reshape where
+        the stored tile holds a token's heads whole (XLA relayouts an int8
+        pool of fewer than 4 heads).  The scale pools (P, ps, nkv) are
+        viewed (P, 1, ps·nkv) and padded to whole lane rows, one row per
+        page: XLA relayouts both at every call, since Mosaic cannot copy a
+        stored (ps, nkv) tile narrower than a lane row, and fetching those
+        tiles through BlockSpecs instead costs the kernel more than the
+        relayout.  A page's rows land in a VMEM slot of ``width`` rows,
+        ps·nkv rounded up to 128, matching its scale row's lanes.
+        ``pt`` and each slot's live length ``lens = pos + 1`` (at least 1)
+        are scalar-prefetched.  Grid (b, ⌈np/ppb⌉): one step per slot and
+        block of ``ppb`` pages (about 128 tokens).  A live block's live
+        pages (index < ⌈len/ps⌉) are DMA'd page by page into a
+        double-buffered VMEM scratch, and the copies of the next live block
+        (this slot's next, or the next slot's first) start before this one
+        computes; a block at or past the length starts no copy and does no
+        work.  All KV heads of the slot go in one step: the block's
+        (ppb·ps·nkv, hd) rows meet all nkv·g8 query rows in one score
+        tile, and a static head mask keeps each query row to its own
+        head's columns — so every page's rows and its scale row are read
+        once, with the scales folded into the score and value weights.
+        Liveness comes from an iota against ``lens``; dead columns (and
+        rows of pages never copied) are masked, never read into the sums.
+  MLA:  ``pt`` rides in the BlockSpec index maps (scalar prefetch), one
+        page per grid step over the whole window, masked by ``kmask`` —
+        the same kernel body as the contiguous MLA decode.
 """
 from __future__ import annotations
 
@@ -135,59 +154,6 @@ def _gqa_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, nk, quantized):
         o_ref[0, 0] = acc_ref[...] * inv
 
 
-def _gqa_call(q, k, v, kmask, k_scale, v_scale, *, q_map, kv_map,
-              scale_map, mask_map, grid, bs, nk, logit_scale, interpret,
-              prefetch=()):
-    """Shared pallas_call of the contiguous and paged GQA decode: the
-    kernel body and operand views are identical, only the KV / scale /
-    mask index maps (and the scalar-prefetched page table) differ."""
-    b, nkv, g8, hd = q.shape
-    hdv = v.shape[-1]
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
-    in_specs = [
-        pl.BlockSpec((1, 1, g8, hd), q_map),
-        pl.BlockSpec((1, bs, hd), kv_map),
-        pl.BlockSpec((1, bs, hdv), kv_map),
-        pl.BlockSpec((1, 1, 1, bs), mask_map),
-    ]
-    args = [q, k.reshape(k.shape[0], k.shape[1], nkv * hd),
-            v.reshape(v.shape[0], v.shape[1], nkv * hdv),
-            _row_tiles(kmask, bs)]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, bs, nkv), scale_map)] * 2
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-    kern = functools.partial(
-        _gqa_kernel, scale=float(logit_scale), nk=nk, quantized=quantized)
-    if prefetch:
-        body = kern
-
-        def kern(*refs):  # scalar-prefetch operands arrive first
-            body(*refs[len(prefetch):])
-    out_spec = pl.BlockSpec((1, 1, g8, hdv), q_map)
-    scratch = [
-        pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
-        pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
-        pltpu.VMEM((g8, hdv), jnp.float32),
-    ]
-    out_shape = jax.ShapeDtypeStruct((b, nkv, g8, hdv), jnp.float32)
-    if prefetch:
-        return pl.pallas_call(
-            kern,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(prefetch), grid=grid,
-                in_specs=in_specs, out_specs=out_spec,
-                scratch_shapes=scratch),
-            out_shape=out_shape,
-            interpret=interpret,
-        )(*prefetch, *args)
-    return pl.pallas_call(
-        kern, grid=grid, in_specs=in_specs, out_specs=out_spec,
-        out_shape=out_shape, scratch_shapes=scratch, interpret=interpret,
-    )(*args)
-
-
 @functools.partial(
     jax.jit, static_argnames=("logit_scale", "bs", "interpret"))
 def attn_decode_gqa_pallas(
@@ -217,14 +183,38 @@ def attn_decode_gqa_pallas(
         raise ValueError(
             f"cache length {cap} % tile {bs} or rows {g8} % {DECODE_ROWS}")
     nk = cap // bs
-    return _gqa_call(
-        q, k, v, kmask, k_scale, v_scale,
-        q_map=lambda bi, hi, ki: (bi, hi, 0, 0),
-        kv_map=lambda bi, hi, ki: (bi, ki, hi),
-        scale_map=lambda bi, hi, ki: (bi, ki, 0),
-        mask_map=lambda bi, hi, ki: (bi, ki, 0, 0),
-        grid=(q.shape[0], q.shape[1], nk), bs=bs, nk=nk,
-        logit_scale=logit_scale, interpret=interpret)
+    b, nkv, _, hd = q.shape
+    hdv = v.shape[-1]
+    quantized = k_scale is not None
+    if quantized != (v_scale is not None):
+        raise ValueError("pass both k_scale and v_scale, or neither")
+    q_map = lambda bi, hi, ki: (bi, hi, 0, 0)  # noqa: E731
+    kv_map = lambda bi, hi, ki: (bi, ki, hi)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((1, 1, g8, hd), q_map),
+        pl.BlockSpec((1, bs, hd), kv_map),
+        pl.BlockSpec((1, bs, hdv), kv_map),
+        pl.BlockSpec((1, 1, 1, bs), lambda bi, hi, ki: (bi, ki, 0, 0)),
+    ]
+    args = [q, k.reshape(b, cap, nkv * hd), v.reshape(b, cap, nkv * hdv),
+            _row_tiles(kmask, bs)]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, bs, nkv),
+                                  lambda bi, hi, ki: (bi, ki, 0))] * 2
+        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    kern = functools.partial(
+        _gqa_kernel, scale=float(logit_scale), nk=nk, quantized=quantized)
+    return pl.pallas_call(
+        kern, grid=(b, nkv, nk), in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, g8, hdv), q_map),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, g8, hdv), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((g8, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((g8, hdv), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*args)
 
 
 def _mla_kernel(ql_ref, qr_ref, c_ref, kr_ref, mask_ref, *rest, scale, nk,
@@ -331,13 +321,112 @@ def attn_decode_mla_pallas(
 # ---------------------------------------------------------------------------
 
 
+_BLOCK_TOKENS = 128  # tokens a paged GQA grid step covers (whole pages)
+
+
+def _gqa_paged_kernel(lens_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest, scale,
+                      ppb, npages, quantized):
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, buf_ref,
+         m_ref, l_ref, acc_ref) = rest
+        scales = ((ks_hbm, ksbuf), (vs_hbm, vsbuf))
+    else:
+        o_ref, kbuf, vbuf, sem, buf_ref, m_ref, l_ref, acc_ref = rest
+        scales = ()
+    bi, ki = pl.program_id(0), pl.program_id(1)
+    nb = pl.num_programs(0)
+    nkv, g8, hd = q_ref.shape[1:]
+    hdv = vbuf.shape[-1]
+    width = kbuf.shape[2]                # a page's rows, padded to 128
+    ps = k_hbm.shape[1] // nkv
+    rows, cols = nkv * g8, ppb * width
+
+    def live_pages(b):
+        return jnp.minimum((lens_ref[b] + ps - 1) // ps, npages)
+
+    def block_copies(b, blk, slot, fn):
+        """``fn`` on the DMA of every live page of block ``blk`` of slot
+        ``b`` (codes, and scales as one row) into buffer ``slot``."""
+        def body(j, carry):
+            page = pt_ref[b * npages + blk * ppb + j]
+            for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                fn(pltpu.make_async_copy(
+                    src.at[page], dst.at[slot, j, pl.ds(0, ps * nkv)],
+                    sem.at[slot]))
+            for src, dst in scales:
+                fn(pltpu.make_async_copy(src.at[page], dst.at[slot, j],
+                                         sem.at[slot]))
+            return carry
+        jax.lax.fori_loop(
+            0, jnp.minimum(ppb, live_pages(b) - blk * ppb), body, 0)
+
+    @pl.when((bi == 0) & (ki == 0))
+    def _prime():
+        buf_ref[0] = 0
+        block_copies(0, 0, 0, lambda c: c.start())
+
+    npl = live_pages(bi)
+
+    @pl.when(ki * ppb < npl)
+    def _block():
+        slot = buf_ref[0]
+        more = (ki + 1) * ppb < npl
+
+        @pl.when(more | (bi + 1 < nb))
+        def _prefetch():  # this slot's next block, or the next slot's first
+            block_copies(jnp.where(more, bi, bi + 1),
+                         jnp.where(more, ki + 1, 0), 1 - slot,
+                         lambda c: c.start())
+            buf_ref[0] = 1 - slot
+
+        @pl.when(ki == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, ATTN_NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        block_copies(bi, ki, slot, lambda c: c.wait())
+        left = lens_ref[bi] - ki * ppb * ps     # live tokens from here on
+
+        def is_live(c):  # column / row c: page c // width, (token, head)
+            lane = c % width
+            return (lane < ps * nkv) & ((c // width) * ps + lane // nkv
+                                         < left)
+
+        live = is_live(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1))
+        # query row h·g8 + i scores only the columns (token, h) of its head
+        mine = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % nkv
+                == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // g8)
+        q = q_ref[0].astype(jnp.float32).reshape(rows, hd) * scale
+        k = kbuf[slot].astype(jnp.float32).reshape(cols, hd)
+        v = vbuf[slot].astype(jnp.float32).reshape(cols, hdv)
+        # rows never copied (padding, later pages) hold stale VMEM: zero them
+        v = jnp.where(is_live(jax.lax.broadcasted_iota(jnp.int32, (cols, 1),
+                                                       0)), v, 0.0)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        v_scale = None
+        if quantized:  # per-(token, head) scales, one row per page
+            s = s * jnp.concatenate([ksbuf[slot, j] for j in range(ppb)], 1)
+            v_scale = jnp.where(live, jnp.concatenate(
+                [vsbuf[slot, j] for j in range(ppb)], 1), 0.0)
+        s = jnp.where(mine & live, s, ATTN_NEG_INF)
+        _online_update(s, v, m_ref, l_ref, acc_ref, p_scale=v_scale)
+
+        @pl.when(ki == (npl + ppb - 1) // ppb - 1)
+        def _store():
+            l = l_ref[:, :1]
+            inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
+            o_ref[0] = (acc_ref[...] * inv).reshape(nkv, g8, hdv)
+
+
 @functools.partial(jax.jit, static_argnames=("logit_scale", "interpret"))
 def attn_decode_gqa_paged_pallas(
     pt: jnp.ndarray,
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
-    kmask: jnp.ndarray,
+    lens: jnp.ndarray,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     *,
@@ -348,29 +437,63 @@ def attn_decode_gqa_paged_pallas(
 
     ``pt`` (b, np) int32 maps logical page ``pi`` of sequence ``b`` to its
     physical page in ``k_pool``/``v_pool`` (P, ps, nkv, hd) [+ scale pools
-    (P, ps, nkv)].  ``kmask`` (b, np*ps) masks the logical window (dead
-    beyond ``pos``, so dummy/unallocated pages never contribute).  The kv
-    tile size *is* the page size; grid (b, nkv, np) with ``pt`` consulted
-    inside the index maps (scalar prefetch) — the pool is read once, as
-    stored, with scales folded in-kernel.  Returns (b, nkv, g8, hd_v) f32.
+    (P, ps, nkv)].  ``lens`` (b,) int32 is each sequence's live length
+    (``pos + 1``, at least 1): only the pages it covers are read.  Grid
+    (b, ⌈np/ppb⌉) with ``ppb`` pages of about 128 tokens a step; see the
+    module docstring.  Returns (b, nkv, g8, hd_v) f32.
     """
-    b, nkv, g8, _ = q.shape
-    ps = k_pool.shape[1]
+    b, nkv, g8, hd = q.shape
+    n_pool, ps, _, hdv = v_pool.shape
     npages = pt.shape[1]
     if ps % 8 or g8 % DECODE_ROWS:
         raise ValueError(
             f"page size {ps} % 8 or rows {g8} % {DECODE_ROWS}")
-    if kmask.shape != (b, npages * ps):
-        raise ValueError(
-            f"kmask {kmask.shape} != (b, np*ps) = {(b, npages * ps)}")
-    return _gqa_call(
-        q, k_pool, v_pool, kmask, k_scale, v_scale,
-        q_map=lambda bi, hi, ki, pt_ref: (bi, hi, 0, 0),
-        kv_map=lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, hi),
-        scale_map=lambda bi, hi, ki, pt_ref: (pt_ref[bi, ki], 0, 0),
-        mask_map=lambda bi, hi, ki, pt_ref: (bi, ki, 0, 0),
-        grid=(b, nkv, npages), bs=ps, nk=npages,
-        logit_scale=logit_scale, interpret=interpret, prefetch=(pt,))
+    if lens.shape != (b,):
+        raise ValueError(f"lens {lens.shape} != (b,) = {(b,)}")
+    quantized = k_scale is not None
+    if quantized != (v_scale is not None):
+        raise ValueError("pass both k_scale and v_scale, or neither")
+    ppb = max(1, min(npages, _BLOCK_TOKENS // ps))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    slot_map = lambda bi, ki, *_: (bi, 0, 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, nkv, g8, hd), slot_map), pool_spec,
+                pool_spec]
+    # a page's codes as ps·nkv rows in (token, head) order, each copied into
+    # a slot of ``width`` rows; its scales as one row of ``width`` lanes
+    width = -(-ps * nkv // 128) * 128
+    args = [q, k_pool.reshape(n_pool, ps * nkv, hd),
+            v_pool.reshape(n_pool, ps * nkv, hdv)]
+    scratch = [pltpu.VMEM((2, ppb, width, hd), k_pool.dtype),
+               pltpu.VMEM((2, ppb, width, hdv), v_pool.dtype)]
+    if quantized:
+        in_specs += [pool_spec] * 2
+        args += [jnp.pad(sc.astype(jnp.float32).reshape(-1, 1, ps * nkv),
+                         ((0, 0), (0, 0), (0, width - ps * nkv)))
+                 for sc in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, ppb, 1, width), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),     # the buffer the next block is in
+        pltpu.VMEM((nkv * g8, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((nkv * g8, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((nkv * g8, hdv), jnp.float32),
+    ]
+    kern = functools.partial(
+        _gqa_paged_kernel, scale=float(logit_scale), ppb=ppb, npages=npages,
+        quantized=quantized)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, pl.cdiv(npages, ppb)),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, nkv, g8, hdv), slot_map),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, g8, hdv), jnp.float32),
+        # steps run in order: each one starts the next live block's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(lens.astype(jnp.int32), pt.reshape(-1), *args)
 
 
 @functools.partial(jax.jit, static_argnames=("logit_scale", "interpret"))

@@ -989,12 +989,14 @@ def _qmatmul(params, x, spec, n, m, backend, tiles):
 #                      engine.  Serving-only: no VJP.
 #   kind="paged_decode" / "paged_mla_decode"
 #                      block-paged variants of the decode kinds: the KV
-#                      lives in a global page pool (P, ps, ...) and a
-#                      per-sequence page table (b, np) rides into the
-#                      Pallas index maps as a scalar-prefetch operand — the
-#                      int8 pool streams once, as stored, no gather into a
+#                      lives in a global page pool (P, ps, ...) read through
+#                      a per-sequence page table (b, np), scalar-prefetched
+#                      — the int8 pool is read as stored, no gather into a
 #                      contiguous temp (the ref oracles *do* gather; that
-#                      gather is the jaxpr-guard negative control).
+#                      gather is the jaxpr-guard negative control).  Paged
+#                      GQA copies only each slot's live pages (pos + 1
+#                      tokens), all KV heads in one grid step; paged MLA
+#                      still walks every page of the window.
 #
 # Sharding: attention is head-local and batch-local, so inside a
 # shard_scope the fused kernels run under shard_map with heads on the
@@ -1294,17 +1296,16 @@ def _attn_mla_fused(q_lat, q_rope, c, k_rope, pos, c_scale, logit_scale,
 def _attn_paged_run(q, k_pool, v_pool, pt, pos, k_scale, v_scale,
                     logit_scale, backend):
     """q (b,nh,hd) vs page pools (P,ps,nkv,hd) [+ scale pools (P,ps,nkv)]
-    through the page table pt (b,np) → (b,nh,hdv) f32.  The kv tile is the
-    page — no tile padding of the pool, and no gather: pt rides into the
-    kernel's index maps."""
+    through the page table pt (b,np) → (b,nh,hdv) f32.  No padding of the
+    pool and no gather: the kernel copies each slot's live pages, the
+    ``pos + 1`` tokens of its context, straight from the pool."""
     b, nh, hd = q.shape
-    ps, nkv = k_pool.shape[1], k_pool.shape[2]
+    nkv = k_pool.shape[2]
     g = nh // nkv
     g8 = _round_up(g, DECODE_ROWS)
     qg = _pad_axis(q.reshape(b, nkv, g, hd), 2, g8)
-    cap = pt.shape[1] * ps
     y = attn_decode_gqa_paged_pallas(
-        pt, qg, k_pool, v_pool, _decode_kmask(pos, cap), k_scale, v_scale,
+        pt, qg, k_pool, v_pool, pos + 1, k_scale, v_scale,
         logit_scale=float(logit_scale), interpret=(backend == "interpret"))
     return y[:, :, :g].reshape(b, nh, v_pool.shape[-1])
 
@@ -1497,9 +1498,10 @@ _ATTN_CANDIDATES = {
     "decode": ((DECODE_ROWS, 128), (DECODE_ROWS, 256), (DECODE_ROWS, 512)),
     "mla_decode": ((DECODE_ROWS, 128), (DECODE_ROWS, 256),
                    (DECODE_ROWS, 512)),
-    # paged decode has no tile freedom (the kv tile IS the page size); a
-    # single sentinel candidate still times + registers the autotune key so
-    # paged launches are attributable in the persisted table
+    # paged decode has no tile freedom (paged GQA fixes its block from the
+    # page size, paged MLA walks single pages); a single sentinel candidate
+    # still times + registers the autotune key so paged launches are
+    # attributable in the persisted table
     "paged_decode": ((DECODE_ROWS, 0),),
     "paged_mla_decode": ((DECODE_ROWS, 0),),
 }

@@ -98,6 +98,11 @@ read of its tokens) and ``engine.commit``; then ``engine.finish``.
 ``prefill_ms`` and ``decode_ms`` are the ``engine.step`` sums of their
 plans.  ``stats['compiles']`` counts the executables built during the run
 (compiled or loaded from the persistent cache, eager ops included).
+``stats['decode_pages']`` counts, over the decode steps of every launch,
+the pages paged attention reads for the live rows (``live``: ⌈(pos+1)/ps⌉
+each, within the window) against their whole page windows (``window``:
+rows × ``max_pages``); each decode ``engine.step`` is tagged with its
+``pages``.
 """
 from __future__ import annotations
 
@@ -609,7 +614,8 @@ class Engine:
                       "preempted": False, "mesh_rebuilds": 0,
                       "lost_devices": 0, "resharded_restores": 0,
                       "collective_timeouts": 0, "straggler_flags": [],
-                      "step_errors": [], "spans": {}, "compiles": 0}
+                      "step_errors": [], "spans": {}, "compiles": 0,
+                      "decode_pages": {"live": 0, "window": 0}}
 
         def count_compile(event, duration_secs, **kwargs):
             if event == _BACKEND_COMPILE_EVENT:
@@ -827,15 +833,16 @@ class Engine:
     # ---- phase steps ----------------------------------------------------
 
     def _launch(self, plan: str, step, host, participants, queue, *,
-                tokens: int):
-        """One step launch under its ``engine.step`` span: ``step(params,
-        host[0], pools, *host[1:], key)``, then the blocking fetch of the
-        tokens it returns.  Returns them as numpy, or None after a failed
-        launch has been recovered (participants requeued or failed)."""
+                tokens: int, **tags):
+        """One step launch under its ``engine.step`` span (tagged with
+        ``tags`` too): ``step(params, host[0], pools, *host[1:], key)``,
+        then the blocking fetch of the tokens it returns.  Returns them as
+        numpy, or None after a failed launch has been recovered
+        (participants requeued or failed)."""
         phase = "prefill" if plan == "chunk" else "decode"
         failure = None
         with self._span("engine.step", key=f"engine.step.{plan}", plan=plan,
-                        live=len(participants), tokens=tokens):
+                        live=len(participants), tokens=tokens, **tags):
             try:
                 if self.faults.fires("dist.collective_timeout"):
                     self.stats["collective_timeouts"] += 1
@@ -962,12 +969,21 @@ class Engine:
             plan, step = "burst", self._burst_step
         else:
             plan, step, n = "decode", self._decode_step, 1
+        # pages paged decode attention reads for the live rows: each row's
+        # pos + 1 tokens at every step of the launch, within its window
+        rows = pos[[self._slots.index(s) for s in decoding]]
+        lens = rows[:, None] + np.arange(1, n + 1)
+        live_pages = int(np.minimum(-(-lens // self.page_size),
+                                    self.max_pages).sum())
         toks = self._launch(plan, step, (tok, pt, pos), decoding, queue,
-                            tokens=n * len(decoding))
+                            tokens=n * len(decoding), pages=live_pages)
         if toks is None:
             return
         with self._span("engine.commit"):
             self.stats["decode_steps"] += n
+            self.stats["decode_pages"]["live"] += live_pages
+            self.stats["decode_pages"]["window"] += \
+                n * len(decoding) * self.max_pages
             for s in decoding:
                 i = self._slots.index(s)
                 poisoned = False

@@ -63,7 +63,25 @@ def _scan_tokens(cfg, prompt, gen, params):
 
 # (batch, page_size, logical pages, physical pages, nh, nkv, hd) — positions
 # off the page grid, GQA group > 1, pool larger than any one sequence
-PAGED_SHAPES = [(2, 8, 5, 9, 4, 2, 16), (1, 16, 3, 7, 8, 2, 24)]
+PAGED_SHAPES = [(2, 8, 5, 9, 4, 2, 16), (1, 16, 3, 7, 8, 2, 24),
+                (2, 16, 20, 41, 4, 2, 16), (2, 8, 21, 44, 8, 2, 16),
+                (3, 16, 20, 61, 4, 2, 32), (32, 16, 144, 1793, 32, 8, 128)]
+# positions of the cases of the live-context walk (blocks of 128 tokens:
+# 8 pages at page size 16, 16 at page size 8); the others are drawn
+LIVE_POS = {
+    # a slot at pos 0, and one live page of 20
+    (2, 16, 20, 41, 4, 2, 16): [0, 15],
+    # 21 pages, not a multiple of 16: the last block is 5 pages, the first
+    # slot's window ends in it, the second slot's context ends in block 0
+    (2, 8, 21, 44, 8, 2, 16): [167, 100],
+    # lengths on block boundaries (128, 256) and one token past one
+    (3, 16, 20, 61, 4, 2, 32): [127, 255, 128],
+    # the batch-decode cell's geometry (Qwen3-8B heads, 32 slots, windows
+    # of 144 pages of 16, a 1,793-page pool) with its 180-400 token
+    # contexts: one long chain of blocks across every slot
+    (32, 16, 144, 1793, 32, 8, 128):
+        np.random.default_rng(5).integers(179, 400, 32).tolist(),
+}
 
 
 def _page_table(rng, b, np_, total):
@@ -79,7 +97,10 @@ def test_paged_decode_kernel_matches_ref(b, ps, np_, tp, nh, nkv, hd, kv):
     rng = np.random.default_rng(0)
     q = jax.random.normal(jax.random.PRNGKey(0), (b, nh, hd))
     pt = _page_table(rng, b, np_, tp)
-    pos = jnp.asarray(rng.integers(1, np_ * ps, (b,)), jnp.int32)
+    pos = LIVE_POS.get((b, ps, np_, tp, nh, nkv, hd))
+    if pos is None:
+        pos = rng.integers(1, np_ * ps, (b,))
+    pos = jnp.asarray(pos, jnp.int32)
     sc = 1.0 / hd ** 0.5
     if kv == "int8":
         kp = jnp.asarray(rng.integers(-127, 128, (tp, ps, nkv, hd)), jnp.int8)
@@ -98,6 +119,79 @@ def test_paged_decode_kernel_matches_ref(b, ps, np_, tp, nh, nkv, hd, kv):
                        backend="interpret")
     assert _cos(y_int, y_ref) > 0.9999
     assert _maxerr(y_int, y_ref) < 3e-5
+
+
+@pytest.mark.parametrize("interpret", ["backend", "nan_scratch"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_decode_kernel_skips_dead_pages(interpret, kv):
+    """NaN in every pool position past each slot's length — the rest of
+    its last page, every later page, and the dummy page 0 that its dead
+    table entries point at — never reaches the output: dead pages are
+    neither read into the sums nor computed on.  A bf16 pool holds the NaN
+    in its codes, an int8 pool in its scales.  ``nan_scratch`` runs the
+    kernel under the TPU interpreter, which fills fresh VMEM with NaN and
+    raises on a read out of bounds: its dead table entries name a page
+    past the pool, so a copy of any dead page fails, and a block that
+    computed on pages it never copied would show."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.attn_decode import attn_decode_gqa_paged_pallas
+
+    b, ps, np_, nh, nkv, hd = 3, 8, 40, 8, 2, 16
+    pos = np.array([0, 150, 19])
+    live = -(-(pos + 1) // ps)
+    tp = int(live.sum()) + 4
+    rng = np.random.default_rng(3)
+    pages = rng.permutation(np.arange(1, tp))
+    pt = np.zeros((b, np_), np.int32)
+    start = np.concatenate([[0], np.cumsum(live)])
+    for i in range(b):
+        pt[i, : live[i]] = pages[start[i]: start[i + 1]]
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, nh, hd))
+    if kv == "int8":
+        kp = rng.integers(-127, 128, (tp, ps, nkv, hd)).astype(np.int8)
+        vp = rng.integers(-127, 128, (tp, ps, nkv, hd)).astype(np.int8)
+        ks = rng.uniform(0.01, 0.05, (tp, ps, nkv)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.05, (tp, ps, nkv)).astype(np.float32)
+        pools = [kp, vp, ks, vs]
+    else:
+        pools = [np.array(jax.random.normal(jax.random.PRNGKey(i),
+                                            (tp, ps, nkv, hd)), np.float32)
+                 for i in (1, 2)]
+    sc = 1.0 / hd ** 0.5
+    pt, posj = jnp.asarray(pt), jnp.asarray(pos, jnp.int32)
+    dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
+
+    def operands():
+        return [jnp.asarray(x, dtype if x.ndim == 4 else jnp.float32)
+                for x in pools]
+
+    kv_ref = operands()
+    y_ref = qattention("paged_decode", q, kv_ref[0], kv_ref[1], pt, posj,
+                       *kv_ref[2:], logit_scale=sc, backend="ref")
+    dead = np.ones((tp, ps), bool)
+    for i in range(b):
+        for t in range(pos[i] + 1):
+            dead[pt[i, t // ps], t % ps] = False
+    assert dead[0].all() and dead.sum() > (tp - live.sum()) * ps
+    for x in pools:
+        if x.dtype != np.int8:
+            x[dead] = np.nan
+    kp, vp, *scales = operands()
+    if interpret == "backend":
+        y = qattention("paged_decode", q, kp, vp, pt, posj, *scales,
+                       logit_scale=sc, backend="interpret")
+    else:
+        qg = q.reshape(b, nkv, nh // nkv, hd)
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 8 - nh // nkv), (0, 0)))
+        unmapped = jnp.arange(np_)[None, :] >= jnp.asarray(live)[:, None]
+        y = attn_decode_gqa_paged_pallas(
+            jnp.where(unmapped, tp + 5, pt), qg, kp, vp, posj + 1, *scales,
+            logit_scale=sc,
+            interpret=pltpu.InterpretParams())[:, :, : nh // nkv]
+        y = y.reshape(b, nh, hd)
+    assert np.isfinite(np.asarray(y)).all()
+    assert _maxerr(y, y_ref) < 3e-5
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -720,6 +814,43 @@ def test_engine_spans_and_compile_count(heng, fresh_jit):
         assert stats["compiles"] == 0
 
 
+def test_engine_counts_decode_pages(heng):
+    """stats["decode_pages"]: ``live`` is Σ⌈(pos+1)/ps⌉ over the live rows
+    of every decode step (each step of a burst one token further on),
+    ``window`` their rows × max_pages; each decode launch's engine.step
+    carries its own count."""
+    cfg, _, eng = heng
+    launches = []
+    launch = eng._launch
+
+    def record(plan, step, host, participants, queue, **kw):
+        if plan != "chunk":
+            rows = [eng._slots.index(s) for s in participants]
+            launches.append((plan, np.asarray(host[2])[rows].copy(),
+                             kw.get("pages")))
+        return launch(plan, step, host, participants, queue, **kw)
+
+    eng._launch = record
+    try:
+        stats = eng.run(_trace(cfg, [10, 20, 6], [6, 3, 9]), timeout_s=600)
+    finally:
+        del eng._launch
+    assert stats["all_completed"], stats
+    assert launches
+    live = window = steps = 0
+    for plan, pos, tagged in launches:
+        n = eng.burst if plan == "burst" else 1
+        need = sum(-(-(p + j + 1) // eng.page_size)
+                   for p in pos for j in range(n))
+        assert tagged == need, (plan, pos, tagged)
+        live += need
+        window += len(pos) * eng.max_pages * n
+        steps += n
+    assert steps == stats["decode_steps"]
+    assert stats["decode_pages"] == {"live": live, "window": window}
+    assert 0 < live < window
+
+
 def test_engine_spans_reach_the_profiler(heng, tmp_path):
     """Under a profiler the engine's spans are host events of the trace,
     each step tagged with its plan."""
@@ -741,3 +872,7 @@ def test_engine_spans_reach_the_profiler(heng, tmp_path):
     plans = {dict(ev.stats).get("plan") for ev in events
              if ev.name == "engine.step"}
     assert plans and plans <= {"chunk", "decode", "burst"}, plans
+    pages = [dict(ev.stats).get("pages") for ev in events
+             if ev.name == "engine.step"
+             and dict(ev.stats).get("plan") != "chunk"]
+    assert sum(pages) == stats["decode_pages"]["live"] > 0, pages

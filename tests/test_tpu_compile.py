@@ -12,6 +12,7 @@ one process may hold the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,7 +123,16 @@ def test_block_matmul_compiles(one_chip):
 
 def test_attention_compiles(one_chip):
     """Flash prefill (bf16), the contiguous int8-cache decode, and the paged
-    decode over an int8 page pool."""
+    decode over an int8 page pool at the batch-decode cell's geometry (32
+    slots, windows of 144 pages of 16, a 1,793-page pool) with 8 KV heads,
+    and with the 4 and 2 a chip holds when tensor parallelism splits them.
+    The pools are row-major, as the engine stores them; at 8 and 4 heads
+    the kernel reads the code pools in place, with no relayout or copy (2
+    int8 heads are padded to a 4-row tile, so XLA relayouts that pool into
+    the kernel's page-rows view; the scale pools' one-row-per-page view is
+    a relayout at every head count)."""
+    from jax.experimental.layout import Format, Layout
+
     sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     b, s, nh, nkv, hd = 1, 512, 32, 8, 128
@@ -132,21 +142,47 @@ def test_attention_compiles(one_chip):
              sd((b, s, nkv, hd), jnp.bfloat16),
              sd((b, s, nkv, hd), jnp.bfloat16), sd((b, s), jnp.int32),
              sd((b, s), jnp.int32), logit_scale=hd ** -0.5, bq=bq, bkv=bkv)
-    slots, pages, page, pool = 8, 34, 16, 8 * 34 + 1
-    cap = 640
+    slots, page, cap = 8, 16, 640
     _compile(attn_decode_gqa_pallas, sd((slots, nkv, 8, hd), jnp.bfloat16),
              sd((slots, cap, nkv, hd), jnp.int8),
              sd((slots, cap, nkv, hd), jnp.int8),
              sd((slots, cap), jnp.float32), sd((slots, cap, nkv), jnp.float32),
              sd((slots, cap, nkv), jnp.float32), logit_scale=hd ** -0.5,
              bs=128)
-    _compile(attn_decode_gqa_paged_pallas, sd((slots, pages), jnp.int32),
-             sd((slots, nkv, 8, hd), jnp.bfloat16),
-             sd((pool, page, nkv, hd), jnp.int8),
-             sd((pool, page, nkv, hd), jnp.int8),
-             sd((slots, pages * page), jnp.float32),
-             sd((pool, page, nkv), jnp.float32),
-             sd((pool, page, nkv), jnp.float32), logit_scale=hd ** -0.5)
+    rm = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=Format(Layout(tuple(range(len(shape)))),
+                                   one_chip))
+    slots, pages, pool = 32, 144, 1793
+    for heads in (8, 4, 2):
+        text = _compile(attn_decode_gqa_paged_pallas,
+                        rm((slots, pages), jnp.int32),
+                        rm((slots, heads, 8, hd), jnp.bfloat16),
+                        rm((pool, page, heads, hd), jnp.int8),
+                        rm((pool, page, heads, hd), jnp.int8),
+                        rm((slots,), jnp.int32),
+                        rm((pool, page, heads), jnp.float32),
+                        rm((pool, page, heads), jnp.float32),
+                        logit_scale=hd ** -0.5)
+        if heads == 2:
+            continue
+        # the code pools reach the kernel as the parameters they are: no
+        # instruction but a bitcast or a move to another memory space (the
+        # same layout) touches them
+        code_pool = f"s8[{pool},{page},{heads},{hd}]"
+
+        def layouts(line):
+            return {re.sub(r"S\(\d+\)", "", lay) for lay in re.findall(
+                re.escape(code_pool) + r"(\{[^}]*\})", line)}
+
+        lines = [ln for ln in text.splitlines() if code_pool in ln]
+        stored = set().union(*(layouts(ln) for ln in lines
+                               if "parameter(" in ln))
+        assert len(stored) == 1
+        assert not [ln for ln in lines if not any(
+            op in ln for op in ("parameter(", "bitcast(", "tpu_custom_call",
+                                "HloModule", "ENTRY"))
+            and not (("copy-start(" in ln or "copy-done(" in ln)
+                     and layouts(ln) == stored)]
 
 
 def _kernel_bodies_without_locations(hlo: str) -> str:

@@ -17,11 +17,12 @@ first, then by the part before its first dot, so ``decode_step_ms.batch``
 and a later ``decode_step_ms.<cell kind>`` share ``decode_step_ms.py``.
 
 A run: check the devices (a TPU, as many chips as the cell asks for, a
-device kind with published peaks), set up (timed as ``setup_s``), run the
-window (under the profiler when ``trace``), read the peak memory, free the
-program's state, run the correctness check, read the metrics, print the
-counters, then the compared numbers on standard error and the JSON line
-last on standard output.
+device kind with published peaks), check the configuration file key by key
+against what the program states (``published.py``), set up (timed as
+``setup_s``), run the window (under the profiler when ``trace``), read the
+peak memory, free the program's state, run the correctness check, read the
+metrics, print the counters, then the compared numbers on standard error
+and the JSON line last on standard output.
 """
 from __future__ import annotations
 
@@ -35,6 +36,8 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+
+from chipbench import published
 
 __all__ = ["ROOT", "Cell", "RunContext", "NoChip", "load_cell",
            "load_module", "load_reference", "model_config", "judge",
@@ -147,8 +150,11 @@ def load_reference(cfg: dict):
 
 def model_config(cfg: dict):
     """The program's ModelConfig for a configuration file: the registry
-    entry it names, with every size, norm and quantization setting taken
-    from the file."""
+    entry it names, with the dense sizes, norm and quantization settings
+    taken from the file (``head_dim`` absent or null: the program's d /
+    heads).  Every other published key of the file must agree with what
+    the program states (``published.check``), or the run stops here,
+    before set-up."""
     from repro.configs import get_config
 
     base = get_config(cfg["registry"])
@@ -156,14 +162,17 @@ def model_config(cfg: dict):
     quant = base.quant.with_(
         method="lords", codebook=q["codebook"], block_size=q["block_size"],
         mode=q["mode"], rank=None if q["rank"] == "parity" else q["rank"])
-    return base.with_(
+    model_cfg = base.with_(
         num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
         num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        num_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
         d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
         rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
         tie_embeddings=cfg["tie_word_embeddings"],
         kv_cache_dtype=q["kv_cache_dtype"], quant=quant)
+    published.check(cfg, model_cfg)
+    return model_cfg
 
 
 def _devices(chips: int):
@@ -325,7 +334,7 @@ def main(argv=None) -> int:
     try:
         cell = load_cell(args.workload)
         result = run_cell(cell, args.seed, args.seconds, bool(args.trace))
-    except NoChip as e:
+    except (NoChip, published.ConfigMismatch) as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 2
     for name, c in result["checks"].items():

@@ -93,7 +93,7 @@ class Job:
                  r["first_token"] is not None) for r in recs]
         shape = work.shape_of(self.ctx.cfg)
         wk = work.serve_work(shape, rows, self.g["chunk"], st["chunk_steps"],
-                             st["decode_steps"])
+                             st["decode_steps"], st.get("experts_hit"))
         statuses = st["statuses"]
         backlog = self.mix["arrivals"]["kind"] == "backlog"
         admitted = sum(r["admitted"] is not None for r in recs)

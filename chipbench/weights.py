@@ -24,6 +24,11 @@ first or last by that sum, its sign set by a near-cancelling ``c``.  With
 random signs the mean part of each linear is a rank-1 term between two
 random directions, which no later layer feeds again.
 
+A router (f32, experts × d) is normal with std ``1/sqrt(d)``, so the
+routing logits of an RMS-normed row are about unit normal: neither uniform
+nor one-hot.  Expert-stacked linears are quantized linears with a leading
+expert axis, drawn by the same rule.
+
 The reference gets the very same arrays; nothing the program computes
 goes into them.
 """
@@ -125,7 +130,9 @@ def _draw_tree(key, tree):
             out[name] = _draw_tree(k, v)
         elif name in ("embed", "head"):
             out[name] = _normal(k, v, EMBED_STD)
-        elif name in ("ln1", "ln2", "final_norm"):
+        elif name == "router":
+            out[name] = _normal(k, v, 1.0 / math.sqrt(v.shape[-1]))
+        elif name in ("ln1", "ln2", "final_norm", "q_norm", "kv_norm"):
             out[name] = (1.0 + NORM_JITTER * jax.random.normal(
                 k, v.shape, jnp.float32)).astype(v.dtype)
         else:

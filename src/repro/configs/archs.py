@@ -6,12 +6,15 @@ by per-arch CPU smoke tests.
 """
 from __future__ import annotations
 
+import dataclasses
+
 from repro.configs.base import (
     MLACfg,
     MambaCfg,
     ModelConfig,
     MoECfg,
     XLSTMCfg,
+    YarnCfg,
     register,
 )
 
@@ -139,6 +142,33 @@ def jamba_1_5_large_398b_cfg() -> ModelConfig:
     )
 
 
+@register
+def deepseek_v2_lite_cfg() -> ModelConfig:
+    # [hf:deepseek-ai/DeepSeek-V2-Lite config.json] 27L d=2048 16H, MLA
+    # without q LoRA; layer 0 a dense SwiGLU of 10944, then 26 layers of 64
+    # routed experts of 1408 (top-6 softmax, unrenormalised) + 2 shared
+    return ModelConfig(
+        name="deepseek-v2-lite", family="moe", num_layers=27, d_model=2048,
+        num_heads=16, num_kv_heads=16, d_ff=10944, vocab_size=102400,
+        attn_kind="mla",
+        mla=MLACfg(q_lora_rank=None, kv_lora_rank=512, qk_nope_dim=128,
+                   qk_rope_dim=64, v_head_dim=128),
+        moe=MoECfg(num_experts=64, top_k=6, d_ff=1408, n_shared=2,
+                   first_dense=1, scoring_func="softmax",
+                   norm_topk_prob=False, routed_scaling_factor=1.0,
+                   topk_method="greedy", n_group=1, topk_group=1),
+        rope_theta=10000.0,
+        rope_scaling=YarnCfg(factor=40.0,
+                             original_max_position_embeddings=4096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                             mscale_all_dim=0.707),
+        norm_eps=1e-6, micro_tokens=2048,
+        notes="rope in the repo's half-split pair layout (DeepSeek pairs "
+              "adjacent dims: a fixed permutation of the rope columns); "
+              "kv_b stored as two linears, k_up and v_up.",
+    )
+
+
 # ---------------------------------------------------------------------------
 # the paper's own models (Tables 1-6)
 # ---------------------------------------------------------------------------
@@ -186,12 +216,19 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     )
     if cfg.period > 1:
         kw["num_layers"] = cfg.period  # one full heterogeneous period
+    if cfg.lead_layers:
+        # the leading dense layers, then at least two scanned layers
+        kw["num_layers"] = cfg.lead_layers + max(kw["num_layers"], 2)
     if cfg.attn_kind == "mla":
-        kw["mla"] = MLACfg(q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
-                           qk_rope_dim=8, v_head_dim=16)
+        kw["mla"] = MLACfg(
+            q_lora_rank=None if cfg.mla.q_lora_rank is None else 32,
+            kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
     if cfg.moe is not None:
-        kw["moe"] = MoECfg(num_experts=4, top_k=min(2, cfg.moe.top_k),
-                           d_ff=64, every=cfg.moe.every)
+        # routing, shared experts and leading dense layers as the config
+        # has them; top-k stays below the expert count
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, num_experts=4, top_k=min(2, cfg.moe.top_k), d_ff=64,
+            pad_experts_to=None)
     if cfg.mamba is not None:
         kw["mamba"] = MambaCfg(d_state=8, d_conv=4, expand=2, chunk=16)
     if cfg.xlstm is not None:

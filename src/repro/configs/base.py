@@ -10,8 +10,8 @@ import jax.numpy as jnp
 from repro.core.lords import QuantSpec
 
 __all__ = [
-    "MoECfg", "MLACfg", "MambaCfg", "XLSTMCfg", "ModelConfig", "ShapeCfg",
-    "SHAPES", "register", "get_config", "list_configs",
+    "MoECfg", "MLACfg", "YarnCfg", "MambaCfg", "XLSTMCfg", "ModelConfig",
+    "ShapeCfg", "SHAPES", "register", "get_config", "list_configs",
 ]
 
 
@@ -29,6 +29,35 @@ class MoECfg:
     #               (the §Perf fix for collective-bound MoE training)
     dispatch: str = "pjit"
     pad_experts_to: int | None = None  # pad so EP divides the device count
+    # DeepSeek-style settings; None means "not set" and keeps the legacy
+    # behaviour (no shared experts, no leading dense layers, softmax top-k
+    # with the gates renormalised to sum to 1)
+    n_shared: int | None = None        # shared experts: one SwiGLU of n·d_ff
+    first_dense: int | None = None     # leading dense layers (d_ff wide)
+    scoring_func: str | None = None    # router scores: 'softmax'
+    norm_topk_prob: bool | None = None  # renormalise the top-k gates
+    routed_scaling_factor: float | None = None  # gate scale if not renormed
+    topk_method: str | None = None     # 'greedy' (plain top-k)
+    n_group: int | None = None         # expert groups (1: ungrouped)
+    topk_group: int | None = None      # groups a token may use
+
+    def __post_init__(self):
+        if self.scoring_func not in (None, "softmax"):
+            raise ValueError(f"router scoring {self.scoring_func!r}: only "
+                             "softmax is implemented")
+        if self.topk_method not in (None, "greedy") or (
+                self.n_group or 1) != 1 or (self.topk_group or 1) != 1:
+            raise ValueError("only greedy top-k over one expert group is "
+                             "implemented")
+
+    @property
+    def renormalise(self) -> bool:
+        return self.norm_topk_prob is not False
+
+    @property
+    def shared_ff(self) -> int:
+        """Width of the shared-expert SwiGLU (0: none)."""
+        return (self.n_shared or 0) * self.d_ff
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,6 +67,31 @@ class MLACfg:
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
     v_head_dim: int = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn):
+    inverse frequencies blended between interpolated (÷ factor) and
+    original by a linear ramp over the dims whose wavelength lies between
+    ``beta_fast`` and ``beta_slow`` rotations of the original context; the
+    attention softmax scale multiplied by ``mscale(mscale_all_dim)²`` and
+    cos/sin by ``mscale(mscale) / mscale(mscale_all_dim)``, where
+    ``mscale(m) = 0.1·m·ln(factor) + 1``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def published(self) -> dict:
+        return {"beta_fast": self.beta_fast, "beta_slow": self.beta_slow,
+                "factor": self.factor, "mscale": self.mscale,
+                "mscale_all_dim": self.mscale_all_dim,
+                "original_max_position_embeddings":
+                    self.original_max_position_embeddings,
+                "type": "yarn"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +131,7 @@ class ModelConfig:
     #        xlstm: ('mlstm',)*7 + ('slstm',)
     layer_pattern: tuple = ("attn",)
     rope_theta: float = 500000.0
+    rope_scaling: YarnCfg | None = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     input_kind: str = "tokens"     # tokens | embeddings (vlm/audio stubs)
@@ -103,6 +158,12 @@ class ModelConfig:
         return int(math.ceil(self.vocab_size / m) * m)
 
     @property
+    def lead_layers(self) -> int:
+        """Leading dense layers (attention + a d_ff SwiGLU), kept out of
+        the scan over periods; the periods cover the layers after them."""
+        return (self.moe.first_dense or 0) if self.moe is not None else 0
+
+    @property
     def pattern(self) -> tuple:
         """Full per-layer mixer pattern of length num_layers (tiled)."""
         p = self.layer_pattern
@@ -115,20 +176,21 @@ class ModelConfig:
         p = len(self.layer_pattern)
         if self.moe is not None and self.moe.every > 1:
             p = math.lcm(p, self.moe.every)
-        if self.num_layers % p:
+        scanned = self.num_layers - self.lead_layers
+        if scanned % p:
             # fall back to unrolled if the pattern doesn't tile evenly
-            return self.num_layers
+            return scanned
         return p
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // self.period
+        return (self.num_layers - self.lead_layers) // self.period
 
     def layer_kinds(self, period_idx: int = 0) -> list[tuple[str, str]]:
         """[(mixer_kind, mlp_kind)] for one scan period."""
         out = []
         for i in range(self.period):
-            layer = period_idx * self.period + i
+            layer = self.lead_layers + period_idx * self.period + i
             mixer = self.pattern[i % len(self.pattern)]
             if self.moe is not None and layer % self.moe.every == (self.moe.every - 1 if self.moe.every > 1 else 0):
                 mlp = "moe"

@@ -129,6 +129,17 @@ def lords_init_from_weight(
     if rank is None:
         rank = parity_rank(n, m, block_size, extra_rank)
     block_size = eff_block(m, block_size)
+    pad = -m % block_size
+    if pad:
+        # a partial last block (DeepSeek-V2-Lite's 10944 at 128): scales
+        # from the real columns zero-padded to whole blocks; the padding's
+        # columns of A are dropped
+        cs = None if channel_scale is None else jnp.pad(
+            channel_scale, (0, pad), constant_values=1.0)
+        b, a = lords_init_from_weight(jnp.pad(w, ((0, 0), (0, pad))),
+                                      block_size, rank=rank,
+                                      channel_scale=cs)
+        return b, a[:, :m]
     c = None
     if channel_scale is not None:
         c = jnp.maximum(jnp.abs(channel_scale.astype(w.dtype)), SCALE_EPS)

@@ -126,8 +126,10 @@ def estimate_quantized_gb(cfg, pack: int = 2) -> float:
             if cfg.attn_kind == "mla":
                 m = cfg.mla
                 qk = m.qk_nope_dim + m.qk_rope_dim
-                per_layer += (d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * qk
-                              + d * (m.kv_lora_rank + m.qk_rope_dim)
+                q_proj = (d * cfg.num_heads * qk if m.q_lora_rank is None
+                          else d * m.q_lora_rank
+                          + m.q_lora_rank * cfg.num_heads * qk)
+                per_layer += (q_proj + d * (m.kv_lora_rank + m.qk_rope_dim)
                               + m.kv_lora_rank * cfg.num_heads
                               * (m.qk_nope_dim + m.v_head_dim)
                               + cfg.num_heads * m.v_head_dim * d)
@@ -148,9 +150,11 @@ def estimate_quantized_gb(cfg, pack: int = 2) -> float:
         if mlp == "dense":
             per_layer += 3 * d * cfg.d_ff
         elif mlp == "moe":
-            per_layer += cfg.moe.num_experts * 3 * d * cfg.moe.d_ff
-    reps = cfg.num_layers / cfg.period
-    q_bytes = reps * per_layer / pack
+            per_layer += (cfg.moe.num_experts * 3 * d * cfg.moe.d_ff
+                          + 3 * d * cfg.moe.shared_ff)
+    # the leading dense layers' MLPs (their attention is left out)
+    lead = cfg.lead_layers * 3 * d * cfg.d_ff
+    q_bytes = (cfg.num_periods * per_layer + lead) / pack
     embed_bytes = cfg.padded_vocab * d * 2 * (1 if cfg.tie_embeddings else 2)
     return float(q_bytes + embed_bytes) / 1e9
 

@@ -112,6 +112,7 @@ from repro.kernels.lut_quantize import lut_quantize_pallas
 __all__ = [
     "BACKENDS",
     "qmatmul",
+    "qmatmul_experts",
     "qattention",
     "default_backend",
     "fused_backend_active",
@@ -960,6 +961,52 @@ def _qmatmul(params, x, spec, n, m, backend, tiles):
 
 
 # ---------------------------------------------------------------------------
+# Grouped expert matmul (dropless mixture of experts)
+# ---------------------------------------------------------------------------
+
+
+def qmatmul_experts(params: dict, x: jnp.ndarray, tiles, spec, n: int,
+                    m: int, *, backend: str | None = None) -> jnp.ndarray:
+    """y = x @ Ŵ[e]ᵀ row tile by row tile, for an expert-stacked quantized
+    linear (leaves with a leading expert axis).
+
+    ``x`` (tiles·rows, m) holds rows grouped by expert; ``tiles`` is
+    ``(tile_expert, tile_row, tile_live, rows)`` as
+    :func:`repro.models.moe.group_rows` builds it.  Fused lords weights run
+    the grouped kernel (:mod:`repro.kernels.lords_experts`) over the packed
+    codes of the whole stack; every other backend, method or mode runs each
+    tile's expert through :func:`qmatmul` on that expert's gathered
+    leaves.  Rows of dead tiles hold no defined value."""
+    from repro.core import quantize as quantize_mod
+    from repro.kernels.lords_experts import (
+        expert_tiles,
+        lords_experts_pallas,
+        plane_pad,
+    )
+
+    backend = _resolve(backend)
+    tile_expert, tile_row, tile_live, rows = tiles
+    cd = spec.compute_dtype
+    if (backend in _FUSED and spec.method == "lords" and spec.mode != "qat"
+            and _fused_supported(params, spec)
+            and quantize_mod.pack_spec(spec.codebook).group_bytes == 1):
+        ba = spec.ba_compute_dtype
+        bn, t = expert_tiles(n, m, spec.codebook)
+        y = lords_experts_pallas(
+            plane_pad(x.astype(cd), m, spec.codebook), tile_expert,
+            tile_row, tile_live, params["q"],
+            jnp.swapaxes(params["b"].astype(ba), 1, 2),
+            plane_pad(params["a"].astype(ba), m, spec.codebook),
+            spec.codebook, rows=rows, bn=bn, t=t,
+            interpret=backend == "interpret")
+        return y.astype(cd)
+    per_tile = jax.tree.map(lambda v: v[tile_expert], params)
+    y = jax.vmap(lambda p, xt: qmatmul(p, xt, spec, n, m, backend=backend)
+                 )(per_tile, x.reshape(-1, rows, m))
+    return y.reshape(-1, n)
+
+
+# ---------------------------------------------------------------------------
 # Fused attention dispatch (flash prefill + quantized-KV decode)
 # ---------------------------------------------------------------------------
 #
@@ -1059,9 +1106,23 @@ def _pad_axis(arr, axis: int, to: int, value=0):
 # ---- prefill ----
 
 
+def _lane_heads(q, k, v, backend):
+    """On the chip, head dims padded with zeros to the 128-lane tile (a
+    head is a lane-aligned column block of the flattened heads; MLA's
+    nope + rope of 192 is not): exact, the zero columns add nothing to a
+    score or an output."""
+    if backend != "pallas":
+        return q, k, v
+    qk = _round_up(q.shape[-1], 128)
+    return (_pad_axis(q, 3, qk), _pad_axis(k, 3, qk),
+            _pad_axis(v, 3, _round_up(v.shape[-1], 128)))
+
+
 def _attn_prefill_run(q, k, v, positions, logit_scale, backend, tiles):
     """Pad-to-tile + flash kernel, all in the model's native layouts.
     q (b,s,nh,hd), k/v (b,s,nkv,hd), positions (b,s) → (b,s,nh,hdv) f32."""
+    hdv = v.shape[-1]
+    q, k, v = _lane_heads(q, k, v, backend)
     b, s, nh, hd = q.shape
     nkv = k.shape[2]
     bq, bkv = tiles or attn_tile_for(
@@ -1076,7 +1137,7 @@ def _attn_prefill_run(q, k, v, positions, logit_scale, backend, tiles):
     y = attn_prefill_pallas(
         qt, kt, vt, qpos, kpos, logit_scale=float(logit_scale),
         bq=bq, bkv=bkv, interpret=(backend == "interpret"))
-    return y[:, :s]
+    return y[:, :s, :, :hdv]
 
 
 def _attn_prefill_fused(q, k, v, positions, logit_scale, backend, tiles):
@@ -1134,6 +1195,8 @@ def _attn_chunk_run(q, k, v, qpos, kpos, logit_scale, backend, tiles):
     (b,s,nh,hdv) f32.  Same kernel as prefill — the flash kernel already
     takes separate query/key position arrays; only the padding differs
     (q and kv lengths round up to their tiles independently)."""
+    hdv = v.shape[-1]
+    q, k, v = _lane_heads(q, k, v, backend)
     b, s, nh, hd = q.shape
     skv, nkv = k.shape[1], k.shape[2]
     bq, bkv = tiles or attn_tile_for(
@@ -1149,7 +1212,7 @@ def _attn_chunk_run(q, k, v, qpos, kpos, logit_scale, backend, tiles):
     y = attn_prefill_pallas(
         qt, kt, vt, qp, kp, logit_scale=float(logit_scale),
         bq=bq, bkv=bkv, interpret=(backend == "interpret"))
-    return y[:, :s]
+    return y[:, :s, :, :hdv]
 
 
 def _attn_chunk_fused(q, k, v, qpos, kpos, logit_scale, backend, tiles):

@@ -109,9 +109,10 @@ def lut_select(codes, levels: tuple[float, ...]):
     return jnp.broadcast_to(pick(0, nbits - 1), codes.shape)
 
 
-def _kernel(x_ref, *refs, ps, levels, eps, nk):
+def _kernel(x_ref, *refs, ps, levels, eps, nk, kk=None):
     *q_refs, bt_ref, a_ref, o_ref = refs
-    kk = pl.program_id(2)
+    if kk is None:
+        kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
@@ -131,6 +132,12 @@ def _kernel(x_ref, *refs, ps, levels, eps, nk):
         preferred_element_type=jnp.float32,
     )                                                          # (bm, bn)
     o_ref[...] += acc
+
+
+# one grid step (K step ``kk``, grid axis 2 where not given): one code plane
+# of one packed tile against its x / A columns, accumulated into the output
+# tile; shared with the grouped expert kernel (``lords_experts``)
+tile_body = _kernel
 
 
 @functools.partial(

@@ -102,7 +102,12 @@ plans.  ``stats['compiles']`` counts the executables built during the run
 the pages paged attention reads for the live rows (``live``: ⌈(pos+1)/ps⌉
 each, within the window) against their whole page windows (``window``:
 rows × ``max_pages``); each decode ``engine.step`` is tagged with its
-``pages``.
+``pages``.  For a config with experts, ``stats['experts_hit']``
+(``prefill``, ``decode``) sums over steps, burst sub-steps and expert
+layers the distinct routed experts the live rows were sent to, and
+``stats['expert_rows']`` per phase the live rows' assignments
+(``assigned``) and the rows the grouped expert matmul computed
+(``computed``).
 """
 from __future__ import annotations
 
@@ -197,6 +202,8 @@ class Engine:
         if total_pages < 2:
             raise ValueError("need at least one real page beyond the dummy")
         self.cfg = cfg
+        # expert configs: the steps count the grouped expert layers' work
+        self._experts = cfg.moe is not None
         self.slots = slots
         self.total_pages = total_pages
         self.page_size = page_size
@@ -279,14 +286,17 @@ class Engine:
         z_pos = jnp.zeros((self.slots,), jnp.int32)
         z_pt = jnp.zeros((self.slots, self.max_pages), jnp.int32)
         z_t = jnp.zeros((self.slots,), jnp.int32)
-        tok1, self.pools = self._chunk_step(
+        z_live = (z_t,) if self._experts else ()
+        tok1, self.pools, *_ = self._chunk_step(
             self.params, z_tok, self.pools, z_pt, z_qpos, z_pos,
             self._split_key())
-        toks, self.pools = self._decode_step(
-            self.params, z_t, self.pools, z_pt, z_pos, self._split_key())
+        toks, self.pools, *_ = self._decode_step(
+            self.params, z_t, self.pools, z_pt, z_pos, *z_live,
+            self._split_key())
         if self._burst_step is not self._decode_step:
-            toks, self.pools = self._burst_step(
-                self.params, z_t, self.pools, z_pt, z_pos, self._split_key())
+            toks, self.pools, *_ = self._burst_step(
+                self.params, z_t, self.pools, z_pt, z_pos, *z_live,
+                self._split_key())
         jax.block_until_ready((tok1, toks))
         self._warm = True
 
@@ -616,6 +626,11 @@ class Engine:
                       "collective_timeouts": 0, "straggler_flags": [],
                       "step_errors": [], "spans": {}, "compiles": 0,
                       "decode_pages": {"live": 0, "window": 0}}
+        if self._experts:
+            self.stats["experts_hit"] = {"prefill": 0, "decode": 0}
+            self.stats["expert_rows"] = {
+                p: {"assigned": 0, "computed": 0}
+                for p in ("prefill", "decode")}
 
         def count_compile(event, duration_secs, **kwargs):
             if event == _BACKEND_COMPILE_EVENT:
@@ -852,8 +867,9 @@ class Engine:
                     raise InjectedFault(f"injected {plan}-step failure")
                 with self._span("engine.dispatch"):
                     first, *rest = (jnp.asarray(a) for a in host)
-                    toks, self.pools = step(self.params, first, self.pools,
-                                            *rest, self._split_key())
+                    toks, self.pools, *counted = step(
+                        self.params, first, self.pools, *rest,
+                        self._split_key())
             except InjectedFault:
                 failure = (True, None)
             except Exception as e:  # noqa: BLE001 — any launch error retries
@@ -861,11 +877,18 @@ class Engine:
             else:
                 with self._span("engine.fetch"):
                     toks = np.asarray(toks)
+                    counted = [np.asarray(c) for c in counted]
         if failure is not None:
             injected, error = failure
             self._step_failure(participants, queue, injected=injected,
                                phase=phase, error=error)
             return None
+        if counted:
+            hits, assigned, computed = (int(v) for v in counted[0])
+            self.stats["experts_hit"][phase] += hits
+            rows = self.stats["expert_rows"][phase]
+            rows["assigned"] += assigned
+            rows["computed"] += computed
         return toks
 
     def _page_tables(self, live) -> np.ndarray:
@@ -957,25 +980,28 @@ class Engine:
             victim = min(decoding, key=lambda s: s.admit_seq)
             if victim.pages:
                 self._poison_page(victim.pages[0])
-        with self._span("engine.pack"):
-            tok = np.zeros((self.slots,), np.int32)
-            pos = np.zeros((self.slots,), np.int32)
-            for s in decoding:
-                i = self._slots.index(s)
-                tok[i] = s.tok
-                pos[i] = s.pos
-            pt = self._page_tables(decoding)
         if n == self.burst and self.burst > 1:
             plan, step = "burst", self._burst_step
         else:
             plan, step, n = "decode", self._decode_step, 1
+        with self._span("engine.pack"):
+            tok = np.zeros((self.slots,), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            live = np.zeros((self.slots,), np.int32)
+            for s in decoding:
+                i = self._slots.index(s)
+                tok[i] = s.tok
+                pos[i] = s.pos
+                live[i] = min(n, s.req.max_new - len(s.out))
+            pt = self._page_tables(decoding)
         # pages paged decode attention reads for the live rows: each row's
         # pos + 1 tokens at every step of the launch, within its window
         rows = pos[[self._slots.index(s) for s in decoding]]
         lens = rows[:, None] + np.arange(1, n + 1)
         live_pages = int(np.minimum(-(-lens // self.page_size),
                                     self.max_pages).sum())
-        toks = self._launch(plan, step, (tok, pt, pos), decoding, queue,
+        host = (tok, pt, pos) + ((live,) if self._experts else ())
+        toks = self._launch(plan, step, host, decoding, queue,
                             tokens=n * len(decoding), pages=live_pages)
         if toks is None:
             return

@@ -39,6 +39,7 @@ from repro.core import peft
 from repro.distributed.sharding import make_rules, tree_shardings
 from repro.kernels import dispatch
 from repro.models import (
+    EXPERT_STATS,
     activation_rules,
     cache_init,
     forward_decode,
@@ -473,7 +474,10 @@ def build_prefill_chunk_plan(cfg, mesh, *, slots: int, chunk: int,
     Dead slots (qpos all -1, pt row all zeros) write only the dummy page
     and produce garbage tok1 the scheduler ignores; ``tok1`` is each row's
     token sampled from its last live logits — the first generated token for
-    slots whose prompt ends in this chunk.  Donate pools (argnums 2)."""
+    slots whose prompt ends in this chunk.  Donate pools (argnums 2).
+    For a config with experts the step also returns the grouped expert
+    layers' counters (``models.EXPERT_STATS``, summed over the layers);
+    rows whose qpos is -1 route to no expert."""
     if chunk % page_size:
         raise ValueError(f"chunk {chunk} must be a multiple of the page "
                          f"size {page_size}")
@@ -489,25 +493,30 @@ def build_prefill_chunk_plan(cfg, mesh, *, slots: int, chunk: int,
     pos0 = jax.ShapeDtypeStruct((b,), jnp.int32)
     key_arg = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
+    stats = cfg.moe is not None
+
     def chunk_step(params, tokens, pools, pt, qpos, pos0, key):
         with _step_scope("step_chunk_prefill", rules, kernel_backend, mesh):
-            logits, pools = forward_prefill_chunk(
-                params, cfg, {"tokens": tokens}, pools, pt, qpos, pos0)
+            out = forward_prefill_chunk(
+                params, cfg, {"tokens": tokens}, pools, pt, qpos, pos0,
+                **({"expert_stats": True} if stats else {}))
+            logits, pools = out[:2]
             with jax.named_scope("sample"):
                 tok1 = sample_token_guarded(
                     logits[:, -1, : cfg.vocab_size], key, temperature)
-        return tok1, pools
+        return (tok1, pools) + out[2:]
 
     return StepPlan(
         name=f"chunk_prefill:{cfg.name}:b{slots}c{chunk}",
         step_fn=chunk_step,
         abstract_args=(values, toks, pool_vals, pt, qpos, pos0, key_arg),
         in_shardings=(shard_tree, None, pool_sh, None, None, None, None),
-        out_shardings=(None, pool_sh),
+        out_shardings=(None, pool_sh) + ((None,) if stats else ()),
         rules=rules,
         donate_argnums=(2,),
         meta={"kind": "chunk_prefill", "chunk": chunk,
               "page_size": page_size, "total_pages": total_pages,
+              "expert_stats": stats,
               "kernel_backend": _meta_backend(kernel_backend),
               "attention": _meta_attention(kernel_backend),
               "sharding": _meta_sharding(mesh, rules)},
@@ -530,7 +539,11 @@ def build_paged_generate_plan(cfg, mesh, *, slots: int, gen: int,
     burst can write, so mid-burst writes never land on an unmapped page
     (unmapped entries point at the dummy page 0, whose reads are masked).
     Dead slots run with pt row 0 / pos 0 and their tokens are ignored.
-    Donate pools (argnums 2)."""
+    Donate pools (argnums 2).  For a config with experts the step takes
+    ``live`` (slots,) int32 before the key, the sub-steps of the burst in
+    which each row's token is kept (0 for a dead slot; rows past it route
+    to no expert), and also returns the grouped expert layers' counters
+    summed over the layers and sub-steps."""
     rules, values, shard_tree = _plan_state(
         cfg, mesh, _PagedShape(max_pages * page_size, slots, "decode"),
         "decode", budget_gb=budget_gb, force_2d=force_2d)
@@ -542,13 +555,24 @@ def build_paged_generate_plan(cfg, mesh, *, slots: int, gen: int,
     pos0 = jax.ShapeDtypeStruct((b,), jnp.int32)
     key_arg = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
-    def generate_step(params, tok0, pools, pt, pos0, key):
+    stats = cfg.moe is not None
+
+    def generate_step(params, tok0, pools, pt, pos0, *rest):
+        # expert configs take live (slots,) int32 before the key: the
+        # sub-steps of the burst in which each row's token is kept
+        live, key = rest if stats else (None, rest[0])
         with _step_scope(f"step_paged_generate_g{gen}", rules,
                          kernel_backend, mesh):
-            def body(carry, _):
-                tok, pools, pos, key = carry
-                logits, pools = forward_decode_paged(
-                    params, cfg, {"tokens": tok}, pools, pt, pos)
+            def body(carry, j):
+                tok, pools, pos, key, st = carry
+                if stats:
+                    logits, pools, counts = forward_decode_paged(
+                        params, cfg, {"tokens": tok}, pools, pt, pos,
+                        j < live, expert_stats=True)
+                    st = st + counts
+                else:
+                    logits, pools = forward_decode_paged(
+                        params, cfg, {"tokens": tok}, pools, pt, pos)
                 key, sub = jax.random.split(key)
                 with jax.named_scope("sample"):
                     nxt = sample_token_guarded(
@@ -557,23 +581,30 @@ def build_paged_generate_plan(cfg, mesh, *, slots: int, gen: int,
                 # embedding lookup stays in range; the emitted -1 persists
                 # (its KV history is poisoned, logits stay non-finite) and
                 # the engine truncates at the first marker
-                return (jnp.maximum(nxt, 0), pools, pos + 1, key), nxt
+                return (jnp.maximum(nxt, 0), pools, pos + 1, key, st), nxt
 
-            (_, pools, _, _), toks = jax.lax.scan(
-                body, (tok0, pools, pos0, key), None, length=gen)
-        return jnp.moveaxis(toks, 0, 1), pools  # (slots, gen)
+            st0 = (jnp.zeros((len(EXPERT_STATS),), jnp.int32) if stats
+                   else None)
+            steps = jnp.arange(gen, dtype=jnp.int32) if stats else None
+            (_, pools, _, _, st), toks = jax.lax.scan(
+                body, (tok0, pools, pos0, key, st0), steps, length=gen)
+        out = (jnp.moveaxis(toks, 0, 1), pools)  # (slots, gen)
+        return out + (st,) if stats else out
 
+    live_arg = (jax.ShapeDtypeStruct((b,), jnp.int32),) if stats else ()
     return StepPlan(
         name=f"paged_generate:{cfg.name}:b{slots}g{gen}",
         step_fn=generate_step,
-        abstract_args=(values, tok0, pool_vals, pt, pos0, key_arg),
-        in_shardings=(shard_tree, None, pool_sh, None, None, None),
-        out_shardings=(None, pool_sh),
+        abstract_args=(values, tok0, pool_vals, pt, pos0) + live_arg
+        + (key_arg,),
+        in_shardings=(shard_tree, None, pool_sh, None, None)
+        + (None,) * len(live_arg) + (None,),
+        out_shardings=(None, pool_sh) + ((None,) if stats else ()),
         rules=rules,
         donate_argnums=(2,),
         meta={"kind": "paged_generate", "gen": gen,
               "page_size": page_size, "total_pages": total_pages,
-              "temperature": temperature,
+              "temperature": temperature, "expert_stats": stats,
               "kernel_backend": _meta_backend(kernel_backend),
               "attention": _meta_attention(kernel_backend),
               "sharding": _meta_sharding(mesh, rules)},

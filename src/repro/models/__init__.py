@@ -10,3 +10,4 @@ from repro.models.model import (  # noqa: F401
     model_init,
     paged_cache_init,
 )
+from repro.models.moe import EXPERT_STATS  # noqa: F401

@@ -31,6 +31,7 @@ from repro.models.common import (
     qlinear_init,
     rmsnorm,
     rmsnorm_init,
+    rope_attn_scale,
     shard,
 )
 
@@ -192,8 +193,8 @@ def _gqa_qkv(params, x, cfg, quant, positions):
     q = qmatmul(params["wq"], x, quant, nh * hd, d).reshape(b, s, nh, hd)
     k = qmatmul(params["wk"], x, quant, nkv * hd, d).reshape(b, s, nkv, hd)
     v = qmatmul(params["wv"], x, quant, nkv * hd, d).reshape(b, s, nkv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     q = shard(q, "batch", "seq", "heads", "head_dim")
     k = shard(k, "batch", "seq", "kv_heads", "head_dim")
     v = shard(v, "batch", "seq", "kv_heads", "head_dim")
@@ -280,8 +281,8 @@ def gqa_decode(params, x, cfg, quant, cache, pos):
     q = qmatmul(params["wq"], x, quant, nh * hd, d).reshape(b, 1, nh, hd)
     k = qmatmul(params["wk"], x, quant, nkv * hd, d).reshape(b, 1, nkv, hd)
     v = qmatmul(params["wv"], x, quant, nkv * hd, d).reshape(b, 1, nkv, hd)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    q = apply_rope(q, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
+    k = apply_rope(k, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
     new_cache = {**_kv_store(cache, "k", k, pos),
                  **_kv_store(cache, "v", v, pos)}
     new_cache = {
@@ -304,12 +305,23 @@ def gqa_decode(params, x, cfg, quant, cache, pos):
 
 
 def mla_init(key, cfg, quant):
+    """Latent attention's linears.  q comes through a LoRA (``q_down``,
+    ``q_norm``, ``q_up``) or, where ``q_lora_rank`` is None, from one
+    direct projection ``wq``."""
     m, d, nh = cfg.mla, cfg.d_model, cfg.num_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
     ks = jax.random.split(key, 6)
+    if m.q_lora_rank is None:
+        q = {"wq": qlinear_init(ks[0], nh * qk, d, quant, "qkv_out",
+                                "embed")}
+    else:
+        q = {"q_down": qlinear_init(ks[0], m.q_lora_rank, d, quant,
+                                    "q_lora", "embed"),
+             "q_up": qlinear_init(ks[1], nh * qk, m.q_lora_rank, quant,
+                                  "qkv_out", "q_lora"),
+             "q_norm": rmsnorm_init(m.q_lora_rank, "q_lora")}
     return {
-        "q_down": qlinear_init(ks[0], m.q_lora_rank, d, quant, "q_lora", "embed"),
-        "q_up": qlinear_init(ks[1], nh * qk, m.q_lora_rank, quant, "qkv_out", "q_lora"),
+        **q,
         "kv_down": qlinear_init(
             ks[2], m.kv_lora_rank + m.qk_rope_dim, d, quant, "kv_lora", "embed"),
         "k_up": qlinear_init(
@@ -317,7 +329,6 @@ def mla_init(key, cfg, quant):
         "v_up": qlinear_init(
             ks[4], nh * m.v_head_dim, m.kv_lora_rank, quant, "qkv_out", "kv_lora"),
         "wo": qlinear_init(ks[5], d, nh * m.v_head_dim, quant, "embed", "qkv_out"),
-        "q_norm": rmsnorm_init(m.q_lora_rank, "q_lora"),
         "kv_norm": rmsnorm_init(m.kv_lora_rank, "kv_lora"),
     }
 
@@ -326,13 +337,24 @@ def _mla_q(params, x, cfg, quant, positions):
     m, d, nh = cfg.mla, cfg.d_model, cfg.num_heads
     b, s, _ = x.shape
     qk = m.qk_nope_dim + m.qk_rope_dim
-    ql = qmatmul(params["q_down"], x, quant, m.q_lora_rank, d)
-    ql = rmsnorm(params["q_norm"], ql, cfg.norm_eps)
-    q = qmatmul(params["q_up"], ql, quant, nh * qk, m.q_lora_rank)
+    if m.q_lora_rank is None:
+        q = qmatmul(params["wq"], x, quant, nh * qk, d)
+    else:
+        ql = qmatmul(params["q_down"], x, quant, m.q_lora_rank, d)
+        ql = rmsnorm(params["q_norm"], ql, cfg.norm_eps)
+        q = qmatmul(params["q_up"], ql, quant, nh * qk, m.q_lora_rank)
     q = q.reshape(b, s, nh, qk)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                        cfg.rope_scaling)
     return q_nope, q_rope
+
+
+def _mla_scale(cfg) -> float:
+    """Softmax scale of latent attention: 1/sqrt(nope + rope), times
+    YaRN's mscale² where the config scales its rope."""
+    m = cfg.mla
+    return rope_attn_scale(m.qk_nope_dim + m.qk_rope_dim, cfg.rope_scaling)
 
 
 def _mla_latents(params, x, cfg, quant, positions):
@@ -341,7 +363,8 @@ def _mla_latents(params, x, cfg, quant, positions):
         params["kv_down"], x, quant, m.kv_lora_rank + m.qk_rope_dim, d)
     c, k_rope = ckv[..., : m.kv_lora_rank], ckv[..., m.kv_lora_rank:]
     c = rmsnorm(params["kv_norm"], c, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0]
     return c, k_rope  # (b,s,kv_lora), (b,s,rope)
 
 
@@ -361,7 +384,7 @@ def mla_train(params, x, cfg, quant, positions, chunk=512):
         axis=-1,
     )
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_scale(cfg)
     out = chunked_causal_attention(q, k, v, chunk=chunk, logit_scale=scale,
                                    positions=positions)
     out = out.reshape(b, s, nh * m.v_head_dim)
@@ -411,7 +434,7 @@ def mla_decode(params, x, cfg, quant, cache, pos):
     new_cache = {**_kv_store(cache, "c", c_new, pos),
                  **_kv_store(cache, "k_rope", k_rope_new, pos)}
     r_cache = new_cache["k_rope"]
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_scale(cfg)
 
     # absorb k_up into q:  q_lat (b,1,nh,kv_lora)
     w_kup = _dequant(params["k_up"], cfg, quant, nh * m.qk_nope_dim, m.kv_lora_rank)
@@ -591,8 +614,8 @@ def gqa_decode_paged(params, x, cfg, quant, pool, pt, pos):
     q = qmatmul(params["wq"], x, quant, nh * hd, d).reshape(b, 1, nh, hd)
     k = qmatmul(params["wk"], x, quant, nkv * hd, d).reshape(b, 1, nkv, hd)
     v = qmatmul(params["wv"], x, quant, nkv * hd, d).reshape(b, 1, nkv, hd)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    q = apply_rope(q, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
+    k = apply_rope(k, pos[:, None], cfg.rope_theta, cfg.rope_scaling)
     new_pool = {**_paged_store(pool, "k", k, pt, pos=pos),
                 **_paged_store(pool, "v", v, pt, pos=pos)}
     new_pool = {
@@ -618,7 +641,7 @@ def mla_decode_paged(params, x, cfg, quant, pool, pt, pos):
     c_new, k_rope_new = _mla_latents(params, x, cfg, quant, pos[:, None])
     new_pool = {**_paged_store(pool, "c", c_new, pt, pos=pos),
                 **_paged_store(pool, "k_rope", k_rope_new, pt, pos=pos)}
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_scale(cfg)
     w_kup = _dequant(params["k_up"], cfg, quant, nh * m.qk_nope_dim,
                      m.kv_lora_rank)
     w_kup = w_kup.reshape(nh, m.qk_nope_dim, m.kv_lora_rank)
@@ -696,7 +719,7 @@ def mla_prefill_chunk(params, x, cfg, quant, qpos, pos0, pool, pt):
     prefix_pos = jnp.arange(cap, dtype=jnp.int32)[None]
     prefix_pos = jnp.where(prefix_pos < pos0[:, None], prefix_pos, -1)
     kpos = jnp.concatenate([prefix_pos, qpos], axis=1)
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_scale(cfg)
     out = qattention("chunk_prefill", q, kcat, vcat, qpos, kpos,
                      logit_scale=scale)
     out = out.astype(x.dtype).reshape(b, cs, nh * m.v_head_dim)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import threading
 from typing import Any, NamedTuple
 
@@ -27,6 +28,7 @@ __all__ = [
     "rmsnorm_init",
     "rmsnorm",
     "rope_freqs",
+    "rope_attn_scale",
     "apply_rope",
     "shard",
     "activation_rules",
@@ -207,21 +209,68 @@ def rmsnorm(g, x, eps=1e-5):
     return (g * xf * jax.lax.rsqrt(var + eps)).astype(x.dtype)
 
 
-def rope_freqs(head_dim, theta=10000.0):
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
-    return inv  # (head_dim/2,)
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def apply_rope(x, positions, theta=10000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def rope_freqs(head_dim, theta=10000.0, scaling=None):
+    """Inverse frequencies (head_dim/2,).  With a YaRN ``scaling``
+    (:class:`repro.configs.YarnCfg`) each dim blends the interpolated
+    frequency (÷ factor) and the original one by a linear ramp between the
+    dims that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context: fast dims keep their frequency, slow ones are interpolated."""
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
+                           / head_dim))
+    if scaling is None:
+        return inv  # (head_dim/2,)
+
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp           # 1: the original frequency
+    return inv / scaling.factor * ramp + inv * keep
+
+
+def _rope_mscale(scaling) -> float:
+    """YaRN's factor on cos and sin."""
+    if scaling is None:
+        return 1.0
+    return (_yarn_mscale(scaling.factor, scaling.mscale)
+            / _yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def rope_attn_scale(depth: int, scaling=None) -> float:
+    """Attention softmax scale for query-key depth ``depth``: 1/sqrt(depth),
+    times YaRN's ``mscale(mscale_all_dim)²`` where that is set."""
+    scale = 1.0 / math.sqrt(depth)
+    if scaling is not None and scaling.mscale_all_dim:
+        scale *= _yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x, positions, theta=10000.0, scaling=None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+    ``scaling``: a YaRN rope scaling, or None."""
     import os
 
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)
+    inv = rope_freqs(hd, theta, scaling)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., s, hd/2)
     dt = x.dtype if os.environ.get("REPRO_BF16_ELEMWISE") == "1" else jnp.float32
-    cos = jnp.cos(ang)[..., None, :].astype(dt)
-    sin = jnp.sin(ang)[..., None, :].astype(dt)
+    ms = _rope_mscale(scaling)
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
+    if ms != 1.0:
+        cos, sin = cos * ms, sin * ms
+    cos, sin = cos.astype(dt), sin.astype(dt)
     x1, x2 = jnp.split(x.astype(dt), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -7,6 +7,9 @@ blocks (e.g. Jamba: 1×attn + 7×mamba, MoE every 2nd layer).  Layers are
 initialized per period and stacked along a leading 'layers' axis, then the
 forward is one ``lax.scan`` over periods with the period body unrolled —
 heterogeneous architectures keep O(period) HLO size instead of O(num_layers).
+Leading dense layers (``cfg.lead_layers``, DeepSeek's
+``first_k_dense_replace``) sit outside the periods in a stack of their own,
+``params['lead']``; caches and page pools then hold ``{'lead', 'layers'}``.
 
 Modes
 -----
@@ -20,7 +23,10 @@ frontend embeddings (the frontend itself is stubbed per assignment).
 
 The serving forwards name their parts with ``jax.named_scope``: ``embed``,
 ``attn`` (the mixer with its norm and residual; ``mamba``/``mlstm``/``slstm``
-for recurrent mixers), ``mlp`` and ``final_norm_head``.
+for recurrent mixers), ``mlp`` (``dense_mlp`` in a leading dense layer; an
+expert layer's ``router``, ``experts`` and ``shared_experts`` inside it)
+and ``final_norm_head``.  Serving runs expert layers through the dropless
+grouped layer over the live rows; training through the capacity layer.
 """
 from __future__ import annotations
 
@@ -109,6 +115,86 @@ def _block_train(blk, x, cfg, kind, positions):
 
 
 # ---------------------------------------------------------------------------
+# layer groups: the leading dense layers, then the scanned periods
+# ---------------------------------------------------------------------------
+
+_LEAD_KINDS = (("attn", "dense"),)
+
+
+def _groups(cfg):
+    """(key, kinds, count) of each stacked group of layers: the leading
+    dense layers (``params['lead']``, one layer a step) where the config
+    has them, then the periods (``params['layers']``)."""
+    out = []
+    if cfg.lead_layers:
+        out.append(("lead", _LEAD_KINDS, cfg.lead_layers))
+    out.append(("layers", tuple(cfg.layer_kinds()), cfg.num_periods))
+    return out
+
+
+def _split_state(cfg, state) -> dict:
+    """A cache or pool tree by group key; a config without leading dense
+    layers keeps its tree flat (the periods' stack)."""
+    return dict(state) if cfg.lead_layers else {"layers": state}
+
+
+def _join_state(cfg, parts: dict):
+    return parts if cfg.lead_layers else parts["layers"]
+
+
+def _stack_layers(cfg, init_one):
+    """``init_one(kinds, i)`` -> one layer's tree for each group, stacked
+    along a leading 'layers' axis, in ``_split_state`` layout."""
+    parts = {}
+    for key, kinds, count in _groups(cfg):
+        parts[key] = stack_periods([init_one(kinds, i)
+                                    for i in range(count)])
+    return parts
+
+
+def _scan_layers(cfg, body, carry, xs, count):
+    """``lax.scan`` of ``body`` over ``count`` stacked layers, or the same
+    unrolled (cost-analysis probes; XLA counts loop bodies once)."""
+    if cfg.scan_layers:
+        return jax.lax.scan(body, carry, xs)
+    outs = []
+    for p in range(count):
+        carry, out = body(carry, _index_period(xs, p))
+        outs.append(out)
+    return carry, jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
+
+
+def _serve_layers(params, cfg, x, state, layer, *, remat=False):
+    """Every layer of a serving pass: ``layer(blk, x, kind, blk_state,
+    mlp_scope) -> (x, new_blk_state, stats)`` over each group.  Returns
+    (x, new state, stats): the grouped expert layers' counters summed over
+    the layers (``moe.EXPERT_STATS``; None without experts)."""
+    parts = _split_state(cfg, state)
+    new_parts = {}
+    stats = jnp.zeros((len(moe_mod.EXPERT_STATS),), jnp.int32) \
+        if cfg.moe is not None else None
+    for key, kinds, count in _groups(cfg):
+        scope = "dense_mlp" if key == "lead" else "mlp"
+
+        def body(carry, inp, kinds=kinds, scope=scope):
+            x, st = carry
+            lp, ls = inp
+            new = {}
+            for i, kind in enumerate(kinds):
+                x, new[f"blk{i}"], s_i = layer(lp[f"blk{i}"], x, kind,
+                                               ls[f"blk{i}"], scope)
+                if s_i is not None:
+                    st = st + s_i
+            return (x, st), new
+
+        if remat:
+            body = jax.checkpoint(body, policy=_remat_policy(cfg))
+        (x, stats), new_parts[key] = _scan_layers(
+            cfg, body, (x, stats), (params[key], parts[key]), count)
+    return x, _join_state(cfg, new_parts), stats
+
+
+# ---------------------------------------------------------------------------
 # caches
 # ---------------------------------------------------------------------------
 
@@ -128,15 +214,12 @@ def _block_cache(cfg, mixer_kind, batch, capacity):
 
 
 def cache_init(cfg, batch, capacity):
-    """Stacked (num_periods-leading) P-tree of per-layer decode caches."""
-    period_caches = []
-    kinds = cfg.layer_kinds()
-    for _ in range(cfg.num_periods):
-        period_caches.append({
-            f"blk{i}": _block_cache(cfg, kinds[i][0], batch, capacity)
-            for i in range(cfg.period)
-        })
-    return stack_periods(period_caches)
+    """Stacked (num_periods-leading) P-tree of per-layer decode caches
+    (the leading dense layers' under ``lead``, the periods' under
+    ``layers``, where the config has leading dense layers)."""
+    return _join_state(cfg, _stack_layers(cfg, lambda kinds, _: {
+        f"blk{i}": _block_cache(cfg, k[0], batch, capacity)
+        for i, k in enumerate(kinds)}))
 
 
 def paged_cache_init(cfg, total_pages, page_size):
@@ -152,31 +235,35 @@ def paged_cache_init(cfg, total_pages, page_size):
             f"got mixers {sorted({k[0] for k in kinds})}")
     pool_init = (attn.mla_paged_cache_init if cfg.attn_kind == "mla"
                  else attn.gqa_paged_cache_init)
-    periods = []
-    for _ in range(cfg.num_periods):
-        periods.append({
-            f"blk{i}": pool_init(cfg, total_pages, page_size)
-            for i in range(cfg.period)
-        })
-    return stack_periods(periods)
+    return _join_state(cfg, _stack_layers(cfg, lambda kinds, _: {
+        f"blk{i}": pool_init(cfg, total_pages, page_size)
+        for i in range(len(kinds))}))
 
 
-def _mlp_residual(blk, x, cfg, mlp_kind):
-    """Shared post-mixer MLP residual (inference paths discard moe aux)."""
+def _mlp_residual(blk, x, cfg, mlp_kind, live=None, scope="mlp"):
+    """Shared post-mixer MLP residual (inference paths discard moe aux).
+    Expert layers run the dropless grouped layer over the ``live`` rows
+    (b, s) (None: all), or the shard_map layer where the config asks for
+    it.  Returns (x, expert stats or None)."""
     q = cfg.quant
-    with jax.named_scope("mlp"):
+    stats = None
+    with jax.named_scope(scope):
         if mlp_kind == "dense":
             h = rmsnorm(blk["ln2"], x, cfg.norm_eps)
             x = x + moe_mod.dense_mlp_apply(blk["mlp"], h, cfg.d_model,
                                             cfg.d_ff, q)
         elif mlp_kind == "moe":
             h = rmsnorm(blk["ln2"], x, cfg.norm_eps)
-            y, _ = moe_mod.moe_apply(blk["mlp"], h, cfg, q)
+            if cfg.moe.dispatch == "shard_map":
+                y, _ = moe_mod.moe_apply(blk["mlp"], h, cfg, q)
+            else:
+                y, stats = moe_mod.moe_apply_grouped(blk["mlp"], h, cfg, q,
+                                                     live)
             x = x + y
-    return x
+    return x, stats
 
 
-def _block_decode(blk, x, cfg, kind, cache, pos):
+def _block_decode(blk, x, cfg, kind, cache, pos, scope="mlp"):
     mixer_kind, mlp_kind = kind
     q = cfg.quant
     with jax.named_scope(mixer_kind):
@@ -195,10 +282,11 @@ def _block_decode(blk, x, cfg, kind, cache, pos):
         else:
             y, cache = ssm.slstm_decode(blk["mixer"], h, cfg, q, cache, pos)
         x = x + y
-    return _mlp_residual(blk, x, cfg, mlp_kind), cache
+    x, stats = _mlp_residual(blk, x, cfg, mlp_kind, scope=scope)
+    return x, cache, stats
 
 
-def _block_prefill(blk, x, cfg, kind, cache, positions):
+def _block_prefill(blk, x, cfg, kind, cache, positions, scope="mlp"):
     mixer_kind, mlp_kind = kind
     q = cfg.quant
     with jax.named_scope(mixer_kind):
@@ -217,7 +305,8 @@ def _block_prefill(blk, x, cfg, kind, cache, positions):
             # via train path).
             y = _mixer_train(blk["mixer"], h, cfg, mixer_kind, positions)
         x = x + y
-    return _mlp_residual(blk, x, cfg, mlp_kind), cache
+    x, stats = _mlp_residual(blk, x, cfg, mlp_kind, positions >= 0, scope)
+    return x, cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -226,16 +315,16 @@ def _block_prefill(blk, x, cfg, kind, cache, positions):
 
 
 def model_init(key, cfg):
-    keys = jax.random.split(key, cfg.num_periods + 3)
-    kinds = cfg.layer_kinds()
-    periods = []
-    for p in range(cfg.num_periods):
-        pkeys = jax.random.split(keys[p], cfg.period)
-        periods.append({
-            f"blk{i}": _block_init(pkeys[i], cfg, *kinds[i])
-            for i in range(cfg.period)
-        })
-    params = {"layers": stack_periods(periods),
+    keys = jax.random.split(key, cfg.num_periods + cfg.lead_layers + 3)
+    lead_keys = keys[cfg.num_periods:cfg.num_periods + cfg.lead_layers]
+
+    def init_one(kinds, i):
+        k = lead_keys[i] if kinds is _LEAD_KINDS else keys[i]
+        pkeys = jax.random.split(k, len(kinds))
+        return {f"blk{j}": _block_init(pkeys[j], cfg, *kinds[j])
+                for j in range(len(kinds))}
+
+    params = {**_stack_layers(cfg, init_one),
               "final_norm": rmsnorm_init(cfg.d_model)}
     if cfg.input_kind == "tokens":
         params["embed"] = dense_init(
@@ -292,27 +381,24 @@ def _index_period(tree, i):
 
 
 def _scan_train(params, cfg, x, positions):
-    kinds = cfg.layer_kinds()
+    aux = jnp.zeros((), jnp.float32)
+    for key, kinds, count in _groups(cfg):
+        def period_body(carry, layer_params, kinds=kinds):
+            x, aux = carry
+            for i, kind in enumerate(kinds):
+                x, a = _block_train(layer_params[f"blk{i}"], x, cfg, kind,
+                                    positions)
+                aux = aux + a
+            return (x, aux), None
 
-    def period_body(carry, layer_params):
-        x, aux = carry
-        for i in range(cfg.period):
-            x, a = _block_train(layer_params[f"blk{i}"], x, cfg, kinds[i],
-                                positions)
-            aux = aux + a
-        return (x, aux), None
-
-    body = period_body
-    if cfg.remat:
-        body = jax.checkpoint(period_body, policy=_remat_policy(cfg))
-    carry0 = (x, jnp.zeros((), jnp.float32))
-    if cfg.scan_layers:
-        (x, aux), _ = jax.lax.scan(body, carry0, params["layers"])
-    else:  # unrolled (cost-analysis probes; XLA counts loop bodies once)
-        carry = carry0
-        for p in range(cfg.num_periods):
-            carry, _ = body(carry, _index_period(params["layers"], p))
-        x, aux = carry
+        body = period_body
+        if cfg.remat:
+            body = jax.checkpoint(period_body, policy=_remat_policy(cfg))
+        if cfg.scan_layers:
+            (x, aux), _ = jax.lax.scan(body, (x, aux), params[key])
+        else:  # unrolled (cost-analysis probes; XLA counts loop bodies once)
+            for p in range(count):
+                (x, aux), _ = body((x, aux), _index_period(params[key], p))
     return x, aux
 
 
@@ -364,11 +450,12 @@ def forward_prefill(params, cfg, batch, cache, positions=None):
     """Full-sequence forward filling caches; returns (last logits, cache).
 
     ``positions`` (b, s) int32 makes the window ragged: -1 rows are dead
-    padding (masked out of attention), and the returned logits come from
-    each row's *last live* token instead of column s-1 — so a batch of
-    mixed-length prompts prefills in one fixed-shape call without the
-    padding leaking into the numerics.  None = the aligned arange (every
-    row fully live, logits from the last column, as before)."""
+    padding (masked out of attention, routed to no expert), and the
+    returned logits come from each row's *last live* token instead of
+    column s-1 — so a batch of mixed-length prompts prefills in one
+    fixed-shape call without the padding leaking into the numerics.  None
+    = the aligned arange (every row fully live, logits from the last
+    column, as before)."""
     if cfg.input_kind == "tokens":
         b, s = batch["tokens"].shape
     else:
@@ -378,99 +465,60 @@ def forward_prefill(params, cfg, batch, cache, positions=None):
             jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     with jax.named_scope("embed"):
         x = _embed_in(params, cfg, batch)
-    kinds = cfg.layer_kinds()
 
-    def period_body(x, inp):
-        layer_params, layer_cache = inp
-        new_cache = {}
-        for i in range(cfg.period):
-            x, new_cache[f"blk{i}"] = _block_prefill(
-                layer_params[f"blk{i}"], x, cfg, kinds[i],
-                layer_cache[f"blk{i}"], positions)
-        return x, new_cache
+    def layer(blk, x, kind, cache, scope):
+        return _block_prefill(blk, x, cfg, kind, cache, positions, scope)
 
-    body = period_body
-    if cfg.remat:
-        body = jax.checkpoint(period_body, policy=_remat_policy(cfg))
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-    else:
-        outs = []
-        for p in range(cfg.num_periods):
-            x, nc = body(x, (_index_period(params["layers"], p),
-                             _index_period(cache, p)))
-            outs.append(nc)
-        new_cache = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
+    x, new_cache, _ = _serve_layers(params, cfg, x, cache, layer,
+                                    remat=cfg.remat)
     return _final_norm_head(params, cfg, x, positions), new_cache
 
 
 def forward_decode(params, cfg, batch, cache, pos):
     """One decode step.  batch: token (b,) or embed (b,1,d); pos (b,) int32."""
     x = _embed_step(params, cfg, batch)
-    kinds = cfg.layer_kinds()
 
-    def period_body(x, inp):
-        layer_params, layer_cache = inp
-        new_cache = {}
-        for i in range(cfg.period):
-            x, new_cache[f"blk{i}"] = _block_decode(
-                layer_params[f"blk{i}"], x, cfg, kinds[i],
-                layer_cache[f"blk{i}"], pos)
-        return x, new_cache
+    def layer(blk, x, kind, cache, scope):
+        return _block_decode(blk, x, cfg, kind, cache, pos, scope)
 
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(period_body, x, (params["layers"], cache))
-    else:
-        outs = []
-        for p in range(cfg.num_periods):
-            x, nc = period_body(x, (_index_period(params["layers"], p),
-                                    _index_period(cache, p)))
-            outs.append(nc)
-        new_cache = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
+    x, new_cache, _ = _serve_layers(params, cfg, x, cache, layer)
     return _final_norm_head(params, cfg, x), new_cache
 
 
-def forward_decode_paged(params, cfg, batch, pools, pt, pos):
+def forward_decode_paged(params, cfg, batch, pools, pt, pos, live=None, *,
+                         expert_stats=False):
     """One decode step against the page pools.  batch: token (b,) or embed
-    (b,1,d); pt (b, np) page table; pos (b,) int32 current positions."""
+    (b,1,d); pt (b, np) page table; pos (b,) int32 current positions;
+    ``live`` (b,) bool: the rows whose token is kept (the others route to
+    no expert; None: all).  ``expert_stats`` also returns the grouped
+    expert layers' counters (``moe.EXPERT_STATS``, summed over layers)."""
     x = _embed_step(params, cfg, batch)
     dec = (attn.mla_decode_paged if cfg.attn_kind == "mla"
            else attn.gqa_decode_paged)
     q = cfg.quant
-    kinds = cfg.layer_kinds()
+    rows = None if live is None else live[:, None]
 
-    def period_body(x, inp):
-        layer_params, layer_pools = inp
-        new_pools = {}
-        for i in range(cfg.period):
-            blk = layer_params[f"blk{i}"]
-            with jax.named_scope("attn"):
-                h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
-                y, new_pools[f"blk{i}"] = dec(blk["mixer"], h, cfg, q,
-                                              layer_pools[f"blk{i}"], pt,
-                                              pos)
-                x = x + y
-            x = _mlp_residual(blk, x, cfg, kinds[i][1])
-        return x, new_pools
+    def layer(blk, x, kind, pool, scope):
+        with jax.named_scope("attn"):
+            h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
+            y, pool = dec(blk["mixer"], h, cfg, q, pool, pt, pos)
+            x = x + y
+        x, stats = _mlp_residual(blk, x, cfg, kind[1], rows, scope)
+        return x, pool, stats
 
-    if cfg.scan_layers:
-        x, new_pools = jax.lax.scan(period_body, x, (params["layers"],
-                                                     pools))
-    else:
-        outs = []
-        for p in range(cfg.num_periods):
-            x, np_ = period_body(x, (_index_period(params["layers"], p),
-                                     _index_period(pools, p)))
-            outs.append(np_)
-        new_pools = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
-    return _final_norm_head(params, cfg, x), new_pools
+    x, new_pools, stats = _serve_layers(params, cfg, x, pools, layer)
+    out = (_final_norm_head(params, cfg, x), new_pools)
+    return out + (stats,) if expert_stats else out
 
 
-def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0):
+def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0, *,
+                          expert_stats=False):
     """One chunk of paged prefill.  batch: tokens (b, cs); qpos (b, cs)
-    in-chunk positions (-1 = dead row); pos0 (b,) page-aligned chunk start.
+    in-chunk positions (-1 = dead row, routed to no expert); pos0 (b,)
+    page-aligned chunk start.
 
-    Returns (last-live-row logits (b, 1, V), new pools).  The logits are
+    Returns (last-live-row logits (b, 1, V), new pools) and, with
+    ``expert_stats``, the grouped expert layers' counters.  The logits are
     each row's argmax(qpos) column — only meaningful for slots whose final
     prompt token is in this chunk (the scheduler samples token 1 from them
     then, and ignores them for slots still mid-prompt)."""
@@ -479,30 +527,15 @@ def forward_prefill_chunk(params, cfg, batch, pools, pt, qpos, pos0):
     pre = (attn.mla_prefill_chunk if cfg.attn_kind == "mla"
            else attn.gqa_prefill_chunk)
     q = cfg.quant
-    kinds = cfg.layer_kinds()
 
-    def period_body(x, inp):
-        layer_params, layer_pools = inp
-        new_pools = {}
-        for i in range(cfg.period):
-            blk = layer_params[f"blk{i}"]
-            with jax.named_scope("attn"):
-                h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
-                y, new_pools[f"blk{i}"] = pre(blk["mixer"], h, cfg, q, qpos,
-                                              pos0, layer_pools[f"blk{i}"],
-                                              pt)
-                x = x + y
-            x = _mlp_residual(blk, x, cfg, kinds[i][1])
-        return x, new_pools
+    def layer(blk, x, kind, pool, scope):
+        with jax.named_scope("attn"):
+            h = rmsnorm(blk["ln1"], x, cfg.norm_eps)
+            y, pool = pre(blk["mixer"], h, cfg, q, qpos, pos0, pool, pt)
+            x = x + y
+        x, stats = _mlp_residual(blk, x, cfg, kind[1], qpos >= 0, scope)
+        return x, pool, stats
 
-    if cfg.scan_layers:
-        x, new_pools = jax.lax.scan(period_body, x, (params["layers"],
-                                                     pools))
-    else:
-        outs = []
-        for p in range(cfg.num_periods):
-            x, np_ = period_body(x, (_index_period(params["layers"], p),
-                                     _index_period(pools, p)))
-            outs.append(np_)
-        new_pools = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
-    return _final_norm_head(params, cfg, x, qpos), new_pools
+    x, new_pools, stats = _serve_layers(params, cfg, x, pools, layer)
+    out = (_final_norm_head(params, cfg, x, qpos), new_pools)
+    return out + (stats,) if expert_stats else out

@@ -12,7 +12,7 @@ from repro.models import forward_train, model_init, split_tree
 ALL_ARCHS = [
     "minicpm3-4b", "minitron-4b", "llama3-405b", "granite-20b",
     "phi3.5-moe-42b-a6.6b", "kimi-k2-1t-a32b", "internvl2-1b", "xlstm-1.3b",
-    "musicgen-medium", "jamba-1.5-large-398b",
+    "musicgen-medium", "jamba-1.5-large-398b", "deepseek-v2-lite",
     # the paper's own models
     "llama3-8b", "qwen3-8b", "qwen3-4b",
 ]

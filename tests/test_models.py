@@ -103,21 +103,35 @@ def test_gqa_decode_matches_prefill_logits(key):
                                rtol=2e-2, atol=2e-2)
 
 
-def test_moe_dispatch_matches_dense_reference(key):
-    """With ample capacity, scatter-dispatch MoE == dense-einsum reference."""
+@pytest.mark.parametrize("case", ["ample", "tight", "skewed"])
+def test_moe_dispatch_matches_dense_reference(key, case):
+    """With ample capacity, scatter-dispatch MoE == dense-einsum reference;
+    the dropless serving layer equals it too at a capacity factor at which
+    the capacity layer drops ("tight") and when every row prefers the same
+    experts ("skewed")."""
     cfg = smoke_variant(get_config("phi3.5-moe-42b-a6.6b"))
     cfg = cfg.with_(moe=cfg.moe.__class__(
-        num_experts=4, top_k=2, d_ff=32, capacity_factor=4.0))
+        num_experts=4, top_k=2, d_ff=32,
+        capacity_factor=4.0 if case == "ample" else 0.5))
     quant = QuantSpec(method="none", mode="frozen",
                       compute_dtype=jnp.float32)
     params_p = moe_mod.moe_init(key, cfg, quant)
     params, _ = split_tree(params_p)
-    x = jax.random.normal(key, (2, 8, cfg.d_model), jnp.float32) * 0.3
-    y, aux = moe_mod.moe_apply(params, x, cfg, quant)
-    assert np.isfinite(np.asarray(y)).all() and float(aux) > 0
+    s = 8 if case == "ample" else 32
+    x = jax.random.normal(key, (2, s, cfg.d_model), jnp.float32) * 0.3
+    if case == "skewed":
+        x = jnp.abs(x)
+        router = np.zeros((4, cfg.d_model), np.float32)
+        router[2], router[0] = 4.0, 2.0
+        params = dict(params, router=jnp.asarray(router))
+    if case == "ample":
+        y, aux = moe_mod.moe_apply(params, x, cfg, quant)
+        assert np.isfinite(np.asarray(y)).all() and float(aux) > 0
+    else:
+        y, _ = moe_mod.moe_apply_grouped(params, x, cfg, quant)
 
     # dense reference: route every token through its top-k experts exactly
-    t = 16
+    t = 2 * s
     xf = x.reshape(t, cfg.d_model)
     logits = xf @ params["router"].T
     probs = jax.nn.softmax(logits, -1)
@@ -132,6 +146,11 @@ def test_moe_dispatch_matches_dense_reference(key):
             h = jax.nn.silu(wg[e] @ xf[ti]) * (wu[e] @ xf[ti])
             y_ref[ti] += float(gates[ti, j]) * np.asarray(wd[e] @ h)
     np.testing.assert_allclose(np.asarray(y.reshape(t, -1)), y_ref,
+                               rtol=2e-3, atol=2e-3)
+    if case != "ample":
+        # the capacity layer drops here: it misses the reference
+        y_cap, _ = moe_mod.moe_apply(params, x, cfg, quant)
+        assert not np.allclose(np.asarray(y_cap.reshape(t, -1)), y_ref,
                                rtol=2e-3, atol=2e-3)
 
 

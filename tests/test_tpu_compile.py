@@ -185,6 +185,48 @@ def test_attention_compiles(one_chip):
                      and layouts(ln) == stored)]
 
 
+# DeepSeek-V2-Lite's routed experts: 64 of (gate/up 1408 × 2048, down
+# 2048 × 1408); the down projection's planes of 704 codes are not lane
+# multiples, so its last code tile of a plane reads past the plane's end
+@pytest.mark.parametrize("n,k", [(1408, 2048), (2048, 1408)])
+@pytest.mark.parametrize("rows,tiles", [(8, 80), (128, 256)])
+def test_grouped_expert_kernel_compiles(one_chip, n, k, rows, tiles):
+    """The grouped expert kernel at a decode step (32 rows × top-6 in
+    tiles of 8) and a chunk step (32 × 128 rows in tiles of 128)."""
+    from repro.kernels.lords_experts import expert_tiles, lords_experts_pallas
+
+    e = 64
+    r = QuantSpec(block_size=128).lords_rank(n, k)
+    bn, t = expert_tiles(n, k, CODEBOOK)
+    kpad = 2 * (-(-(k // 2) // 128) * 128)
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    _compile(lords_experts_pallas, sd((tiles * rows, kpad), jnp.bfloat16),
+             sd((tiles,), jnp.int32), sd((tiles,), jnp.int32),
+             sd((tiles,), jnp.int32), sd((e, n, k // 2), jnp.uint8),
+             sd((e, r, n), jnp.float32), sd((e, r, kpad), jnp.float32),
+             codebook_name=CODEBOOK, rows=rows, bn=bn, t=t)
+
+
+def test_latent_chunk_prefill_compiles(one_chip):
+    """Chunk prefill attention of latent attention on the chip path: 16
+    heads of nope + rope = 192 (not a lane multiple: the dispatch pads
+    them to 256 with zeros) and v of 128, 32 slots of 128 queries against
+    a window of 2,304 + 128 keys."""
+    b, s, nh, skv = 32, 128, 16, 2432
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+
+    def step(q, k, v, qpos, kpos):
+        return dispatch.qattention("chunk_prefill", q, k, v, qpos, kpos,
+                                   logit_scale=0.1, backend="pallas")
+
+    _compile(step, sd((b, s, nh, 192), jnp.bfloat16),
+             sd((b, skv, nh, 192), jnp.bfloat16),
+             sd((b, skv, nh, 128), jnp.bfloat16), sd((b, s), jnp.int32),
+             sd((b, skv), jnp.int32))
+
+
 def _kernel_bodies_without_locations(hlo: str) -> str:
     """Each Mosaic kernel body (MLIR bytecode in the custom call's backend
     config) replaced by its text without debug locations: a named scope
